@@ -77,6 +77,7 @@ def main(grid: str = "full", repeats: int = 9) -> List[dict]:
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.compat import make_mesh
     from repro.distributed import faults
     from repro.distributed.resilience import checked_syrk, route_runner
 
@@ -86,8 +87,8 @@ def main(grid: str = "full", repeats: int = 9) -> List[dict]:
               "(run with XLA_FLAGS=--xla_force_host_platform_device_"
               "count=8)")
         return []
-    mesh8 = jax.make_mesh((8,), ("x",))
-    mesh6 = jax.make_mesh((6,), ("x",))
+    mesh8 = make_mesh((8,), ("x",))
+    mesh6 = make_mesh((6,), ("x",))
     route_kw = {
         "1d": dict(mesh=mesh8, axis="x"),
         "ring": dict(mesh=mesh8, axis="x"),

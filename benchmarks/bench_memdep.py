@@ -40,6 +40,7 @@ import jax, jax.numpy as jnp
 
 from repro import blas
 from repro.analysis.hlo_cost import analyze_hlo
+from repro.compat import make_mesh
 from repro.blas.meshpath import (REP_AXIS, TB_AXIS, _limited_steps,
                                  _mesh_3d)
 from repro.core.lower_bounds import memory_dependent_parallel_lower_bound
@@ -49,7 +50,7 @@ from repro.core.twodim import make_2d_plan
 cfg = json.loads(sys.argv[1])
 n1, n2, Ptot = cfg["shape"]
 reps = cfg["reps"]
-mesh = jax.make_mesh((Ptot,), ("x",))
+mesh = make_mesh((Ptot,), ("x",))
 A = jnp.asarray(np.random.default_rng(0).standard_normal((n1, n2)),
                 jnp.float32)
 
@@ -108,6 +109,7 @@ def rows(grid: str = "full") -> List[dict]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={_SHAPE[2]}"
+    env["JAX_PLATFORMS"] = "cpu"  # fake devices: never the chip
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run([sys.executable, "-c", _CHILD, json.dumps(cfg)],
                          env=env, capture_output=True, text=True,

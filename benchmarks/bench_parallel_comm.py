@@ -25,6 +25,7 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.hlo_cost import analyze_hlo
+from repro.compat import make_mesh
 from repro.core.dispatch import choose_algorithm
 from repro.core.lower_bounds import memory_independent_lower_bound
 from repro.core.onedim import syrk_1d, syr2k_1d, symm_1d, pack_for_1d_symm
@@ -42,7 +43,7 @@ def emit(**kw):
 
 # ---------------- 1D (case 1): n1 small, n2 large, P small -------------
 P_ = 8
-mesh = jax.make_mesh((P_,), ("x",))
+mesh = make_mesh((P_,), ("x",))
 n1, n2 = 64, 64 * P_
 A = jax.ShapeDtypeStruct((n1, n2), jnp.float32)
 B = jax.ShapeDtypeStruct((n1, n2), jnp.float32)
@@ -64,7 +65,7 @@ emit(kernel="symm", algo="1d", P=P_, n1=n1, n2=n2,
 # ---------------- 2D (case 2): n1 large, n2 small ----------------------
 c = 3
 P2 = c * (c + 1)
-mesh2 = jax.make_mesh((P2,), ("x",))
+mesh2 = make_mesh((P2,), ("x",))
 n1, n2 = 4 * c * c, 2 * (c + 1)           # mn2 < n1
 plan = make_2d_plan(c, n1, n2)
 a_spec = jax.ShapeDtypeStruct((P2, c, plan.nb, plan.w), jnp.float32)
@@ -89,7 +90,7 @@ emit(kernel="symm", algo="2d", P=P2, n1=n1, n2=n2,
 c, p2 = 2, 2
 p1 = c * (c + 1)
 P3 = p1 * p2
-mesh3 = jax.make_mesh((p1, p2), ("tb", "rep"))
+mesh3 = make_mesh((p1, p2), ("tb", "rep"))
 n1 = 2 * c * c
 n2 = 2 * (c + 1) * p2
 n2s = n2 // p2
@@ -117,6 +118,7 @@ print(json.dumps(rows))
 def rows() -> List[dict]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
+    env["JAX_PLATFORMS"] = "cpu"  # fake devices: never the chip
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                          capture_output=True, text=True, timeout=900)

@@ -136,6 +136,7 @@ def bench_blas_fwd_bwd(repeats: int = 7, grid: str = "full"):
     import numpy as np
 
     from repro import blas
+    from repro.compat import make_mesh
 
     rng = np.random.default_rng(0)
     rows = []
@@ -250,6 +251,7 @@ def bench_blas_mesh(repeats: int = 7, grid: str = "full"):
     import numpy as np
 
     from repro import blas
+    from repro.compat import make_mesh
 
     ndev = jax.device_count()
     rng = np.random.default_rng(1)
@@ -261,7 +263,7 @@ def bench_blas_mesh(repeats: int = 7, grid: str = "full"):
             print(f"[blas mesh] skip {op}[{n1}x{n2}] {path}: needs "
                   f"{need} devices, have {ndev}")
             continue
-        mesh = jax.make_mesh((need,), ("x",))
+        mesh = make_mesh((need,), ("x",))
         a = jnp.asarray(rng.standard_normal((n1, n2)), jnp.float32)
         b = jnp.asarray(rng.standard_normal((n1, n2)), jnp.float32)
         kw = {} if fill is None else dict(fill=fill)
@@ -439,6 +441,7 @@ def check_ring_flops_gate(n1: int = 2048, n2: int = 512) -> bool:
 
     from repro.analysis.hlo_cost import analyze_hlo
     from repro.blas import meshpath
+    from repro.compat import make_mesh
 
     if jax.device_count() < 8:
         print("[ring gate] needs 8 devices — skipping")
@@ -446,8 +449,8 @@ def check_ring_flops_gate(n1: int = 2048, n2: int = 512) -> bool:
     rng = np.random.default_rng(5)
     A = jnp.asarray(rng.standard_normal((n1, n2)), jnp.float32)
     B = jnp.asarray(rng.standard_normal((n1, n2)), jnp.float32)
-    mesh8 = jax.make_mesh((8,), ("x",))
-    mesh6 = jax.make_mesh((6,), ("x",))
+    mesh8 = make_mesh((8,), ("x",))
+    mesh6 = make_mesh((6,), ("x",))
 
     def flops(fn, *xs):
         return analyze_hlo(jax.jit(fn).lower(*xs).compile().as_text()).flops

@@ -34,6 +34,7 @@ PACKED_CKPT = "/tmp/repro_elastic_demo_packed"
 def run_phase(ndev: int, extra):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
+    env["JAX_PLATFORMS"] = "cpu"  # fake devices: never the chip
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     cmd = [sys.executable, "-m", "repro.launch.train",
            "--steps", "40", "--global-batch", "12", "--seq-len", "128",
@@ -49,6 +50,7 @@ def run_phase(ndev: int, extra):
 def run_packed_phase(ndev: int, phase: str):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
+    env["JAX_PLATFORMS"] = "cpu"  # fake devices: never the chip
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     p = subprocess.run([sys.executable, os.path.abspath(__file__),
                         "--phase", phase],

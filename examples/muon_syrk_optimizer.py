@@ -22,11 +22,12 @@ import jax                                                     # noqa: E402
 import jax.numpy as jnp                                        # noqa: E402
 
 from repro.analysis.hlo_cost import analyze_hlo                # noqa: E402
+from repro.compat import make_mesh                             # noqa: E402
 from repro.optim.muon import (orthogonalize_1d,                # noqa: E402
                               orthogonalize_reference)
 from repro.launch.train import build_argparser, train          # noqa: E402
 
-mesh = jax.make_mesh((jax.device_count(),), ("model",))
+mesh = make_mesh((jax.device_count(),), ("model",))
 m, n = 128, 512
 g = jax.random.normal(jax.random.key(0), (m, n), jnp.float32)
 
@@ -58,9 +59,8 @@ def ns_naive_1d(x, steps=5):
             return a * v + (b * s + c * (s @ s)) @ v
         return jax.lax.fori_loop(0, steps, it, x_loc).astype(x.dtype)
 
-    from repro.compat import shard_map
-    return shard_map(body, mesh=mesh, in_specs=P(None, "model"),
-                     out_specs=P(None, "model"))(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(None, "model"),
+                         out_specs=P(None, "model"))(x)
 
 
 def wire_bytes(fn, *args):

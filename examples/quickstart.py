@@ -18,6 +18,7 @@ import numpy as np                                              # noqa: E402
 import jax                                                      # noqa: E402
 import jax.numpy as jnp                                         # noqa: E402
 
+from repro.compat import make_mesh                              # noqa: E402
 from repro.core.seq import seq_symm, seq_syr2k, seq_syrk        # noqa: E402
 from repro.core.lower_bounds import (                           # noqa: E402
     memory_independent_lower_bound, sequential_reads_lower_bound)
@@ -68,7 +69,7 @@ for n1_, n2_, P in ((1 << 10, 1 << 16, 8),     # short-wide, few procs -> 1D
 print("=" * 70)
 print("3. Parallel algorithms on a 12-device CPU mesh")
 P = 4
-mesh1 = jax.make_mesh((P,), ("x",))
+mesh1 = make_mesh((P,), ("x",))
 n1p, n2p = 24, 8 * P
 Ap = rng.standard_normal((n1p, n2p)).astype(np.float32)
 out = unpack_1d_result(np.asarray(syrk_1d(jnp.asarray(Ap), mesh1)), n1p)
@@ -77,7 +78,7 @@ print(f"  1D SYRK  (Alg 7, P={P}): max|err| = {err:.2e}")
 
 c = 3
 P2 = c * (c + 1)
-mesh2 = jax.make_mesh((P2,), ("x",))
+mesh2 = make_mesh((P2,), ("x",))
 n1q, n2q = 4 * c * c, 3 * (c + 1)
 plan = make_2d_plan(c, n1q, n2q)
 Aq = rng.standard_normal((n1q, n2q)).astype(np.float32)
@@ -122,7 +123,7 @@ print(f"  pallas SYMM  max|err| = {np.abs(got - want).max():.2e}")
 # ----------------------------------------------------- 5. unified dispatch
 print("=" * 70)
 print("5. repro.blas: one entry point, regime-routed execution")
-mesh4 = jax.make_mesh((4,), ("x",))
+mesh4 = make_mesh((4,), ("x",))
 A5 = jnp.asarray(rng.standard_normal((16, 1024)), np.float32)
 for op, n1_, n2_, mesh_ in (("syrk", 24, 24, None),
                             ("syrk", 16, 1024, mesh4),

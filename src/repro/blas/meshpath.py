@@ -38,7 +38,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..core import ringpath
 from ..core.dispatch import ring_nb
 from ..core.onedim import (_padded_tril_len, symm_1d_local, syr2k_1d_local,
@@ -187,8 +186,8 @@ def syrk_1d_packed(a: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
         full = jax.lax.all_gather(shard, axis, axis=0, tiled=True)
         return full[:tril_size(n1)]
 
-    return shard_map(body, mesh=mesh, in_specs=P(None, axis),
-                     out_specs=P(), check_vma=False)(a)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(None, axis),
+                         out_specs=P(), check_vma=False)(a)
 
 
 def syr2k_1d_packed(a: jax.Array, b: jax.Array, mesh: Mesh, axis: str
@@ -201,9 +200,9 @@ def syr2k_1d_packed(a: jax.Array, b: jax.Array, mesh: Mesh, axis: str
         full = jax.lax.all_gather(shard, axis, axis=0, tiled=True)
         return full[:tril_size(n1)]
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(None, axis), P(None, axis)),
-                     out_specs=P(), check_vma=False)(a, b)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(None, axis), P(None, axis)),
+                         out_specs=P(), check_vma=False)(a, b)
 
 
 def symm_1d_packed_a(a_packed: jax.Array, b: jax.Array, n1: int, mesh: Mesh,
@@ -218,8 +217,8 @@ def symm_1d_packed_a(a_packed: jax.Array, b: jax.Array, n1: int, mesh: Mesh,
     packed = jnp.pad(a_packed,
                      (0, _padded_tril_len(n1, nsh) - a_packed.shape[0]))
     f = functools.partial(symm_1d_local, axis=axis, n1=n1)
-    return shard_map(f, mesh=mesh, in_specs=(P(axis), P(None, axis)),
-                     out_specs=P(None, axis), check_vma=False)(packed, b)
+    return jax.shard_map(f, mesh=mesh, in_specs=(P(axis), P(None, axis)),
+                         out_specs=P(None, axis), check_vma=False)(packed, b)
 
 
 def symm_1d_dense(a_sym: jax.Array, b: jax.Array, mesh: Mesh, axis: str
@@ -255,9 +254,9 @@ def _rank_update_1d_stacked(local_gram, operands, mesh: Mesh, axis: str
                                      tiled=True)
         return jax.lax.all_gather(shard, axis, axis=1, tiled=True)[:, :L]
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(None, None, axis),) * len(operands),
-                     out_specs=P(), check_vma=False)(*operands)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(None, None, axis),) * len(operands),
+                         out_specs=P(), check_vma=False)(*operands)
 
 
 def syrk_1d_packed_stacked(a: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
@@ -294,10 +293,10 @@ def symm_1d_packed_a_stacked(a_packed: jax.Array, b: jax.Array, n1: int,
         sym = unpack_tril(full, n1, diag=True, symmetric=True)
         return jnp.einsum("kmn,knj->kmj", sym, b_loc)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(None, axis), P(None, None, axis)),
-                     out_specs=P(None, None, axis),
-                     check_vma=False)(packed, b)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(None, axis), P(None, None, axis)),
+                         out_specs=P(None, None, axis),
+                         check_vma=False)(packed, b)
 
 
 # --------------------------------------------------------------------------

@@ -1,98 +1,28 @@
-"""Version portability layer for the jax APIs this repo leans on.
-
-The codebase targets the modern ``jax.shard_map`` / ``jax.set_mesh`` /
-``jax.lax.pvary`` surface; older jaxlibs (>= 0.4.35) ship the same
-functionality under different names (``jax.experimental.shard_map`` with
-``check_rep``, the ``Mesh`` context manager, no varying-manual-axes
-tracking).  Every module that touches meshes or manual collectives goes
-through these wrappers so a single file absorbs the skew.
+"""Mesh helpers shared by every module that builds or reads a mesh.
 
 Exports:
-  shard_map(f, mesh, in_specs, out_specs, check_vma=None)
-  use_mesh(mesh)          — context manager setting the ambient mesh
+  make_mesh(shape, names) — the one mesh constructor (Auto axes)
   get_ambient_mesh()      — ambient (abstract or physical) mesh, or None
-  make_mesh(shape, names, axis_types=None)
-  pvary(x, axes)          — mark a constant varying over manual axes
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 
-_HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
 
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with Auto axes.
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None):
-    """``jax.shard_map`` when present, else the experimental spelling.
-
-    ``check_vma`` (new name) maps onto ``check_rep`` (old name); ``None``
-    keeps each version's default.
-    """
-    if _HAS_NATIVE_SHARD_MAP:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    New jax: ``jax.set_mesh``.  Old jax: the ``Mesh`` object is itself a
-    context manager that sets the thread-local physical mesh (which is all
-    explicit-sharding code paths need).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    Bare ``jax.make_mesh`` builds Explicit axes, under which jnp gathers
+    on sharded operands need ``out_sharding=`` and ``jax.grad`` needs an
+    ambient mesh; the code here relies on GSPMD propagation, so every
+    mesh is Auto."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=auto)
 
 
 def get_ambient_mesh():
-    """The mesh installed by :func:`use_mesh`, or ``None`` outside one."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and getattr(mesh, "empty", False):
-            return None
-        return mesh
-    from jax._src import mesh as mesh_lib
-    mesh = mesh_lib.thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
-
-
-def make_mesh(axis_shapes, axis_names, axis_types=None):
-    """``jax.make_mesh`` accepting (and dropping, pre-AxisType jax) the
-    ``axis_types`` keyword.  ``axis_types`` may be the string ``"auto"`` /
-    ``"explicit"`` (applied to every axis) or a tuple of AxisType."""
-    if axis_types is not None and hasattr(jax.sharding, "AxisType"):
-        if isinstance(axis_types, str):
-            at = getattr(jax.sharding.AxisType, axis_types.capitalize())
-            axis_types = (at,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types)
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict.
-
-    Older jax returns a one-element list of per-program dicts; newer jax
-    returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
-def pvary(x, axes):
-    """Mark a replicated constant as varying over manual ``axes`` (the
-    scan-carry vma rule).  Identity on jax versions without varying
-    tracking — their shard_map does not distinguish."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axes)
-    return x
+    """The mesh installed by ``jax.set_mesh``, or ``None`` outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty:
+        return None
+    return mesh

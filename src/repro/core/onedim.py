@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from .packing import pack_tril, tril_size, unpack_tril
 
 
@@ -86,17 +85,17 @@ def syrk_1d(A: jax.Array, mesh: jax.sharding.Mesh, axis: str = "x"
     f = functools.partial(syrk_1d_local, axis=axis, n_shards=nsh)
     spec_in = P(None, axis)
     spec_out = P(axis)
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=spec_in,
-                                 out_specs=spec_out))(A)
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=spec_in,
+                                     out_specs=spec_out))(A)
 
 
 def syr2k_1d(A: jax.Array, B: jax.Array, mesh: jax.sharding.Mesh,
              axis: str = "x") -> jax.Array:
     nsh = _axis_size(mesh, axis)
     f = functools.partial(syr2k_1d_local, axis=axis, n_shards=nsh)
-    return jax.jit(shard_map(f, mesh=mesh,
-                                 in_specs=(P(None, axis), P(None, axis)),
-                                 out_specs=P(axis)))(A, B)
+    return jax.jit(jax.shard_map(f, mesh=mesh,
+                                     in_specs=(P(None, axis), P(None, axis)),
+                                     out_specs=P(axis)))(A, B)
 
 
 def symm_1d(A_packed: jax.Array, B: jax.Array, n1: int,
@@ -104,9 +103,9 @@ def symm_1d(A_packed: jax.Array, B: jax.Array, n1: int,
     """C = A·B, A given as packed lower triangle (padded to multiple of P and
     sharded over ``axis``); B column-sharded.  Returns C column-sharded."""
     f = functools.partial(symm_1d_local, axis=axis, n1=n1)
-    return jax.jit(shard_map(f, mesh=mesh,
-                                 in_specs=(P(axis), P(None, axis)),
-                                 out_specs=P(None, axis)))(A_packed, B)
+    return jax.jit(jax.shard_map(f, mesh=mesh,
+                                     in_specs=(P(axis), P(None, axis)),
+                                     out_specs=P(None, axis)))(A_packed, B)
 
 
 # --------------------------------------------------------------------------

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
-from ..compat import shard_map
 from .dispatch import ring_nb
 from .packing import packed_to_tiles, tiles_to_packed
 
@@ -103,7 +102,7 @@ def syrk_ring(a_stage, mesh, axis: str = "x"):
                 slots.append(_mm_t(a_loc, buf))
         return jnp.stack(slots, axis=-3)[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=PartitionSpec(axis),
         out_specs=PartitionSpec(axis)))(a_stage)
 
@@ -145,7 +144,7 @@ def syr2k_ring(ab_stage, mesh, axis: str = "x"):
                 slots.append(_mm_t(a_loc, buf[1]) + _mm_t(b_loc, buf[0]))
         return jnp.stack(slots, axis=-3)[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=PartitionSpec(axis),
         out_specs=PartitionSpec(axis)))(ab_stage)
 
@@ -186,7 +185,7 @@ def symm_ring(slots_stage, b_stage, mesh, axis: str = "x"):
         ret = jax.lax.ppermute(buf[1], axis, perm=home)
         return (c_own + ret)[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(PartitionSpec(axis),
                                    PartitionSpec(axis)),
         out_specs=PartitionSpec(axis)))(slots_stage, b_stage)

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import pvary, shard_map
 from .twodim import (TwoDPlan, _exchange_rows, _syrk_blocks, make_2d_plan,
                      symm_2d_local, symm_2d_local_stacked, syr2k_2d_local,
                      syr2k_2d_local_stacked, syrk_2d_local,
@@ -48,7 +47,7 @@ def _pad_to(x: jax.Array, mult: int) -> jax.Array:
 
 def _varying(x: jax.Array, axes: Tuple[str, ...]) -> jax.Array:
     """Mark a constant as varying over manual axes (scan-carry vma rule)."""
-    return pvary(x, axes)
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def syrk_3d_local(a_own: jax.Array, plan: TwoDPlan, tb_axis: str,
@@ -182,7 +181,7 @@ def syrk_3d(a_dist: jax.Array, plan: TwoDPlan, mesh, tb_axis: str = "tb",
     def body(a):                       # a: (1, 1, c, nb, w2) per device
         return f(a[0, 0])[None, None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(tb_axis, rep_axis),
         out_specs=P(tb_axis, rep_axis)))(a_dist)
 
@@ -196,7 +195,7 @@ def syr2k_3d(a_dist, b_dist, plan: TwoDPlan, mesh, tb_axis="tb",
     def body(a, b):
         return f(a[0, 0], b[0, 0])[None, None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
         out_specs=P(tb_axis, rep_axis)))(a_dist, b_dist)
 
@@ -211,7 +210,7 @@ def symm_3d(a_flat, b_dist, plan: TwoDPlan, mesh, tb_axis="tb",
     def body(a, b):
         return f(a[0, 0], b[0, 0])[None, None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
         out_specs=P(tb_axis, rep_axis)))(a_flat, b_dist)
 
@@ -228,7 +227,7 @@ def syrk_3d_stacked(a_dist: jax.Array, plan: TwoDPlan, mesh,
     def body(a):
         return f(a[0, 0])[None, None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(tb_axis, rep_axis),
         out_specs=P(tb_axis, rep_axis)))(a_dist)
 
@@ -242,7 +241,7 @@ def syr2k_3d_stacked(a_dist, b_dist, plan: TwoDPlan, mesh, tb_axis="tb",
     def body(a, b):
         return f(a[0, 0], b[0, 0])[None, None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
         out_specs=P(tb_axis, rep_axis)))(a_dist, b_dist)
 
@@ -257,7 +256,7 @@ def symm_3d_stacked(a_flat, b_dist, plan: TwoDPlan, mesh, tb_axis="tb",
     def body(a, b):
         return f(a[0, 0], b[0, 0])[None, None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
         out_specs=P(tb_axis, rep_axis)))(a_flat, b_dist)
 
@@ -273,7 +272,7 @@ def syrk_3d_limited(a_chunks: jax.Array, plan: TwoDPlan, mesh,
     def body(a):                   # a: (1, 1, nsteps, c, nb, bw) per device
         return f(a[0, 0])[None, None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(tb_axis, rep_axis),
         out_specs=P(tb_axis, rep_axis)))(a_chunks)
 
@@ -287,7 +286,7 @@ def syr2k_3d_limited(a_chunks, b_chunks, plan: TwoDPlan, mesh,
     def body(a, b):
         return f(a[0, 0], b[0, 0])[None, None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
         out_specs=P(tb_axis, rep_axis)))(a_chunks, b_chunks)
 
@@ -303,7 +302,7 @@ def symm_3d_limited(a_flat, b_chunks, plan: TwoDPlan, mesh,
     def body(a, b):
         return f(a[0, 0], b[0, 0])[None, None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
         out_specs=P(tb_axis, rep_axis)))(a_flat, b_chunks)
 

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from .triangle import TrianglePartition, affine_partition
 
 
@@ -451,7 +450,7 @@ def syrk_2d(a_dist: jax.Array, plan: TwoDPlan, mesh, axis: str = "x"):
         off, diag = syrk_2d_local(a[0], plan, axis)
         return off[None], diag[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(axis),
         out_specs=(P(axis), P(axis))))(a_dist)
 
@@ -462,7 +461,7 @@ def syr2k_2d(a_dist: jax.Array, b_dist: jax.Array, plan: TwoDPlan, mesh,
         off, diag = syr2k_2d_local(a[0], b[0], plan, axis)
         return off[None], diag[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(axis), P(axis)),
         out_specs=(P(axis), P(axis))))(a_dist, b_dist)
 
@@ -472,7 +471,7 @@ def symm_2d(a_off: jax.Array, a_diag: jax.Array, b_dist: jax.Array,
     def body(ao, ad, b):
         return symm_2d_local(ao[0], ad[0], b[0], plan, axis)[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
         out_specs=P(axis)))(a_off, a_diag, b_dist)
 
@@ -485,7 +484,7 @@ def syrk_2d_stacked(a_dist: jax.Array, plan: TwoDPlan, mesh,
         off, diag = syrk_2d_local_stacked(a[0], plan, axis)
         return off[None], diag[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(axis),
         out_specs=(P(axis), P(axis))))(a_dist)
 
@@ -496,7 +495,7 @@ def syr2k_2d_stacked(a_dist: jax.Array, b_dist: jax.Array, plan: TwoDPlan,
         off, diag = syr2k_2d_local_stacked(a[0], b[0], plan, axis)
         return off[None], diag[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(axis), P(axis)),
         out_specs=(P(axis), P(axis))))(a_dist, b_dist)
 
@@ -509,7 +508,7 @@ def symm_2d_stacked(a_off: jax.Array, a_diag: jax.Array,
     def body(ao, ad, b):
         return symm_2d_local_stacked(ao[0], ad[0], b[0], plan, axis)[None]
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
         out_specs=P(axis)))(a_off, a_diag, b_dist)
 

@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..core.packing import PackedTriangle, pack_tril, tril_size, unpack_tril
 
 
@@ -191,7 +190,6 @@ def compressed_allreduce(x: jax.Array, mesh, axis: str = "data",
                                 tiled=False).reshape(nb, 1)
         return (q2.astype(jnp.float32) * s2)[None]
 
-    _smap = shard_map
     flat, pad = _pad_to(x, block)
     nb = flat.shape[0] // block
     # pad so the block count divides the axis
@@ -205,8 +203,8 @@ def compressed_allreduce(x: jax.Array, mesh, axis: str = "data",
     # in_specs=P() route replicated the input and every device
     # re-quantized the whole array).
     stack = jnp.broadcast_to(blocks[None], (naxis,) + blocks.shape)
-    out = _smap(inner, mesh=mesh, in_specs=P(axis),
-                out_specs=P(axis), check_vma=False)(stack)
+    out = jax.shard_map(inner, mesh=mesh, in_specs=P(axis),
+                        out_specs=P(axis), check_vma=False)(stack)
     n = 1
     for d in x.shape:
         n *= d

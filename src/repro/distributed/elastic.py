@@ -25,6 +25,7 @@ import jax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from ..compat import make_mesh
 from ..core.dispatch import fit_c_grid
 from ..core.packing import PackedTriangle, ShardedTriTiles, TriTiles
 
@@ -61,7 +62,7 @@ def plan_mesh(n_devices: Optional[int] = None, *, max_model: int = 16,
         n_devices = jax.device_count()
     data, model = plan_shape(n_devices, max_model=max_model,
                              model_divides=model_divides)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def wire_c(n_devices: Optional[int] = None) -> int:

@@ -16,7 +16,6 @@ from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.analysis.roofline import build_roofline  # noqa: E402
-from repro.compat import cost_analysis, use_mesh  # noqa: E402
 from repro.configs import ARCHS, get_config  # noqa: E402
 from repro.configs.shapes import (SHAPES, cell_applicable,  # noqa: E402
                                   input_specs)
@@ -113,14 +112,14 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             NamedSharding(mesh, bs["positions"]), c_shardings))
         args = (params_shape, specs["token"], specs["pos"], cache_shape)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     roof, coll = build_roofline(cost, hlo, chips)
 

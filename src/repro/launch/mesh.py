@@ -6,9 +6,7 @@ the 512-device XLA flag before any jax initialization.
 """
 from __future__ import annotations
 
-from typing import Optional
-
-import jax
+from repro.compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,16 +14,4 @@ def make_production_mesh(*, multi_pod: bool = False):
     (pod=2, data=16, model=16) = 512 chips (v5e pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(data: int, model: int, pod: int = 1):
-    """Arbitrary mesh for tests/examples (pod axis only when pod > 1)."""
-    if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
-
-
-def dp_axes(mesh) -> tuple:
-    """The data-parallel axes of a mesh (pod included when present)."""
-    return tuple(a for a in ("pod", "data") if a in mesh.shape)
+    return make_mesh(shape, axes)

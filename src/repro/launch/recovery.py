@@ -49,6 +49,7 @@ def _run_phase(ckpt_dir: str, ndev: int, extra_args: List[str],
                ) -> subprocess.CompletedProcess:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
+    env["JAX_PLATFORMS"] = "cpu"  # fake devices: never the chip
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     env.pop(faults.ENV_SPECS, None)            # phase 2 runs fault-free
     if extra_env:

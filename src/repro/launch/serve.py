@@ -48,6 +48,7 @@ import numpy as np
 
 from repro import blas
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.launch.serving_cache import ServingGramCache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models.model import init_cache, init_params
@@ -374,7 +375,9 @@ def build_argparser():
 
 
 def main(argv=None):
-    serve(build_argparser().parse_args(argv))
+    args = build_argparser().parse_args(argv)
+    enable_compilation_cache()
+    serve(args)
 
 
 if __name__ == "__main__":

@@ -40,7 +40,7 @@ from repro.distributed import (ErrorFeedbackInt8, StepTimer,
                                latest_step, plan_mesh, restore_checkpoint,
                                save_checkpoint, verify_restored,
                                wait_for_saves)
-from repro.compat import use_mesh
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.launch.steps import (describe_blas_routing, make_optimizer,
                                 make_train_step)
 from repro.models.model import init_params
@@ -92,37 +92,43 @@ def train(args) -> Dict[str, Any]:
         for line in describe_blas_routing(params_shape, mesh):
             print(line)
 
+    def init_state(params):
+        if compressor is None:
+            return opt.init(params)
+        return opt.init(params), compressor.init(params)
+
+    # the step is fed and returns state at exactly these shardings, so
+    # step 1 reuses step 0's executable
+    state_shape = jax.eval_shape(init_state, params_shape)
+    o_sh = _state_shardings(state_shape, params_shape, p_sh, mesh)
+
     # ---- init or resume -------------------------------------------------
     start_step = 0
     resumed = False
     if args.ckpt_dir and latest_step(args.ckpt_dir) is not None \
             and not args.fresh:
-        like = {"params": params_shape,
-                "opt": jax.eval_shape(opt.init, params_shape)}
+        like = {"params": params_shape}
         if compressor is not None:
-            like["ef"] = jax.eval_shape(compressor.init, params_shape)
+            like["opt"], like["ef"] = state_shape
+        else:
+            like["opt"] = state_shape
         start_step, state = restore_checkpoint(args.ckpt_dir, like)
         vr = verify_restored(args.ckpt_dir, state, step=start_step)
         print(f"[train] restore verified: {vr['checked']} leaves, "
               f"{len(vr['mismatches'])} mismatches")
         params = jax.device_put(state["params"], p_sh)
-        opt_state = jax.device_put(state["opt"], _rep_tree(
-            state["opt"], mesh, p_sh, params_shape))
-        if compressor is not None:
-            opt_state = (opt_state, jax.device_put(
-                state["ef"], _rep_tree(state["ef"], mesh, p_sh,
-                                       params_shape)))
+        opt_state = state["opt"] if compressor is None \
+            else (state["opt"], state["ef"])
+        opt_state = jax.device_put(opt_state, o_sh)
         resumed = True
         print(f"[train] resumed from step {start_step} "
               f"({args.ckpt_dir})")
     else:
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.jit(
                 lambda k: init_params(cfg, k),
                 out_shardings=p_sh)(jax.random.key(args.seed))
-        opt_state = jax.jit(opt.init)(params)
-        if compressor is not None:
-            opt_state = (opt_state, jax.jit(compressor.init)(params))
+        opt_state = jax.jit(init_state, out_shardings=o_sh)(params)
 
     # ---- data ------------------------------------------------------------
     dcfg = DataConfig(seq_len=args.seq_len, global_batch=args.global_batch,
@@ -132,13 +138,16 @@ def train(args) -> Dict[str, Any]:
     it = make_train_iterator(dcfg, start_step=start_step, sharding=b_sh,
                              frontend="tokens")
 
-    jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
+    jit_step = jax.jit(step_fn,
+                       in_shardings=(p_sh, o_sh, b_sh),
+                       out_shardings=(p_sh, o_sh, NamedSharding(mesh, P())),
+                       donate_argnums=(0, 1))
     monitor = StragglerMonitor(threshold=args.straggler_threshold)
     timer = StepTimer(monitor)
-    losses = []
+    losses, step_s = [], []
 
     t_train0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for step in range(start_step, args.steps):
             if args.fail_at is not None and step == args.fail_at \
                     and not resumed:
@@ -162,6 +171,7 @@ def train(args) -> Dict[str, Any]:
                                                       batch)
                 loss = float(metrics["loss"])
             losses.append(loss)
+            step_s.append(timer.last)
             if timer.event is not None:
                 print(f"[straggler] step {step}: {timer.event.action} "
                       f"({timer.event.ratio:.1f}x median)")
@@ -185,6 +195,10 @@ def train(args) -> Dict[str, Any]:
            "first_loss": losses[0] if losses else None,
            "mean_step_s": (time.time() - t_train0)
            / max(args.steps - start_step, 1),
+           "step_s": step_s,
+           # one entry per distinct input signature: a step fed state at
+           # other shardings than it returned would add a second
+           "step_compiles": jit_step._cache_size(),
            "straggler_events": len(monitor.events),
            "resumed": resumed, "mesh": dict(mesh.shape)}
     if args.ckpt_dir:
@@ -193,19 +207,24 @@ def train(args) -> Dict[str, Any]:
     return out
 
 
-def _rep_tree(state, mesh, p_sh, params_shape):
-    """Optimizer-state shardings: param-shaped leaves inherit the param
-    sharding, everything else is replicated."""
+def _state_shardings(state_shape, params_shape, p_sh, mesh):
+    """Optimizer-state shardings: in every subtree that mirrors the params
+    tree (moments, momentum, error feedback), a leaf of its param's shape
+    takes the param's sharding; every other leaf (quantized blocks and
+    their scales, counters, packed Grams) is replicated."""
+    p_def = jax.tree.structure(params_shape)
     rep = NamedSharding(mesh, P())
-    flat_p = [(tuple(x.shape), s) for x, s in
-              zip(jax.tree.leaves(params_shape), jax.tree.leaves(p_sh))]
-    by_shape = {}
-    for shp, s in flat_p:
-        by_shape.setdefault(shp, s)
 
-    def pick(x):
-        return by_shape.get(tuple(np.shape(x)), rep)
-    return jax.tree.map(pick, state)
+    def mirrors_params(x):
+        return jax.tree.structure(x) == p_def
+
+    def pick(sub):
+        if not mirrors_params(sub):
+            return jax.tree.map(lambda _: rep, sub)
+        return jax.tree.map(lambda x, p, s: s if x.shape == p.shape else rep,
+                            sub, params_shape, p_sh)
+
+    return jax.tree.map(pick, state_shape, is_leaf=mirrors_params)
 
 
 def _save(args, step, params, opt_state, compressor, blocking=False):
@@ -258,6 +277,7 @@ def build_argparser():
 
 def main(argv=None):
     args = build_argparser().parse_args(argv)
+    enable_compilation_cache()
     train(args)
 
 

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import get_ambient_mesh, shard_map
+from ..compat import get_ambient_mesh
 from .common import ArchConfig, MoECfg, Params, dense_init, split_keys
 
 
@@ -315,11 +315,11 @@ def moe_apply(cfg: ArchConfig, p: Params, x: jax.Array,
         if "wg" in p["shared"]:
             shared["wg"] = P(None, "model")
         p_specs["shared"] = shared
-    fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(ba if ba else None, None, None),
-                                 p_specs),
-                       out_specs=P(ba if ba else None, None, None),
-                       check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(ba if ba else None, None, None),
+                                     p_specs),
+                           out_specs=P(ba if ba else None, None, None),
+                           check_vma=False)
     from jax.ad_checkpoint import checkpoint_name
     return checkpoint_name(fn(x, {k_: p[k_] for k_ in p_specs}),
                            "scan_out")

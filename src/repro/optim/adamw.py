@@ -4,6 +4,7 @@ optimizer-state HBM, which is what lets the ≥200B archs fit train_4k on a
 256-chip pod (see EXPERIMENTS §Dry-run memory notes)."""
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, NamedTuple, Optional, Tuple
 
@@ -40,7 +41,7 @@ class AdamW:
 
     def _dq(self, q: jax.Array, scale: jax.Array, shape) -> jax.Array:
         flat = (q.astype(jnp.float32) * scale).reshape(-1)
-        return flat[:int(jnp.prod(jnp.asarray(shape)))].reshape(shape)
+        return flat[:math.prod(shape)].reshape(shape)
 
     # -- api --------------------------------------------------------------
     def init(self, params: Any) -> AdamWState:

@@ -31,7 +31,6 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from .. import blas
-from ..compat import shard_map
 from ..core.onedim import syrk_1d_local
 from ..core.packing import (PackedTriangle, pack_tril, tril_size,
                             unpack_tril)
@@ -85,16 +84,17 @@ def orthogonalize_reference(g: jax.Array, steps: int = 5,
                             mesh: Optional[Mesh] = None,
                             axis: Optional[str] = None,
                             gram_chunk: Optional[int] = None) -> jax.Array:
-    """NS orthogonalization of a (m, n) matrix, operating on the short
-    side; returns an approximately semi-orthogonal matrix."""
-    transpose = g.shape[0] > g.shape[1]
-    x = g.T if transpose else g
+    """NS orthogonalization of a (..., m, n) matrix (leading stack dims
+    allowed), operating on the short side; returns an approximately
+    semi-orthogonal matrix per stacked slice."""
+    transpose = g.shape[-2] > g.shape[-1]
+    x = g.swapaxes(-1, -2) if transpose else g
     x = x.astype(jnp.float32)
-    x = x / (jnp.linalg.norm(x) + 1e-7)
+    x = x / (jnp.linalg.norm(x, axis=(-2, -1), keepdims=True) + 1e-7)
     x = jax.lax.fori_loop(
         0, steps,
         lambda _, v: ns_iteration_reference(v, mesh, axis, gram_chunk), x)
-    return (x.T if transpose else x).astype(g.dtype)
+    return (x.swapaxes(-1, -2) if transpose else x).astype(g.dtype)
 
 
 def _ns_iteration_1d_local(x_loc: jax.Array, axis: str, n_shards: int
@@ -170,7 +170,7 @@ def orthogonalize_1d(g: jax.Array, mesh: Mesh, axis: str = "model",
         return one(x_loc)
 
     spec = P(*([None] * (g.ndim - 1) + [axis]))
-    fn = shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)
     return fn(g)
 
 
@@ -178,8 +178,9 @@ def orthogonalize_1d(g: jax.Array, mesh: Mesh, axis: str = "model",
 # optimizer
 # ---------------------------------------------------------------------------
 def _is_matrix(p: jax.Array) -> bool:
-    """Muon applies to true 2D weight matrices; ≤1D (norms, biases) and
-    stacked-expert 3D params are handled by vmapping the trailing 2D."""
+    """Muon applies to true 2D weight matrices (≤1D norms and biases
+    take the fallback); stacked 3D params orthogonalize per trailing 2D
+    slice."""
     return p.ndim >= 2 and min(p.shape[-2:]) >= 8
 
 
@@ -247,18 +248,14 @@ class Muon:
                 out = orthogonalize_1d(x, self.mesh, self.axis,
                                        self.ns_steps)
                 return out.swapaxes(-1, -2) if transpose else out
-        if m2.ndim > 2:
-            # stacked params vmap the NS chain: collectives don't vmap,
-            # so no mesh here (blas routes dense/pallas per merits)
-            flat = m2.reshape((-1,) + m2.shape[-2:])
-            o = jax.vmap(lambda t: orthogonalize_reference(
-                t, self.ns_steps, gram_chunk=self.gram_chunk))(flat)
-            return o.reshape(m2.shape)
         mesh, axis = None, None
         if self.mesh is not None and self.axis in self.mesh.shape:
             # reference mode on a mesh: let the blas router pick the
             # comm-optimal schedule per (shape, P) instead of a manual
-            # shard_map — forward and (custom-VJP) backward both routed
+            # shard_map — forward and (custom-VJP) backward both routed;
+            # stacked params ride the batch-native mesh wires (a Pallas
+            # kernel cannot be partitioned by GSPMD, so a meshless call
+            # inside a multi-device step would not compile on TPU)
             mesh, axis = self.mesh, self.axis
         return orthogonalize_reference(m2, self.ns_steps, mesh, axis,
                                        gram_chunk=self.gram_chunk)

@@ -16,8 +16,8 @@ import numpy as np
 
 
 def _mesh(shape, names):
-    import jax
-    return jax.make_mesh(shape, names)
+    from repro.compat import make_mesh
+    return make_mesh(shape, names)
 
 
 def check_1d(P: int) -> None:
@@ -296,13 +296,15 @@ def check_blas_grad() -> None:
 
     # batched operands on a mesh (stacked packed triangles on the 1D
     # wire) still differentiate and match the meshless gradient for
-    # every fill
+    # every fill (fixed linear weights, as above: a squared loss feeds
+    # the forward's accumulation-order noise into the cotangent)
     Ab = jnp.asarray(rng.standard_normal((2, 16, 64)), jnp.float32)
     for fill in ("tril", "full", "packed"):
+        Wb = jnp.stack([W[fill], -W[fill]])
         gm = jax.grad(lambda x: jnp.sum(
-            blas.syrk(x, fill=fill, mesh=mesh) ** 2))(Ab)
+            Wb * blas.syrk(x, fill=fill, mesh=mesh)))(Ab)
         gd = jax.grad(lambda x: jnp.sum(
-            blas.syrk(x, fill=fill) ** 2))(Ab)
+            Wb * blas.syrk(x, fill=fill)))(Ab)
         cmp(gm, gd)
     print("  grad parity for batched operands on the mesh")
 

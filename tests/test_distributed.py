@@ -14,6 +14,7 @@ from repro.distributed import (ErrorFeedbackInt8, StragglerMonitor,
                                quantize_int8, reshard_tree,
                                restore_checkpoint, save_checkpoint,
                                wait_for_saves)
+from repro.compat import make_mesh
 from repro.distributed.compression import wire_bytes_per_device
 from repro.distributed.elastic import spec_tree_like, validate_divisibility
 
@@ -178,13 +179,13 @@ def test_reshard_roundtrip_smaller_world(tmp_path):
     """Save on mesh A, restore & reshard on mesh B (elastic restart)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     ndev = jax.device_count()
-    mesh_a = jax.make_mesh((ndev,), ("model",))
+    mesh_a = make_mesh((ndev,), ("model",))
     x = jnp.arange(ndev * 4.0).reshape(ndev, 4)
     xa = jax.device_put(x, NamedSharding(mesh_a, P("model", None)))
     save_checkpoint(str(tmp_path), 1, {"x": xa})
 
     half = max(ndev // 2, 1)
-    mesh_b = jax.make_mesh((half,), ("model",))
+    mesh_b = make_mesh((half,), ("model",))
     _, back = restore_checkpoint(str(tmp_path),
                                  jax.eval_shape(lambda: {"x": x}))
     placed = reshard_tree(back, {"x": P("model", None)}, mesh_b)
@@ -308,7 +309,7 @@ def test_error_feedback_accumulates_residual():
 
 @pytest.mark.skipif(jax.device_count() < 2, reason="needs >1 device")
 def test_compressed_allreduce_matches_mean():
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     x = jax.random.normal(jax.random.key(1), (512,))
     out = compressed_allreduce(x, mesh, axis="data", block=128)
     # every device contributed the same x -> mean == x
@@ -355,7 +356,7 @@ def test_error_feedback_typed_packed_leaf():
 def test_compressed_allreduce_sym_matches_mean():
     from repro.core.packing import PackedTriangle
     from repro.distributed import compressed_allreduce_sym
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     s = _sym(24, 6)
     out = compressed_allreduce_sym(s, mesh, axis="data", block=64)
     got = np.asarray(out)

@@ -35,6 +35,18 @@ def test_train_restart_bit_exact(tmp_path):
                                                   abs=0.0)
 
 
+@pytest.mark.parametrize("optimizer,compress", [
+    ("adamw", False), ("muon", False), ("adamw8bit", False),
+    ("adamw", True)], ids=["adamw", "muon", "adamw8bit", "adamw-int8grads"])
+def test_train_step_compiles_once(optimizer, compress):
+    """Params and optimizer state come back at the shardings the step
+    was fed, so steps 1.. reuse step 0's executable."""
+    kw = {"compress_grads": True} if compress else {}
+    out = train(_train_args(steps=3, optimizer=optimizer, **kw))
+    assert out["step_compiles"] == 1
+    assert len(out["step_s"]) == 3
+
+
 def test_train_with_compression():
     out = train(_train_args(steps=20, compress_grads=True))
     assert out["final_loss"] < out["first_loss"]

@@ -140,7 +140,7 @@ sys.path.insert(0, %r)
 from repro.optim.gram import packed_gram
 from repro.core.packing import unpack_tril
 from repro.compat import make_mesh
-mesh = make_mesh((4,), ("model",), axis_types="auto")
+mesh = make_mesh((4,), ("model",))
 x = jax.random.normal(jax.random.key(0), (16, 128))
 g = packed_gram(x, mesh)
 dense = unpack_tril(g, 16, diag=True, symmetric=True)

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.analysis.hlo_cost import analyze_hlo
-from repro.compat import cost_analysis, use_mesh
+from repro.compat import make_mesh
 
 
 def _mlp_body(h, w):
@@ -33,8 +33,8 @@ def test_dot_flops_match_unrolled_cost_analysis():
     comp_flat = _lower(unroll=N_LAYERS)
 
     mine = analyze_hlo(comp_loop.as_text())
-    xla_flat = cost_analysis(comp_flat)
-    xla_loop = cost_analysis(comp_loop)
+    xla_flat = comp_flat.cost_analysis()
+    xla_loop = comp_loop.cost_analysis()
 
     expected_dot_flops = N_LAYERS * 2 * B * D * D
     # XLA undercounts the loop version by ~N_LAYERS:
@@ -51,14 +51,14 @@ def test_bytes_scale_with_trip_count():
     comp_loop = _lower(unroll=1)
     comp_flat = _lower(unroll=N_LAYERS)
     mine = analyze_hlo(comp_loop.as_text())
-    xla_flat = cost_analysis(comp_flat)
+    xla_flat = comp_flat.cost_analysis()
     # bytes: our traffic model counts operands+results per op — the
     # unrolled XLA count should agree within 2x (fusion boundaries differ)
     assert mine.bytes_accessed == pytest.approx(
         xla_flat["bytes accessed"], rel=1.0)
     # and must be ~N_LAYERS larger than the naive loop-body-once count
     xla_loop = comp_flat  # noqa: F841
-    assert mine.bytes_accessed > 2.5 * cost_analysis(comp_loop)[
+    assert mine.bytes_accessed > 2.5 * comp_loop.cost_analysis()[
         "bytes accessed"]
 
 
@@ -76,8 +76,7 @@ def test_collectives_multiplied_by_trip_count():
     if jax.device_count() < 2:
         pytest.skip("needs >= 2 devices")
     ndev = jax.device_count()
-    mesh = jax.make_mesh((ndev,), ("model",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
+    mesh = make_mesh((ndev,), ("model",))
 
     def body(h, w):
         y = h @ w                       # w col-sharded -> partial sums
@@ -92,7 +91,7 @@ def test_collectives_multiplied_by_trip_count():
     T = 5
     x = jax.ShapeDtypeStruct((8, D), jnp.float32)
     ws = jax.ShapeDtypeStruct((T, D, D), jnp.float32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         comp = jax.jit(
             f, in_shardings=(NamedSharding(mesh, P()),
                              NamedSharding(mesh, P(None, "model", None))),

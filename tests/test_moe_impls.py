@@ -103,10 +103,9 @@ p = _params(cfg, jax.random.key(0))
 x = jax.random.normal(jax.random.key(1), (4, 64, cfg.d_model), jnp.float32)
 y_local = moe.moe_apply(cfg, p, x, impl="gather")
 
-from repro.compat import make_mesh, use_mesh
-mesh = make_mesh((2, 2), ("data", "model"),
-                 axis_types="auto")
-with use_mesh(mesh):
+from repro.compat import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
+with jax.set_mesh(mesh):
     y_tp = jax.jit(lambda pp, xx: moe.moe_apply(cfg, pp, xx,
                                                 impl="gather"))(p, x)
 np.testing.assert_allclose(np.asarray(y_tp), np.asarray(y_local),

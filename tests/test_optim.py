@@ -69,6 +69,12 @@ def test_muon_stacked_params():
     grads = jax.tree.map(jnp.ones_like, params)
     new_params, _ = opt.update(grads, state, params)
     assert new_params["periods"].shape == (3, 16, 32)
+    # each stacked slice is orthogonalized on its own
+    want = jnp.stack([orthogonalize_reference(grads["periods"][i])
+                      for i in range(3)])
+    np.testing.assert_allclose(
+        np.asarray(params["periods"] - new_params["periods"]),
+        np.asarray(0.02 * want), rtol=1e-5, atol=1e-6)
 
 
 def test_1d_ns_matches_reference_subprocess():
@@ -77,15 +83,24 @@ def test_1d_ns_matches_reference_subprocess():
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     code = """
 import jax, jax.numpy as jnp, numpy as np
+from repro.compat import make_mesh
 from repro.optim import orthogonalize_1d, orthogonalize_reference
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 g = jax.random.normal(jax.random.key(0), (24, 64), jnp.float32)
 ref = orthogonalize_reference(g, steps=5)
 got = orthogonalize_1d(g, mesh, "model", steps=5)
 np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-3, atol=2e-3)
 print("OK muon-1d")
+# stacked slices ride the batched mesh wires and match per-slice NS
+gs = jax.random.normal(jax.random.key(1), (3, 2, 24, 64), jnp.float32)
+want = jnp.stack([jnp.stack([orthogonalize_reference(gs[i, j], steps=5)
+                             for j in range(2)]) for i in range(3)])
+got = orthogonalize_reference(gs, steps=5, mesh=mesh, axis="model")
+np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
+print("OK muon-stacked-mesh")
 """
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env, timeout=300)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "OK muon-1d" in out.stdout
+    assert "OK muon-stacked-mesh" in out.stdout

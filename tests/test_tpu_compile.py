@@ -1,0 +1,77 @@
+"""Ahead-of-time compiles of the Pallas symmetric kernels for a TPU v5e.
+
+The TPU compiler is installed even where no chip is attached, so each
+test compiles the routed ``repro.blas`` call for one chip of a described
+``v5e:2x2`` at stablelm-1.6b's d_ff weight shape (the Newton–Schulz Gram
+and update of a 2048x5632 weight) and checks that the program carries
+the Mosaic kernel.  That catches what interpret mode cannot: block
+shapes the TPU tiling refuses, VMEM overflow, unsupported ops.  Nothing
+runs, so results and times are not checked here.
+
+The module holds libtpu's compiler in its process; it is kept on one
+xdist worker (the ``xdist_group`` mark under ``--dist loadgroup``, and
+the module itself under ``--dist loadfile``).
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro import blas
+
+N1, N2 = 2048, 5632
+KERNEL = dict(tile=(128, 128), interpret=False)
+
+pytestmark = pytest.mark.xdist_group("libtpu")
+
+OPS = {
+    "syrk": (lambda a: blas.syrk(a, fill="full", **KERNEL), ("a",)),
+    "syr2k": (lambda a, b: blas.syr2k(a, b, fill="full", **KERNEL),
+              ("a", "b")),
+    "symm": (lambda s, b: blas.symm(s, b, **KERNEL), ("s", "b")),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax._src import xla_bridge
+    from jax.experimental import topologies
+    if xla_bridge.get_tpu_library_path() is None:
+        pytest.skip("no TPU compiler (libtpu) is installed")
+    # compiling needs no chip, so another process holding libtpu's lock
+    # (a chip job, a second test run) must not stop this one
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep the cache out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("mode", ["fwd", "grad"])
+@pytest.mark.parametrize("op", sorted(OPS))
+def test_kernel_compiles_for_v5e(one_chip, op, mode, dtype):
+    call, names = OPS[op]
+    shapes = {"a": (N1, N2), "b": (N1, N2), "s": (N1, N1)}
+    args = [jax.ShapeDtypeStruct(shapes[n], dtype, sharding=one_chip)
+            for n in names]
+    fn = call if mode == "fwd" else jax.grad(
+        lambda *xs: jnp.sum(call(*xs)), tuple(range(len(names))))
+    with blas.capture_routes() as log:
+        lowered = jax.jit(fn).lower(*args)
+    assert log and all(r.path == "pallas" for r in log), log
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
