@@ -281,12 +281,29 @@ def _apply_batched(fn, *arrays, trailing=None):
 # --------------------------------------------------------------------------
 # per-route executors (primal bodies; grad.py wraps these in custom_vjp)
 # --------------------------------------------------------------------------
+def route_scope(route: Route) -> str:
+    """``blas.<op>.<path family>``: the named scope every executor runs
+    under, so a device op of the call names its op and route in its HLO
+    metadata (``3d-limited`` is of the ``3d`` family)."""
+    return f"blas.{route.op}.{route.path.split('-')[0]}"
+
+
+def _scoped(execute):
+    """Run a route executor under :func:`route_scope` of its route."""
+    @functools.wraps(execute)
+    def run(*args, route: Route, **kw):
+        with jax.named_scope(route_scope(route)):
+            return execute(*args, route=route, **kw)
+    return run
+
+
 def _scale_sharded(st: ShardedTriTiles, alpha: float) -> ShardedTriTiles:
     if alpha == 1.0:
         return st
     return ShardedTriTiles(alpha * st.off, alpha * st.diag, st.n, st.c)
 
 
+@_scoped
 def _execute_syrk(a32: jax.Array, c32: Optional[jax.Array], *, fill: str,
                   alpha: float, beta: float, route: Route, mesh,
                   interpret: Optional[bool],
@@ -296,9 +313,10 @@ def _execute_syrk(a32: jax.Array, c32: Optional[jax.Array], *, fill: str,
     if fill == "sharded" and route.path not in grid_paths:
         # off-grid routes produce the packed triangle; one block-granular
         # scatter puts it into the mesh-resident layout
-        packed = _execute_syrk(a32, None, fill="packed", alpha=alpha,
-                               beta=0.0, route=route, mesh=mesh,
-                               interpret=interpret, out_dtype=out_dtype)
+        # (already inside this route's scope)
+        packed = _execute_syrk.__wrapped__(
+            a32, None, fill="packed", alpha=alpha, beta=0.0, route=route,
+            mesh=mesh, interpret=interpret, out_dtype=out_dtype)
         return ShardedTriTiles.from_packed(packed, n1,
                                            _sharded_grid_c(route))
     if route.path == "1d":
@@ -353,6 +371,7 @@ def _execute_syrk(a32: jax.Array, c32: Optional[jax.Array], *, fill: str,
     return _combine_fill(_syrk_dense(a32, fill), c32, alpha, beta, fill)
 
 
+@_scoped
 def _execute_syr2k(a32: jax.Array, b32: jax.Array,
                    c32: Optional[jax.Array], *, fill: str, alpha: float,
                    beta: float, route: Route, mesh,
@@ -365,10 +384,10 @@ def _execute_syr2k(a32: jax.Array, b32: jax.Array,
                              scale=diag_scale)
     grid_paths = ("2d", "3d", "3d-limited")
     if fill == "sharded" and route.path not in grid_paths:
-        packed = _execute_syr2k(a32, b32, None, fill="packed", alpha=alpha,
-                                beta=0.0, route=route, mesh=mesh,
-                                interpret=interpret, out_dtype=out_dtype,
-                                diag_scale=diag_scale)
+        packed = _execute_syr2k.__wrapped__(
+            a32, b32, None, fill="packed", alpha=alpha, beta=0.0,
+            route=route, mesh=mesh, interpret=interpret,
+            out_dtype=out_dtype, diag_scale=diag_scale)
         return ShardedTriTiles.from_packed(packed, n1,
                                            _sharded_grid_c(route))
     if route.path == "1d":
@@ -432,6 +451,7 @@ def _execute_syr2k(a32: jax.Array, b32: jax.Array,
                               beta, fill))
 
 
+@_scoped
 def _execute_symm(a32: Union[jax.Array, TriTiles, ShardedTriTiles],
                   b32: jax.Array, *,
                   route: Route, mesh, interpret: Optional[bool],

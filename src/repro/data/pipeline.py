@@ -13,7 +13,10 @@ Design mirrors a production loader:
     packed into fixed ``seq_len`` rows with EOS separators, the standard
     LM pretraining treatment (no padding waste).
   * **Async prefetch** — a background thread keeps ``prefetch`` batches
-    ready so host data work overlaps device compute.
+    ready so host data work overlaps device compute.  In a profiler
+    trace its spans are ``repro.data.produce`` (synthesis and packing)
+    and ``repro.data.put`` (``device_put``); the consumer's blocking
+    wait is ``repro.data.wait``.
 
 The synthetic distribution is a small LCG-mixed Markov stream — cheap,
 seekable, and with enough temporal structure that a model's loss visibly
@@ -28,6 +31,7 @@ from typing import Any, Dict, Iterator, List, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.common import ArchConfig
 
@@ -202,6 +206,17 @@ def make_train_iterator(cfg: DataConfig, *, start_step: int = 0,
         it.seek(start_step)
 
     def produce() -> Dict[str, Any]:
+        with TraceAnnotation("repro.data.produce"):
+            batch = synthesize()
+        if sharding is not None:
+            with TraceAnnotation("repro.data.put"):
+                batch = {k: jax.device_put(v, sharding[k])
+                         if isinstance(sharding, dict)
+                         else jax.device_put(v, sharding)
+                         for k, v in batch.items()}
+        return batch
+
+    def synthesize() -> Dict[str, np.ndarray]:
         batch = next(it)
         if frontend == "embeddings":
             toks = batch.pop("tokens")
@@ -212,11 +227,6 @@ def make_train_iterator(cfg: DataConfig, *, start_step: int = 0,
                    % np.uint64(2048)).astype(np.float32)
             batch["embeds"] = ((emb / 1024.0 - 1.0) * scale) \
                 .astype(np.float32)
-        if sharding is not None:
-            batch = {k: jax.device_put(v, sharding[k])
-                     if isinstance(sharding, dict)
-                     else jax.device_put(v, sharding)
-                     for k, v in batch.items()}
         return batch
 
     q: "queue.Queue" = queue.Queue(maxsize=cfg.prefetch)
@@ -241,7 +251,8 @@ def make_train_iterator(cfg: DataConfig, *, start_step: int = 0,
             return self
 
         def __next__(self):
-            return q.get()
+            with TraceAnnotation("repro.data.wait"):
+                return q.get()
 
         def close(self):
             stop.set()

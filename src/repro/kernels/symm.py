@@ -59,5 +59,6 @@ def symm_tiles(a_packed: jax.Array, b: jax.Array, *, bm: int = 128,
     scaled by ``diag_scale`` (the in-kernel cotangent prologue)."""
     body = _symm_body if diag_scale == 1.0 else \
         functools.partial(_symm_body, diag_scale=diag_scale)
-    return trigrid.sym_stream(body, a_packed, b, bm=bm, bn=bn,
-                              interpret=interpret, out_dtype=out_dtype)
+    return trigrid.sym_stream(body, a_packed, b, name="symm", bm=bm,
+                              bn=bn, interpret=interpret,
+                              out_dtype=out_dtype)

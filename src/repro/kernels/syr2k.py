@@ -38,6 +38,6 @@ def syr2k_tiles(a: jax.Array, b: jax.Array, *, bm: int = 128,
                           accumulate=c0 is not None and beta != 0.0,
                           out_dtype=out_dtype, diag_scale=diag_scale)
     return trigrid.rank_update(_syr2k_body, (a, b, b, a), "ijij",
-                               bm=bm, bk=bk, interpret=interpret,
-                               epilogue=ep,
+                               name="syr2k", bm=bm, bk=bk,
+                               interpret=interpret, epilogue=ep,
                                c0=c0 if ep.accumulate else None)

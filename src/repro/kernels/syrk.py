@@ -46,6 +46,7 @@ def syrk_tiles(a: jax.Array, *, bm: int = 128, bk: int = 128,
     ep = trigrid.Epilogue(alpha=alpha, beta=beta,
                           accumulate=c0 is not None and beta != 0.0,
                           out_dtype=out_dtype)
-    return trigrid.rank_update(_syrk_body, (a, a), "ij", bm=bm, bk=bk,
-                               interpret=interpret, epilogue=ep,
+    return trigrid.rank_update(_syrk_body, (a, a), "ij", name="syrk",
+                               bm=bm, bk=bk, interpret=interpret,
+                               epilogue=ep,
                                c0=c0 if ep.accumulate else None)

@@ -149,7 +149,8 @@ def _rank_update_kernel(im_ref, jm_ref, *refs, nk: int, bm: int, n_in: int,
 
 
 def rank_update(body: Callable, operands: Sequence[jax.Array], rows: str, *,
-                bm: int, bk: int, interpret: Optional[bool] = None,
+                name: str, bm: int, bk: int,
+                interpret: Optional[bool] = None,
                 epilogue: Optional[Epilogue] = None,
                 c0: Optional[jax.Array] = None) -> jax.Array:
     """Run a symmetric rank-update over the flat lower-triangle grid.
@@ -157,10 +158,12 @@ def rank_update(body: Callable, operands: Sequence[jax.Array], rows: str, *,
     ``operands``: (n1, n2) panels streamed as (bm, bk) blocks; ``rows``
     is one char per operand — 'i' streams row-block imap[t], 'j' streams
     jmap[t].  ``body(*panels) -> (bm, bm)`` f32 contribution of one
-    contraction step.  ``c0``: packed tiles (T, bm, bm) consumed by the
-    epilogue's beta-accumulate.  Returns packed tiles (T, bm, bm) in
-    ``epilogue.out_dtype`` with diagonal tiles lower-masked — the final
-    HBM layout, no post-hoc XLA fixup required.
+    contraction step; ``name`` names the kernel (``"syrk"``,
+    ``"syr2k"``) in the jaxpr, the HLO and the profiler.  ``c0``: packed
+    tiles (T, bm, bm) consumed by the epilogue's beta-accumulate.
+    Returns packed tiles (T, bm, bm) in ``epilogue.out_dtype`` with
+    diagonal tiles lower-masked — the final HBM layout, no post-hoc XLA
+    fixup required.
     """
     ep = epilogue or Epilogue()
     interpret = resolve_interpret(interpret)
@@ -198,7 +201,7 @@ def rank_update(body: Callable, operands: Sequence[jax.Array], rows: str, *,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, bm, bm), ep.out_dtype),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(jnp.asarray(imap), jnp.asarray(jmap), *inputs)
 
 
@@ -222,7 +225,8 @@ def _sym_stream_kernel(flat_ref, mode_ref, a_ref, b_ref, o_ref, acc_ref, *,
 
 
 def sym_stream(body: Callable, a_tiles: jax.Array, b: jax.Array, *,
-               bm: int, bn: int, interpret: Optional[bool] = None,
+               name: str, bm: int, bn: int,
+               interpret: Optional[bool] = None,
                out_dtype=jnp.float32) -> jax.Array:
     """Run a symmetric-times-dense product with A stored as packed tiles.
 
@@ -232,7 +236,8 @@ def sym_stream(body: Callable, a_tiles: jax.Array, b: jax.Array, *,
     cached scalar-prefetch table and ``body(a_tile, mode, b_panel)``
     returns the (bm, bn) f32 contribution (mode 0/1/2 selects
     as-is / transpose / diagonal-symmetrize).  Output is (n1, n2) in
-    ``out_dtype``, cast in-kernel.
+    ``out_dtype``, cast in-kernel; ``name`` names the kernel
+    (``"symm"``).
     """
     interpret = resolve_interpret(interpret)
     n1, n2 = b.shape
@@ -258,5 +263,5 @@ def sym_stream(body: Callable, a_tiles: jax.Array, b: jax.Array, *,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n1, n2), out_dtype),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(jnp.asarray(flat), jnp.asarray(mode), a_tiles, b)

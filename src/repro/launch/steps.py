@@ -97,7 +97,8 @@ def make_train_step(cfg: ArchConfig, optimizer, *, microbatches: int = 1,
 
             def acc_fn(carry, mbatch):
                 loss_sum, gacc = carry
-                loss, g = jax.value_and_grad(loss_fn)(params, mbatch)
+                with jax.named_scope("train.loss"):
+                    loss, g = jax.value_and_grad(loss_fn)(params, mbatch)
                 gacc = jax.tree.map(
                     lambda a, b_: a + b_.astype(jnp.float32), gacc, g)
                 return (loss_sum + loss, gacc), None
@@ -109,9 +110,11 @@ def make_train_step(cfg: ArchConfig, optimizer, *, microbatches: int = 1,
             loss = loss / microbatches
             grads = jax.tree.map(lambda g: g / microbatches, grads)
         else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            with jax.named_scope("train.loss"):
+                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
 
-        grads, gnorm = _clip_by_global_norm(grads, clip_norm)
+        with jax.named_scope("train.clip"):
+            grads, gnorm = _clip_by_global_norm(grads, clip_norm)
         if compressor is not None:
             inner, ef = opt_state
             grads, ef = compressor.compress(grads, ef)

@@ -24,7 +24,6 @@ import argparse
 import dataclasses
 import json
 import sys
-import time
 from typing import Any, Dict
 
 import jax
@@ -146,7 +145,6 @@ def train(args) -> Dict[str, Any]:
     timer = StepTimer(monitor)
     losses, step_s = [], []
 
-    t_train0 = time.time()
     with jax.set_mesh(mesh):
         for step in range(start_step, args.steps):
             if args.fail_at is not None and step == args.fail_at \
@@ -193,8 +191,6 @@ def train(args) -> Dict[str, Any]:
            "steps": args.steps - start_step,
            "final_loss": losses[-1] if losses else None,
            "first_loss": losses[0] if losses else None,
-           "mean_step_s": (time.time() - t_train0)
-           / max(args.steps - start_step, 1),
            "step_s": step_s,
            # one entry per distinct input signature: a step fed state at
            # other shardings than it returned would add a second

@@ -179,7 +179,17 @@ def _remat_policy(cfg: ArchConfig):
 def forward(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
             cache: Optional[Params] = None, remat: bool = True
             ) -> Tuple[jax.Array, Optional[Params]]:
-    x = _embed_input(cfg, params, batch)
+    with jax.named_scope("model.embed"):
+        x = _embed_input(cfg, params, batch)
+    with jax.named_scope("model.blocks"):
+        x, out_cache = _blocks(cfg, params, batch, x, cache, remat)
+    return x, out_cache
+
+
+def _blocks(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
+            x: jax.Array, cache: Optional[Params], remat: bool
+            ) -> Tuple[jax.Array, Optional[Params]]:
+    """The prefix blocks, the scanned periods and the final norm."""
     b, s, _ = x.shape
     positions = batch.get("positions")
     if positions is None:
@@ -236,6 +246,12 @@ def _xent_chunk(cfg: ArchConfig, w: jax.Array, x: jax.Array,
 def lm_loss(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
             chunk: int = 512, remat: bool = True) -> jax.Array:
     x, _ = forward(cfg, params, batch, remat=remat)
+    with jax.named_scope("model.head"):
+        return _head_loss(cfg, params, batch, x, chunk, remat)
+
+
+def _head_loss(cfg: ArchConfig, params: Params, batch: Dict[str, Any],
+               x: jax.Array, chunk: int, remat: bool) -> jax.Array:
     labels = batch["labels"]
     w = _unembed(cfg, params)
     b, s, d = x.shape

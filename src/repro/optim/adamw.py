@@ -57,6 +57,7 @@ class AdamW:
         return AdamWState(step=jnp.zeros((), jnp.int32), m=m, v=m,
                           m_scale=s, v_scale=s)
 
+    @jax.named_scope("optim.adamw")
     def update(self, grads: Any, state: AdamWState, params: Any,
                lr_scale: jax.Array = 1.0) -> Tuple[Any, AdamWState]:
         step = state.step + 1

@@ -69,15 +69,18 @@ def ns_iteration_reference(x: jax.Array, mesh: Optional[Mesh] = None,
     wide X the (m, n) slab never needs to be live all at once."""
     a, b, c = NS_COEFFS
     n = x.shape[-1]
-    if gram_chunk is None or gram_chunk >= n:
-        s = blas.syrk(x, fill="full", mesh=mesh, axis=axis)  # S = X·Xᵀ
-    else:
-        s = None
-        for lo in range(0, n, gram_chunk):
-            s = blas.syrk(x[..., lo:lo + gram_chunk], fill="full", c=s,
-                          mesh=mesh, axis=axis)
-    y = b * s + c * blas.symm(s, s, mesh=mesh, axis=axis)  # S² (sym · dense)
-    return a * x + blas.symm(y, x, mesh=mesh, axis=axis)   # sym(Y)·X
+    with jax.named_scope("ns.gram"):
+        if gram_chunk is None or gram_chunk >= n:
+            s = blas.syrk(x, fill="full", mesh=mesh, axis=axis)  # S = X·Xᵀ
+        else:
+            s = None
+            for lo in range(0, n, gram_chunk):
+                s = blas.syrk(x[..., lo:lo + gram_chunk], fill="full", c=s,
+                              mesh=mesh, axis=axis)
+    with jax.named_scope("ns.square"):
+        y = b * s + c * blas.symm(s, s, mesh=mesh, axis=axis)  # S²
+    with jax.named_scope("ns.apply"):
+        return a * x + blas.symm(y, x, mesh=mesh, axis=axis)   # sym(Y)·X
 
 
 def orthogonalize_reference(g: jax.Array, steps: int = 5,
@@ -105,12 +108,15 @@ def _ns_iteration_1d_local(x_loc: jax.Array, axis: str, n_shards: int
     data path) — half the collective bytes of the naive approach."""
     a, b, c = NS_COEFFS
     m = x_loc.shape[0]
-    packed_shard = syrk_1d_local(x_loc, axis, n_shards)     # RS: m²/2 words
-    packed = jax.lax.all_gather(packed_shard, axis, axis=0,
-                                tiled=True)[:tril_size(m)]  # AG: m²/2 words
-    s = unpack_tril(packed, m, diag=True, symmetric=True)   # local unpack
-    y = b * s + c * (s @ s)                                 # S² local (sym)
-    return a * x_loc + y @ x_loc                            # sharded update
+    with jax.named_scope("ns.gram"):
+        packed_shard = syrk_1d_local(x_loc, axis, n_shards)  # RS: m²/2
+        packed = jax.lax.all_gather(packed_shard, axis, axis=0,
+                                    tiled=True)[:tril_size(m)]  # AG: m²/2
+        s = unpack_tril(packed, m, diag=True, symmetric=True)  # unpack
+    with jax.named_scope("ns.square"):
+        y = b * s + c * (s @ s)                             # S² local (sym)
+    with jax.named_scope("ns.apply"):
+        return a * x_loc + y @ x_loc                        # sharded update
 
 
 def _ns_iteration_1d_stacked(x_loc: jax.Array, axis: str, n_shards: int
@@ -121,17 +127,20 @@ def _ns_iteration_1d_stacked(x_loc: jax.Array, axis: str, n_shards: int
     a, b, c = NS_COEFFS
     k, m, _ = x_loc.shape
     L = tril_size(m)
-    g = jnp.einsum("kmi,kni->kmn", x_loc, x_loc)            # local SYRK
-    packed = pack_tril(g)                                   # (k, L) packed
-    pad = (-L) % n_shards
-    if pad:
-        packed = jnp.pad(packed, ((0, 0), (0, pad)))
-    shard = jax.lax.psum_scatter(packed, axis, scatter_dimension=1,
-                                 tiled=True)
-    full = jax.lax.all_gather(shard, axis, axis=1, tiled=True)[:, :L]
-    sym = unpack_tril(full, m, diag=True, symmetric=True)
-    y = b * sym + c * jnp.einsum("kmi,kin->kmn", sym, sym)
-    return a * x_loc + jnp.einsum("kmi,kin->kmn", y, x_loc)
+    with jax.named_scope("ns.gram"):
+        g = jnp.einsum("kmi,kni->kmn", x_loc, x_loc)        # local SYRK
+        packed = pack_tril(g)                               # (k, L) packed
+        pad = (-L) % n_shards
+        if pad:
+            packed = jnp.pad(packed, ((0, 0), (0, pad)))
+        shard = jax.lax.psum_scatter(packed, axis, scatter_dimension=1,
+                                     tiled=True)
+        full = jax.lax.all_gather(shard, axis, axis=1, tiled=True)[:, :L]
+        sym = unpack_tril(full, m, diag=True, symmetric=True)
+    with jax.named_scope("ns.square"):
+        y = b * sym + c * jnp.einsum("kmi,kin->kmn", sym, sym)
+    with jax.named_scope("ns.apply"):
+        return a * x_loc + jnp.einsum("kmi,kin->kmn", y, x_loc)
 
 
 def orthogonalize_1d(g: jax.Array, mesh: Mesh, axis: str = "model",
@@ -177,6 +186,14 @@ def orthogonalize_1d(g: jax.Array, mesh: Mesh, axis: str = "model",
 # ---------------------------------------------------------------------------
 # optimizer
 # ---------------------------------------------------------------------------
+def ns_scope(path) -> str:
+    """``optim.muon.ns.<leaf>``: the named scope of one matrix leaf's NS
+    chain, the leaf's key path as a dotted name
+    (``optim.muon.ns.periods.b0.mlp.wi``)."""
+    return "optim.muon.ns." + jax.tree_util.keystr(path, simple=True,
+                                                   separator=".")
+
+
 def _is_matrix(p: jax.Array) -> bool:
     """Muon applies to true 2D weight matrices (≤1D norms and biases
     take the fallback); stacked 3D params orthogonalize per trailing 2D
@@ -260,6 +277,7 @@ class Muon:
         return orthogonalize_reference(m2, self.ns_steps, mesh, axis,
                                        gram_chunk=self.gram_chunk)
 
+    @jax.named_scope("optim.muon")
     def update(self, grads: Any, state: MuonState, params: Any,
                lr_scale: jax.Array = 1.0) -> Tuple[Any, MuonState]:
         step = state.step + 1
@@ -267,9 +285,10 @@ class Muon:
             lambda mm, g: self.momentum * mm + g.astype(jnp.float32),
             state.momentum, grads)
 
-        def upd(p, mm):
+        def upd(path, p, mm):
             if _is_matrix(p):
-                o = self._orthogonalize(mm)
+                with jax.named_scope(ns_scope(path)):
+                    o = self._orthogonalize(mm)
                 scale = jnp.sqrt(jnp.maximum(1.0, p.shape[-2] / p.shape[-1]))
                 delta = o * scale + self.weight_decay * p.astype(jnp.float32)
                 return (p.astype(jnp.float32)
@@ -279,7 +298,7 @@ class Muon:
                     - self.fallback_lr * lr_scale * jnp.sign(mm)
                     ).astype(p.dtype)
 
-        new_params = jax.tree.map(upd, params, mom)
+        new_params = jax.tree_util.tree_map_with_path(upd, params, mom)
 
         gram = state.gram
         if self.gram_decay is not None and gram is not None:
