@@ -59,6 +59,7 @@ from ..kernels.symm import symm_tiles
 from ..kernels.syr2k import syr2k_tiles
 from ..kernels.syrk import syrk_tiles
 from . import grad, meshpath
+from .autotune import heuristic_tiles
 from .routing import Route, pinned, plan_route
 
 _FILLS = ("tril", "full", "packed", "sharded")
@@ -626,7 +627,7 @@ def _execute_symm_sharded(st: ShardedTriTiles, b32: jax.Array, *,
                                                   route.choice.b, mesh,
                                                   pin_b=pin_b)
     if route.path == "pallas":
-        bm = route.tiles[0] if route.tiles else 128
+        bm = route.tiles[0]           # every Pallas route carries tiles
         return _execute_symm_tiles(st.to_tritiles(bm), b32, route=route,
                                    mesh=mesh, interpret=interpret,
                                    out_dtype=out_dtype)
@@ -786,7 +787,8 @@ def symm(a_sym, b, *, out_dtype=None, mesh=None,
     if isinstance(a_sym, PackedTriangle):
         # row-major packed vec -> packed tiles: one pure scatter, no
         # dense intermediate; from here the TriTiles contract applies
-        bm = tile[0] if tile else min(128, max(8, -(-a_sym.n // 8) * 8))
+        bm = tile[0] if isinstance(tile, tuple) else \
+            heuristic_tiles("symm", a_sym.n, n2)[0]
         a_sym = TriTiles.from_packed(a_sym.vec, a_sym.n, bm)
     if isinstance(a_sym, ShardedTriTiles):
         if a_sym.n != n1 or b.ndim > 2:

@@ -14,17 +14,22 @@ degrades to in-process caching.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import tempfile
 import time
 from typing import Callable, Dict, Optional, Tuple
 
+from ..kernels import trigrid
+
 Tiles = Tuple[int, int]
 
-# measured-mode candidates: MXU-aligned, small enough to pad cheaply
-_CANDIDATES: Tuple[Tiles, ...] = ((64, 64), (128, 128), (128, 256),
-                                  (256, 128), (256, 256))
+# measured-mode candidates: MXU-aligned; the lane-multiple ones are the
+# v5e tile sweep's grid (PERF.md §6), so ``tile="auto"`` can find what
+# the heuristic picks
+_CANDIDATES: Tuple[Tiles, ...] = ((64, 64),) + tuple(
+    itertools.product((128, 256, 512, 1024), repeat=2))
 
 # extra non-square candidates tried only for the beta-accumulate
 # epilogue: the streamed C0 tile is (bm, bm), so shrinking bm while
@@ -118,12 +123,54 @@ def _round_up_tile(n: int, cap: int = 128, floor: int = 8) -> int:
     return min(t, cap)
 
 
+#: lane width of the TPU; a side of at least this is padded to a multiple
+LANE = 128
+
+#: largest tile side: on a TPU v5e, over {128, ..., 1024}² at the
+#: Newton–Schulz shapes, every op ran fastest at 1024 rows and the widest
+#: column tile that fits (PERF.md §6)
+TILE_CAP = 1024
+
+#: VMEM a grid step may hold at the heuristic's tiles (f32 operands):
+#: (1024, 1024) for SYRK and SYMM, (1024, 512) for SYR2K's four panels
+VMEM_BUDGET = 48 << 20
+
+
+def _side_tile(n: int) -> int:
+    """Shrink-to-fit up to one lane; above it the largest power of two
+    up to ``TILE_CAP`` that divides n rounded up to a lane, so a larger
+    tile never pads more than the lane rounding does (640 stays at
+    128)."""
+    if n <= LANE:
+        return _round_up_tile(n)
+    padded = -(-n // LANE) * LANE
+    t = LANE
+    while 2 * t <= TILE_CAP and padded % (2 * t) == 0:
+        t *= 2
+    return t
+
+
+def _vmem(op: str, t0: int, t1: int) -> int:
+    if op == "symm":
+        return trigrid.sym_stream_vmem(t0, t1)
+    return trigrid.rank_update_vmem(4 if op == "syr2k" else 2, t0, t1)
+
+
 def heuristic_tiles(op: str, n1: int, n2: int) -> Tiles:
-    """Shape-derived MXU-aligned default: full 128 tiles for big
-    problems, shrink-to-fit powers of two for small ones (padding a
-    20-row matrix to 128 would waste 6x the kernel work)."""
-    bm = _round_up_tile(n1)
-    bk = _round_up_tile(n2 if op != "symm" else max(n2, n1))
+    """Shape-derived MXU-aligned default, from the op and the shape
+    alone: each side's tile as :func:`_side_tile` gives it, then the
+    wider (the column tile on a tie) halved until a grid step fits
+    ``VMEM_BUDGET``.  Small sides shrink to fit (padding a 20-row
+    matrix to 128 would waste 6x the kernel work).  SYMM's ``bn``
+    follows n2, at least one lane once n1 fills one, so a one-column
+    SYMM pads to 128 columns and no further."""
+    bm = _side_tile(n1)
+    bk = _side_tile(max(n2, min(n1, LANE)) if op == "symm" else n2)
+    while _vmem(op, bm, bk) > VMEM_BUDGET and max(bm, bk) > LANE:
+        if bk >= bm:
+            bk //= 2
+        else:
+            bm //= 2
     return bm, bk
 
 

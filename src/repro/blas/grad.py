@@ -213,7 +213,7 @@ def _packed_cotangent_tiles(g_packed: jax.Array, n1: int,
     kernel(s), whose fused prologue (``_diag_scale=2.0``) applies the
     diagonal doubling in VMEM — the cotangent never becomes an n×n
     dense array and no standalone elementwise scale pass runs."""
-    bm = route.tiles[0] if route.tiles else 128
+    bm = route.tiles[0]               # every Pallas route carries tiles
     return TriTiles.from_packed(g_packed, n1, bm)
 
 

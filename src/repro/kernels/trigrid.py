@@ -40,6 +40,45 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+#: scoped VMEM Mosaic grants a kernel unless its compiler params ask
+#: for more (TPU v5e)
+DEFAULT_SCOPED_VMEM = 16 << 20
+#: most a kernel of this module asks for: a v5e core has 128 MiB of
+#: VMEM, and the rest stays with Mosaic's internal scratch
+MAX_SCOPED_VMEM = 96 << 20
+
+
+def rank_update_vmem(n_in: int, bm: int, bk: int, itemsize: int = 4,
+                     accumulate: bool = False) -> int:
+    """VMEM bytes one rank-update grid step holds: ``n_in`` streamed
+    (bm, bk) panels and the (bm, bm) output tile (and C0 tile, if
+    accumulating), each double-buffered, the f32 accumulator, and the
+    body's f32 panel casts and product."""
+    panels = n_in * bm * bk
+    tiles = bm * bm * (2 if accumulate else 1)
+    return (2 * (panels * itemsize + tiles * 4) + bm * bm * 4
+            + panels * 4 + bm * bm * 4)
+
+
+def sym_stream_vmem(bm: int, bn: int, itemsize: int = 4) -> int:
+    """VMEM bytes one SYMM grid step holds: the (bm, bm) packed tile and
+    the (bm, bn) panel and output block, each double-buffered, the f32
+    accumulator, and the body's f32 tile fixups (transpose, tril,
+    symmetrised, selected: four tile-sized temporaries) and product."""
+    return (2 * ((bm * bm + bm * bn) * itemsize + bm * bn * 4)
+            + bm * bn * 4 + 4 * bm * bm * 4 + bm * bn * 4)
+
+
+def compiler_params(vmem: int) -> Optional[pltpu.CompilerParams]:
+    """Compiler params for a kernel whose grid step holds ``vmem``
+    bytes: none while Mosaic's default scoped VMEM holds it with room
+    to spare, else a limit of twice that, up to ``MAX_SCOPED_VMEM``."""
+    if 2 * vmem <= DEFAULT_SCOPED_VMEM:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(MAX_SCOPED_VMEM, 2 * vmem))
+
+
 def resolve_interpret(interpret: Optional[bool]) -> bool:
     """The shared interpret-mode default: interpret on CPU, compiled on
     accelerator backends, unless the caller pins it."""
@@ -198,10 +237,13 @@ def rank_update(body: Callable, operands: Sequence[jax.Array], rows: str, *,
     )
     kernel = functools.partial(_rank_update_kernel, nk=nk, bm=bm,
                                n_in=len(operands), body=body, ep=ep)
+    itemsize = max(x.dtype.itemsize for x in operands)
+    vmem = rank_update_vmem(len(operands), bm, bk, itemsize, ep.accumulate)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, bm, bm), ep.out_dtype),
         interpret=interpret, name=name,
+        compiler_params=compiler_params(vmem),
     )(jnp.asarray(imap), jnp.asarray(jmap), *inputs)
 
 
@@ -260,8 +302,10 @@ def sym_stream(body: Callable, a_tiles: jax.Array, b: jax.Array, *,
     )
     kernel = functools.partial(_sym_stream_kernel, nk=nk, body=body,
                                out_dtype=out_dtype)
+    itemsize = max(a_tiles.dtype.itemsize, b.dtype.itemsize)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n1, n2), out_dtype),
         interpret=interpret, name=name,
+        compiler_params=compiler_params(sym_stream_vmem(bm, bn, itemsize)),
     )(jnp.asarray(flat), jnp.asarray(mode), a_tiles, b)

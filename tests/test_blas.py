@@ -202,7 +202,62 @@ def test_autotune_disk_cache_roundtrip(tmp_path, monkeypatch):
 
 def test_heuristic_tiles_shrink_to_fit():
     assert blas.heuristic_tiles("syrk", 20, 24) == (32, 32)
-    assert blas.heuristic_tiles("syrk", 4096, 512) == (128, 128)
+    assert blas.heuristic_tiles("syrk", 4096, 512) == (1024, 512)
+
+
+@pytest.mark.parametrize("op,n1,n2,tiles", [
+    # the Newton–Schulz shapes of a 2048-wide model get the measured tile
+    ("syrk", 2048, 5632, (1024, 512)),
+    ("syrk", 2048, 2048, (1024, 1024)),
+    ("syrk", 2048, 100352, (1024, 1024)),
+    ("symm", 2048, 5632, (1024, 512)),
+    ("symm", 2048, 2048, (1024, 1024)),
+    ("symm", 2048, 100352, (1024, 1024)),
+    # four streamed panels: SYR2K's column tile halves to fit VMEM
+    ("syr2k", 2048, 5632, (1024, 512)),
+    ("syr2k", 2048, 2048, (1024, 512)),
+    # a side 512 does not divide pads only to the 128 rounding
+    ("syrk", 640, 640, (128, 128)),
+    ("symm", 640, 640, (128, 128)),
+    ("syrk", 300, 200, (128, 256)),
+    # one-column SYMM (the whitening server's matvec): one lane, no more
+    ("symm", 2048, 1, (1024, 128)),
+    ("symm", 640, 1, (128, 128)),
+    # small shapes still shrink to fit
+    ("syrk", 20, 24, (32, 32)),
+    ("symm", 20, 5, (32, 32)),
+    ("syr2k", 64, 100, (64, 128)),
+])
+def test_heuristic_tiles_rule(op, n1, n2, tiles):
+    from repro.blas import autotune
+    bm, bk = blas.heuristic_tiles(op, n1, n2)
+    assert (bm, bk) == tiles
+    # a side over one lane pads no further than a 128 tile would
+    for n, t in ((n1, bm), (n2, bk)):
+        if n > 128:
+            assert -(-n // t) * t == -(-n // 128) * 128, (n, t)
+    assert autotune._vmem(op, bm, bk) <= autotune.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("n1,n2", [(1024, 1536), (600, 1000)],
+                         ids=["aligned", "padded"])
+@pytest.mark.parametrize("op", ["syrk", "syr2k", "symm"])
+def test_pallas_large_tile_matches_oracle(op, n1, n2):
+    """(512, 512) tiles in interpret mode against the dense reference,
+    on a shape the tiles divide and on one the wrapper pads."""
+    tile = dict(tile=(512, 512), interpret=True)
+    a, b = _rand((n1, n2), 20), _rand((n1, n2), 21)
+    if op == "syrk":
+        got, want = blas.syrk(a, **tile), syrk_ref(a)
+    elif op == "syr2k":
+        got, want = blas.syr2k(a, b, **tile), syr2k_ref(a, b)
+    else:
+        s = _rand((n1, n1), 22)
+        got, want = blas.symm(s, b, **tile), symm_ref(s, b)
+    # an n2-term f32 sum of unit-variance products: entries near zero
+    # carry the rounding of terms up to sqrt(n2) in size
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=3e-5, atol=1e-6 * n2)
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,8 @@ The TPU compiler is installed even where no chip is attached, so each
 test compiles the routed ``repro.blas`` call for one chip of a described
 ``v5e:2x2`` at stablelm-1.6b's d_ff weight shape (the Newton–Schulz Gram
 and update of a 2048x5632 weight) and checks that the program carries
-the Mosaic kernel.  That catches what interpret mode cannot: block
+the Mosaic kernel: at 128 tiles, and at the tiles ``heuristic_tiles``
+picks, there and at the embedding's 2048x100352.  That catches what interpret mode cannot: block
 shapes the TPU tiling refuses, VMEM overflow, unsupported ops.  Nothing
 runs, so results and times are not checked here.
 
@@ -23,12 +24,17 @@ KERNEL = dict(tile=(128, 128), interpret=False)
 
 pytestmark = pytest.mark.xdist_group("libtpu")
 
-OPS = {
-    "syrk": (lambda a: blas.syrk(a, fill="full", **KERNEL), ("a",)),
-    "syr2k": (lambda a, b: blas.syr2k(a, b, fill="full", **KERNEL),
-              ("a", "b")),
-    "symm": (lambda s, b: blas.symm(s, b, **KERNEL), ("s", "b")),
-}
+
+def _ops(kernel):
+    return {
+        "syrk": (lambda a: blas.syrk(a, fill="full", **kernel), ("a",)),
+        "syr2k": (lambda a, b: blas.syr2k(a, b, fill="full", **kernel),
+                  ("a", "b")),
+        "symm": (lambda s, b: blas.symm(s, b, **kernel), ("s", "b")),
+    }
+
+
+OPS = _ops(KERNEL)
 
 
 @pytest.fixture(scope="module")
@@ -59,13 +65,9 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
-                         ids=["bf16", "f32"])
-@pytest.mark.parametrize("mode", ["fwd", "grad"])
-@pytest.mark.parametrize("op", sorted(OPS))
-def test_kernel_compiles_for_v5e(one_chip, op, mode, dtype):
-    call, names = OPS[op]
-    shapes = {"a": (N1, N2), "b": (N1, N2), "s": (N1, N1)}
+def _compile(one_chip, ops, op, mode, dtype, n1, n2):
+    call, names = ops[op]
+    shapes = {"a": (n1, n2), "b": (n1, n2), "s": (n1, n1)}
     args = [jax.ShapeDtypeStruct(shapes[n], dtype, sharding=one_chip)
             for n in names]
     fn = call if mode == "fwd" else jax.grad(
@@ -75,3 +77,33 @@ def test_kernel_compiles_for_v5e(one_chip, op, mode, dtype):
     assert log and all(r.path == "pallas" for r in log), log
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
+    return log
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("mode", ["fwd", "grad"])
+@pytest.mark.parametrize("op", sorted(OPS))
+def test_kernel_compiles_for_v5e(one_chip, op, mode, dtype):
+    _compile(one_chip, OPS, op, mode, dtype, N1, N2)
+
+
+# the tiles the router picks on a TPU: large enough to need more than
+# the default scoped VMEM, which the kernels then ask for
+HEURISTIC_CASES = [(op, mode, dtype, N1, N2) for op in sorted(OPS)
+                   for mode in ("fwd", "grad")
+                   for dtype in (jnp.bfloat16, jnp.float32)] + \
+    [(op, mode, jnp.float32, N1, 100352) for op in ("symm", "syrk")
+     for mode in ("fwd", "grad")]
+
+
+@pytest.mark.parametrize(
+    "op,mode,dtype,n1,n2", HEURISTIC_CASES,
+    ids=[f"{op}-{mode}-{jnp.dtype(dt).name}-{n1}x{n2}"
+         for op, mode, dt, n1, n2 in HEURISTIC_CASES])
+def test_kernel_compiles_for_v5e_at_heuristic_tiles(one_chip, op, mode,
+                                                    dtype, n1, n2):
+    tile = blas.heuristic_tiles(op, n1, n2)
+    log = _compile(one_chip, _ops(dict(tile=tile, interpret=False)), op,
+                   mode, dtype, n1, n2)
+    assert log[0].tiles == tile, log
