@@ -37,6 +37,10 @@ from ..core.packing import (PackedTriangle, pack_tril, tril_size,
 
 # quintic Newton–Schulz coefficients (Jordan et al., Muon)
 NS_COEFFS = (3.4445, -4.7750, 2.0315)
+#: the named scope of Muon's own 1d wire (:func:`orthogonalize_1d`), so
+#: its collectives and local products are told apart from the model's
+#: and from the ``blas.<op>.<path>`` routes in a profile
+WIRE_SCOPE = "optim.muon_1d"
 
 
 class MuonState(NamedTuple):
@@ -180,7 +184,8 @@ def orthogonalize_1d(g: jax.Array, mesh: Mesh, axis: str = "model",
 
     spec = P(*([None] * (g.ndim - 1) + [axis]))
     fn = jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)
-    return fn(g)
+    with jax.named_scope(WIRE_SCOPE):
+        return fn(g)
 
 
 # ---------------------------------------------------------------------------

@@ -130,3 +130,95 @@ def test_data_pipeline_spans_in_a_trace(tmp_path):
              for line in p.lines for e in line.events}
     assert {"repro.data.produce", "repro.data.put",
             "repro.data.wait"} <= names
+
+
+# --------------------------------------------- the mesh cell's wires
+MESH_CHILD = r"""
+import json, pathlib, sys
+root = pathlib.Path(sys.argv[1])
+sys.path[:0] = [str(root), str(root / "src")]
+from bench import scopes, spec
+from repro.blas import routing
+cell = spec.load_cell(root, "pixtral-12b-tiny.muon-tp4")
+with routing.capture_routes() as log:
+    text = scopes.compiled_step_text(cell)
+json.dump({"text": text, "routes": sorted({(r.op, r.path) for r in log})},
+          sys.stdout)
+"""
+MESH_PATHS = ("1d", "2d", "3d", "ring")
+
+
+@pytest.fixture(scope="module")
+def mesh_hlo(tmp_path_factory):
+    """The compiled step of the benchmark's tiny mesh cell on 4 virtual
+    CPU devices (a child process: the device count must not leak)."""
+    import json
+    import subprocess
+
+    from bench.tests import rehearse
+    root = rehearse.make_root(tmp_path_factory.mktemp("mesh"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    p = subprocess.run([sys.executable, "-c", MESH_CHILD, str(root)],
+                       capture_output=True, text=True, env=env, cwd=root,
+                       timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    got = json.loads(p.stdout)
+    return scopes.hlo_ops(got["text"]), [tuple(r) for r in got["routes"]]
+
+
+def _wire_collectives(hlo):
+    """(op_name, scopes) of every collective that runs inside a
+    shard_map of the optimizer."""
+    from bench import xplane
+    return [(n, scopes.scopes_of(n)) for op, n in hlo.ops.values()
+            if xplane.COLLECTIVE.search(op) and "shard_map" in n
+            and scopes.bucket(n) == "optimizer"]
+
+
+def test_muon_wire_collectives_carry_the_wire_scope(mesh_hlo):
+    """Each collective of a shard_map in the optimizer belongs to
+    exactly one wire: Muon's own (``optim.muon_1d``) or a repro.blas
+    mesh route (``blas.<op>.<path>``)."""
+    from repro.optim.muon import WIRE_SCOPE
+    hlo, _ = mesh_hlo
+    wires = _wire_collectives(hlo)
+    muon = [n for n, s in wires if WIRE_SCOPE in s]
+    assert muon, "Muon's 1d wire ran no collective"
+    assert {"reduce-scatter", "all-gather"} <= {
+        hlo.ops[k][0] for k, v in hlo.ops.items() if v[1] in muon}
+    for n, s in wires:
+        routes = [x for x in s if scopes._BLAS.fullmatch(x)]
+        assert (WIRE_SCOPE in s) != bool(routes), n
+        # Muon's wire sits inside its leaf's NS chain
+        assert any(x.startswith("optim.muon.ns.") for x in s), n
+
+
+def test_every_mesh_route_carries_its_scope(mesh_hlo):
+    hlo, routes = mesh_hlo
+    mesh = [(op, path) for op, path in routes
+            if path.split("-")[0] in MESH_PATHS]
+    assert mesh, routes
+    names = [n for _, n in hlo.ops.values()]
+    for op, path in mesh:
+        scope = f"blas.{op}.{path.split('-')[0]}"
+        assert any(scope in scopes.scopes_of(n) for n in names), scope
+    for n, s in _wire_collectives(hlo):
+        for x in s:
+            m = scopes._BLAS.fullmatch(x)
+            if m:
+                assert m.group(2) in MESH_PATHS, n
+
+
+def test_both_wires_are_filed_under_the_optimizer(mesh_hlo):
+    from repro.optim.muon import WIRE_SCOPE
+    hlo, _ = mesh_hlo
+    ran = [hlo.ops[k][1] for k in hlo.top]
+    on_wire = [n for n in ran
+               if WIRE_SCOPE in scopes.scopes_of(n)
+               or any(scopes._BLAS.fullmatch(x)
+                      and x.rsplit(".", 1)[1] in MESH_PATHS
+                      for x in scopes.scopes_of(n))]
+    assert on_wire
+    assert {scopes.bucket(n) for n in on_wire} == {"optimizer"}
+    assert any(WIRE_SCOPE in scopes.label(n).split("/") for n in on_wire)

@@ -107,3 +107,58 @@ def test_kernel_compiles_for_v5e_at_heuristic_tiles(one_chip, op, mode,
     log = _compile(one_chip, _ops(dict(tile=tile, interpret=False)), op,
                    mode, dtype, n1, n2)
     assert log[0].tiles == tile, log
+
+
+#: the ``bytes_limit`` a v5e's runtime reports for one chip
+V5E_BYTES_LIMIT = 16_909_334_528
+
+
+def test_mesh_cell_step_fits_a_v5e_2x2(topo, one_chip, monkeypatch):
+    """The benchmark's ``pixtral-12b.muon-tp4`` train step, at published
+    widths on a (data 1, model 4) mesh of the described chips, compiles
+    with its NS products on the mesh wires (no Pallas kernel: one
+    cannot be partitioned) and within one chip's memory, arguments and
+    temporaries together, and every op it runs carries a scope.
+    ``one_chip`` keeps the compile out of the persistent cache."""
+    import pathlib
+
+    import numpy as np
+    from jax.sharding import Mesh
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    monkeypatch.syspath_prepend(str(repo))
+    from bench import spec
+    cell = spec.load_cell(repo, "pixtral-12b.muon-tp4")
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    # the program picks its kernels by backend: plan them for the TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = cell.driver().Step(cell, mesh=mesh)
+
+    def sds(tree, shardings):
+        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), tree, shardings)
+
+    job = cell.traffic
+    batch = {k: jax.ShapeDtypeStruct((job["global_batch"], job["seq_len"]),
+                                     jnp.int32, sharding=step.b_sh[k])
+             for k in ("tokens", "labels")}
+    with jax.set_mesh(mesh), blas.capture_routes() as log:
+        lowered = step.jit_step.lower(sds(step.shape, step.p_sh),
+                                      sds(step.state_shape, step.o_sh),
+                                      batch)
+    paths = {r.path for r in log}
+    assert {"ring", "1d"} <= paths and "pallas" not in paths, paths
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    ma = compiled.memory_analysis()
+    per_chip = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert per_chip < V5E_BYTES_LIMIT, per_chip
+    # the traced run's breakdown finds a scope for every op that runs,
+    # so its join does not refuse the mesh cell
+    from bench import scopes
+    hlo = scopes.hlo_ops(text)
+    unscoped = [n for n in hlo.top if hlo.ops[n][0] not in
+                ("parameter", "constant", "get-tuple-element", "tuple")
+                and scopes.bucket(hlo.ops[n][1]) == "unattributed"]
+    assert not unscoped, unscoped[:10]
