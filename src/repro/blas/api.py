@@ -34,13 +34,17 @@ Pallas route, ``fill="packed"`` and ``fill="tril"`` never materialize an
 n×n dense intermediate — the kernels emit diagonal-masked packed tiles
 (epilogue in-kernel) and the fill conversion is a cached-index gather
 (packed) or the output assembly itself (tril).  The same discipline
-holds on the mesh routes: 2D/3D schedules emit
+holds on the 1D/2D/3D mesh routes: 2D/3D schedules emit
 :class:`~repro.core.packing.ShardedTriTiles` extended triangle-block
 shards and only the ~n²/2 packed words are ever gathered
 (``fill="tril"/"full"`` unpacks once, at the exit); SYMM scatters a
 pre-packed operand straight into the per-device shards; batched calls
 stack packed triangles on the 1D wire instead of falling back to GSPMD
-dense.
+dense.  The ring route keeps the packed triangle for ``fill="packed"``
+/ ``"sharded"`` and for packed or tiled SYMM operands; its dense fills
+are built from the gathered slot stack in whole nb×nb blocks, and a
+dense SYMM operand is cut into the slot stack the same way, with no
+element-packed round trip (:mod:`repro.core.ringpath`).
 """
 from __future__ import annotations
 
@@ -330,9 +334,13 @@ def _execute_syrk(a32: jax.Array, c32: Optional[jax.Array], *, fill: str,
         base = _packed_to_fill(packed, n1, fill)
         return _combine_fill(base, c32, alpha, beta, fill)
     if route.path == "ring":
-        # batch-native: leading dims ride the shifted payload
-        packed = meshpath.syrk_ring_packed(a32, mesh, route.axis)
-        base = _packed_to_fill(packed, n1, fill)
+        # batch-native: leading dims ride the shifted payload; the dense
+        # fills come from the slot stack in whole blocks
+        if fill == "packed":
+            base = meshpath.syrk_ring_packed(a32, mesh, route.axis)
+        else:
+            base = meshpath.syrk_ring_dense(a32, mesh, route.axis,
+                                            symmetric=(fill == "full"))
         return _combine_fill(base, c32, alpha, beta, fill)
     if route.path in grid_paths:
         if a32.ndim > 2:
@@ -403,8 +411,11 @@ def _execute_syr2k(a32: jax.Array, b32: jax.Array,
         base = _packed_to_fill(packed, n1, fill)
         return post(_combine_fill(base, c32, alpha, beta, fill))
     if route.path == "ring":
-        packed = meshpath.syr2k_ring_packed(a32, b32, mesh, route.axis)
-        base = _packed_to_fill(packed, n1, fill)
+        if fill == "packed":
+            base = meshpath.syr2k_ring_packed(a32, b32, mesh, route.axis)
+        else:
+            base = meshpath.syr2k_ring_dense(a32, b32, mesh, route.axis,
+                                             symmetric=(fill == "full"))
         return post(_combine_fill(base, c32, alpha, beta, fill))
     if route.path in grid_paths:
         if a32.ndim > 2:

@@ -21,6 +21,8 @@ triangle-block shards on the 2D/3D wire.  SYRK/SYR2K return
 consumes a pre-packed triangle via a pure scatter into the per-device
 shards; nothing on these paths builds an n₁×n₁ dense intermediate —
 that exit exists only in the explicitly-dense ``*_dense`` wrappers.
+The ring's ``*_ring_dense`` wrappers move between dense and the ring
+slot stacks in whole nb×nb blocks, never through the packed triangle.
 All functions take/return f32; :mod:`repro.blas.api` handles
 fill/dtype.
 
@@ -462,6 +464,43 @@ def syr2k_ring_packed(a: jax.Array, b: jax.Array, mesh: Mesh, axis: str
     return ringpath.ring_stack_to_packed(stack, n1)
 
 
+def _ring_dense_exit(stack: jax.Array, n1: int, mesh: Mesh, axis: str,
+                     symmetric: bool) -> jax.Array:
+    """Slot stack -> replicated dense (…, n1, n1), symmetrized or its
+    lower triangle: gathered once, placed in whole nb×nb blocks (no
+    element-packed round trip)."""
+    return ringpath.ring_stack_to_full(
+        ringpath.gather_ring_stack(stack, mesh, axis), n1, symmetric)
+
+
+def syrk_ring_dense(a: jax.Array, mesh: Mesh, axis: str,
+                    symmetric: bool = True) -> jax.Array:
+    """Dense exit of :func:`syrk_ring_packed`."""
+    stack = ringpath.syrk_ring(_ring_stage(a, mesh.shape[axis]), mesh, axis)
+    return _ring_dense_exit(stack, a.shape[-2], mesh, axis, symmetric)
+
+
+def syr2k_ring_dense(a: jax.Array, b: jax.Array, mesh: Mesh, axis: str,
+                     symmetric: bool = True) -> jax.Array:
+    """Dense exit of :func:`syr2k_ring_packed`."""
+    nsh = mesh.shape[axis]
+    ab = jnp.stack([_ring_stage(a, nsh), _ring_stage(b, nsh)], axis=1)
+    stack = ringpath.syr2k_ring(ab, mesh, axis)
+    return _ring_dense_exit(stack, a.shape[-2], mesh, axis, symmetric)
+
+
+def _symm_ring_slots(slots: jax.Array, b: jax.Array, n1: int, mesh: Mesh,
+                     axis: str, pin_b: bool) -> jax.Array:
+    """SYMM on ready slot stacks; ``pin_b=True`` keeps the staged B row
+    blocks ``P(axis)``-sharded — the sharded-B entry point — instead of
+    letting GSPMD replicate them."""
+    b_stage = _ring_stage(b, mesh.shape[axis])
+    if pin_b:
+        b_stage = _pin_row_shards(b_stage, mesh, axis)
+    out = ringpath.symm_ring(slots, b_stage, mesh, axis)
+    return _ring_unstage(out, n1)
+
+
 def symm_ring_packed_a(a_packed: jax.Array, b: jax.Array, n1: int,
                        mesh: Mesh, axis: str, pin_b: bool = False
                        ) -> jax.Array:
@@ -469,24 +508,19 @@ def symm_ring_packed_a(a_packed: jax.Array, b: jax.Array, n1: int,
 
     The packed triangle scatters straight into the per-device ring slot
     stacks (a static-table gather, no dense rebuild); B circulates the
-    ring.  ``pin_b=True`` keeps the staged B row blocks ``P(axis)``-
-    sharded — the sharded-B entry point — instead of letting GSPMD
-    replicate them."""
-    nsh = mesh.shape[axis]
-    slots = ringpath.packed_to_ring(a_packed, n1, nsh)
-    b_stage = _ring_stage(b, nsh)
-    if pin_b:
-        b_stage = _pin_row_shards(b_stage, mesh, axis)
-    out = ringpath.symm_ring(slots, b_stage, mesh, axis)
-    return _ring_unstage(out, n1)
+    ring."""
+    slots = ringpath.packed_to_ring(a_packed, n1, mesh.shape[axis])
+    return _symm_ring_slots(slots, b, n1, mesh, axis, pin_b)
 
 
 def symm_ring_dense(a_sym: jax.Array, b: jax.Array, mesh: Mesh, axis: str,
                     pin_b: bool = False) -> jax.Array:
-    """tril-valid dense A: pack the triangle, then the packed entrance."""
+    """tril-valid dense A: the slot stacks are whole nb×nb blocks of
+    tril(A) (:func:`ringpath.dense_to_ring`), never the packed
+    triangle."""
     n1 = a_sym.shape[-1]
-    return symm_ring_packed_a(pack_tril(jnp.tril(a_sym)), b, n1, mesh,
-                              axis, pin_b=pin_b)
+    slots = ringpath.dense_to_ring(a_sym, mesh.shape[axis])
+    return _symm_ring_slots(slots, b, n1, mesh, axis, pin_b)
 
 
 # --------------------------------------------------------------------------

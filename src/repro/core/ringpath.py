@@ -17,12 +17,23 @@ the unique half of the symmetric work — versus ~2·n1²n2/P for the
 Collective volume is S shifts of the nb×n2 slice: m·⌊P/2⌋·nb·n2 words,
 the 1d-route scale (no n×n dense ever crosses the wire).
 
-The slot stack (…, S+1, nb, nb) per device is ``ShardedTriTiles``-
-compatible through the ``ring_stack_to_packed`` / ``packed_to_ring``
-converters below: the (device, slot) ↔ lower-block bijection is a
-static numpy table (blocks with row distance d ≤ S live on device i
-directly; d > S live transposed on device j at slot P−d; the even-P
-antipodal block is the SUM of both partners' half-slots).
+The slot stack (…, S+1, nb, nb) per device maps to the lower block
+triangle by a static (device, slot) ↔ block bijection
+(:func:`ring_block_tables`): blocks with row distance d ≤ S live on
+device i directly; d > S live transposed on device j at slot P−d; the
+even-P antipodal block is the SUM of both partners' half-slots.  Two
+pairs of converters use it:
+
+* packed — ``ring_stack_to_packed`` / ``packed_to_ring`` move between
+  the slot stack and the element-packed triangle, the packed wire of
+  ``fill="packed"``/``"sharded"`` and of packed or tiled SYMM operands;
+* dense — ``ring_stack_to_full`` / ``dense_to_ring`` move between the
+  slot stack and a dense (tril or symmetrized) n1×n1 matrix in whole
+  nb×nb blocks: static block slices, transposes and concatenates, with
+  no element-packed intermediate and no gather or scatter.  The exit
+  reads a replicated stack (:func:`gather_ring_stack`, one all-gather
+  of the slots), so no per-block slice of the sharded stack is left to
+  the partitioner.
 
 SYMM rides the same ring with B circulating instead of A: each shift
 contributes S[r,q]·B_q to the local C_r AND S[q,r]·B_r = L^T·B_r to a
@@ -39,7 +50,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from .dispatch import ring_nb
-from .packing import packed_to_tiles, tiles_to_packed
+from .packing import packed_to_tiles, tile_tril_coords, tiles_to_packed
 
 
 def _mm_t(x, y):
@@ -279,3 +290,87 @@ def packed_to_ring(p, n1: int, P: int):
     g = jnp.where(jnp.asarray(transp)[:, :, None, None],
                   jnp.swapaxes(g, -1, -2), g)
     return jnp.moveaxis(g, -4, 0)
+
+
+def gather_ring_stack(stack, mesh, axis: str = "x"):
+    """``P(axis)``-sharded slot stack (P, …, S+1, nb, nb) → the same
+    stack replicated on every device, by ONE tiled all-gather, so the
+    dense exit assembles its blocks locally."""
+    return jax.jit(jax.shard_map(
+        lambda x: jax.lax.all_gather(x, axis, tiled=True), mesh=mesh,
+        in_specs=PartitionSpec(axis), out_specs=PartitionSpec(),
+        check_vma=False))(stack)
+
+
+def _swap(x):
+    return jnp.swapaxes(x, -1, -2)
+
+
+def ring_stack_to_full(stack, n1: int, symmetric: bool = True):
+    """(P, …, S+1, nb, nb) device-major slot stack → dense (…, n1, n1):
+    the symmetrized matrix, or its lower triangle (``symmetric=False``).
+
+    Each block of the P×P block grid is a static slice of the stack,
+    placed by :func:`ring_block_tables` (the even-P antipodal
+    block is the sum of the partners' half-slots; diagonal slots are
+    read through ``tril``); upper blocks are the lower ones transposed,
+    or zero for the tril fill.  Equal to
+    ``unpack_tril(ring_stack_to_packed(stack, n1), n1, symmetric=…)``
+    with no element-packed round trip."""
+    P = stack.shape[0]
+    S = P // 2
+    src1, src2, use2, transp = ring_block_tables(P)
+
+    def slot(flat):
+        dev, s = divmod(int(flat), S + 1)
+        return stack[dev, ..., s, :, :]
+
+    def lower(i, j):
+        t = i * (i + 1) // 2 + j
+        g = slot(src1[t]) + slot(src2[t]) if use2[t] else slot(src1[t])
+        return _swap(g) if transp[t] else g
+
+    zero = jnp.zeros_like(stack[0, ..., 0, :, :])
+    rows = []
+    for i in range(P):
+        row = []
+        for j in range(P):
+            if i == j:
+                t = jnp.tril(stack[i, ..., 0, :, :])
+                row.append(t + _swap(jnp.tril(t, -1)) if symmetric else t)
+            elif j < i:
+                row.append(lower(i, j))
+            else:
+                row.append(_swap(lower(j, i)) if symmetric else zero)
+        rows.append(jnp.concatenate(row, axis=-1))
+    full = jnp.concatenate(rows, axis=-2)
+    return full[..., :n1, :n1]
+
+
+def dense_to_ring(a, P: int):
+    """Dense (…, n1, n1), lower triangle valid → (P, …, S+1, nb, nb)
+    device-major slot stack, the :func:`packed_to_ring` layout.
+
+    Slot s of device r holds block (r, q = (r−s) mod P) of tril(A), as
+    :func:`ring_unpack_tables` places it: a static block slice when
+    r ≥ q, the transposed block (q, r) when r < q (at even P both
+    antipodal partners get the full block), the diagonal slot
+    tril-masked.  The upper triangle of A is never read,
+    and A is never packed."""
+    n1 = a.shape[-1]
+    nb = ring_nb(n1, P)
+    pad = P * nb - n1
+    if pad:
+        a = jnp.pad(a, [(0, 0)] * (a.ndim - 2) + [(0, pad), (0, pad)])
+    src, transp = ring_unpack_tables(P)
+    coords = tile_tril_coords(P)
+
+    def slot(r, s):
+        i, j = coords[src[r, s]]
+        g = a[..., i * nb:(i + 1) * nb, j * nb:(j + 1) * nb]
+        if i == j:
+            return jnp.tril(g)
+        return _swap(g) if transp[r, s] else g
+
+    return jnp.stack([jnp.stack([slot(r, s) for s in range(src.shape[1])],
+                                axis=-3) for r in range(P)], axis=0)

@@ -975,7 +975,9 @@ def check_persist() -> None:
 def check_ring() -> None:
     """The cyclic-shift ring route (run with 6 or 8 fake devices):
     dense == ring parity for syrk/syr2k/symm at odd and even P incl.
-    ragged n1 and batched stacks, jaxpr proof that the packed ring wire
+    ragged n1 and batched stacks (packed, full and tril fills, dense-A
+    symm), jaxpr proof that the dense fills and dense-A symm convert
+    with no gather or scatter, jaxpr proof that the packed ring wire
     moves no n×n dense intermediate forward or backward, compiled-HLO
     proof the wire is exactly ⌊P/2⌋ collective-permutes, backward-symm
     Route capture, and (8+ devices) the computation-optimality gate:
@@ -1014,12 +1016,60 @@ def check_ring() -> None:
         want = np.asarray(pack_tril(jnp.asarray(
             np.tril(prod + np.swapaxes(prod, -1, -2)))))
         np.testing.assert_allclose(got, want, **TOL)
+        # the dense fills, built from the slot stack in whole blocks
+        gram = A @ np.swapaxes(A, -1, -2)
+        two = prod + np.swapaxes(prod, -1, -2)
+        for fill, keep in [("full", lambda x: x), ("tril", np.tril)]:
+            got = np.asarray(blas.syrk(A, fill=fill, mesh=mesh))
+            np.testing.assert_allclose(got, keep(gram), **TOL)
+            got = np.asarray(blas.syr2k(A, B, fill=fill, mesh=mesh))
+            np.testing.assert_allclose(got, keep(two), **TOL)
         S = rng.standard_normal(shape[:-2] + (n1, n1)).astype(np.float32)
+        S[..., 0, n1 - 1] = np.nan        # a dense A's upper half is unread
         got = np.asarray(blas.symm(S, B, mesh=mesh))
         sym = np.tril(S) + np.swapaxes(np.tril(S, -1), -1, -2)
         np.testing.assert_allclose(got, sym @ B, **TOL)
     print(f"  dense == ring parity at P in {sorted({c[0] for c in cases})} "
-          "(ragged + batched)")
+          "(ragged + batched; packed, full and tril fills, dense-A symm)")
+
+    # ---- the dense fills and dense-A SYMM convert in whole blocks ------
+    def gathers_scatters(jx):
+        """gather / scatter eqns outside the shard_map bodies (the ring
+        schedule's own per-device work is the compile guard's to see)."""
+        found = []
+
+        def walk(j):
+            for eqn in j.eqns:
+                if "shard_map" in eqn.primitive.name:
+                    continue
+                if eqn.primitive.name.startswith(("gather", "scatter")):
+                    found.append(eqn.primitive.name)
+                for val in eqn.params.values():
+                    if hasattr(val, "jaxpr"):
+                        walk(val.jaxpr)
+                    elif hasattr(val, "eqns"):
+                        walk(val)
+
+        walk(jx.jaxpr)
+        return found
+
+    for P, n1, n2, k in [(2, 65, 64, None), (3, 100, 96, 2),
+                         (4, 128, 128, 3)]:
+        mesh = _mesh((P,), ("x",))
+        shape = (k, n1, n2) if k else (n1, n2)
+        A = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        S = jnp.asarray(rng.standard_normal(shape[:-2] + (n1, n1)),
+                        jnp.float32)
+        for fn, ops in [
+                (lambda x: blas.syrk(x, fill="full", mesh=mesh), (A,)),
+                (lambda x: blas.syrk(x, fill="tril", mesh=mesh), (A,)),
+                (lambda x: blas.syr2k(x, x, fill="full", mesh=mesh), (A,)),
+                (lambda s, b: blas.symm(s, b, mesh=mesh), (S, A))]:
+            with blas.capture_routes() as log:
+                jx = jax.make_jaxpr(fn)(*ops)
+            assert [r.path for r in log] == ["ring"], log
+            assert not gathers_scatters(jx), (P, n1, gathers_scatters(jx))
+    print("  ring dense fills and dense-A symm: no gather or scatter")
 
     # ---- the wire is exactly floor(P/2) collective-permutes ------------
     for P, n1, n2 in [(2, 96, 64), (3, 129, 96), (ndev, 32 * ndev,

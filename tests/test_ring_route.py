@@ -140,6 +140,52 @@ def test_ring_stack_packed_round_trip(P, n1):
 
 
 # ---------------------------------------------------------------------------
+# dense converters: whole-block equivalents of the packed round trip
+# ---------------------------------------------------------------------------
+DENSE_CASES = [(P, n1, k) for P in (2, 3, 4, 5, 6)
+               for n1 in (8 * P, 8 * P + 3) for k in (None, 3)]
+
+
+@pytest.mark.parametrize("P,n1,k", DENSE_CASES,
+                         ids=[f"P{P}-n{n1}-{'b' + str(k) if k else 'flat'}"
+                              for P, n1, k in DENSE_CASES])
+def test_ring_dense_converters_match_packed_path(P, n1, k):
+    """``ring_stack_to_full`` and ``dense_to_ring`` give, bit for bit,
+    what the element-packed round trip gives: the exit against
+    ``unpack_tril(ring_stack_to_packed(·))`` for the full and the tril
+    fill, the entry against ``packed_to_ring(pack_tril(tril(·)))`` —
+    at odd and even P, n1 divisible by P and ragged, with and without a
+    leading batch dim.  The stack is random in every slot (diagonal
+    upper halves and both antipodal half-slots included), so each
+    converter must read exactly what the packed path reads."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.packing import pack_tril, unpack_tril
+    rng = np.random.default_rng(P * 1000 + n1)
+    S = P // 2
+    nb = ring_nb(n1, P)
+    lead = (k,) if k else ()
+    stack = jnp.asarray(rng.standard_normal((P,) + lead + (S + 1, nb, nb)),
+                        jnp.float32)
+    for symmetric in (True, False):
+        got = jax.jit(lambda x: ringpath.ring_stack_to_full(
+            x, n1, symmetric=symmetric))(stack)
+        want = jax.jit(lambda x: unpack_tril(
+            ringpath.ring_stack_to_packed(x, n1), n1,
+            symmetric=symmetric))(stack)
+        assert got.shape == lead + (n1, n1)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    a = jnp.asarray(rng.standard_normal(lead + (n1, n1)), jnp.float32)
+    a = a.at[..., 0, n1 - 1].set(jnp.nan)   # the upper half is never read
+    got = jax.jit(lambda x: ringpath.dense_to_ring(x, P))(a)
+    want = jax.jit(lambda x: ringpath.packed_to_ring(
+        pack_tril(jnp.tril(x)), n1, P))(a)
+    assert got.shape == (P,) + lead + (S + 1, nb, nb)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
 # multi-device suite (subprocess: fake devices must not leak)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("ndev", [8, 6])

@@ -162,3 +162,49 @@ def test_mesh_cell_step_fits_a_v5e_2x2(topo, one_chip, monkeypatch):
                 ("parameter", "constant", "get-tuple-element", "tuple")
                 and scopes.bucket(hlo.ops[n][1]) == "unattributed"]
     assert not unscoped, unscoped[:10]
+
+
+#: one stacked MLP leaf of ``pixtral-12b.muon-tp4``'s Newton–Schulz
+#: chain: ten 5120x14336 weights, their 5120x5120 Grams
+RING_K, RING_N1, RING_N2 = 10, 5120, 14336
+
+
+@pytest.mark.parametrize("op", ["syrk", "symm"])
+def test_ring_dense_converters_have_no_loops_on_v5e_2x2(topo, one_chip, op):
+    """The NS chain's dense ring calls — ``syrk(fill="full")`` for the
+    Gram and dense-A ``symm`` for S·S — compiled for a (1, 4) mesh of
+    the described chips at one MLP leaf's stacked shape, route to
+    ``ring`` and convert between dense S and the slot stack with no
+    serial loop: no ``while`` or ``scatter`` op lies under their scope.
+    (The element-packed round trip lowers its slice-granular gathers
+    and scatters to loops on the TPU: 22 under ``blas.syrk.ring``, 12
+    under ``blas.symm.ring`` at this shape.)"""
+    import pathlib
+    import sys
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    if str(repo) not in sys.path:
+        sys.path.insert(0, str(repo))
+    from bench import scopes
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    rep = NamedSharding(mesh, PartitionSpec())
+    kw = dict(mesh=mesh, axis="model")
+    if op == "syrk":
+        fn = lambda a: blas.syrk(a, fill="full", **kw)       # noqa: E731
+        shapes = [(RING_K, RING_N1, RING_N2)]
+    else:
+        fn = lambda s, b: blas.symm(s, b, **kw)               # noqa: E731
+        shapes = [(RING_K, RING_N1, RING_N1)] * 2
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
+            for s in shapes]
+    with blas.capture_routes() as log:
+        lowered = jax.jit(fn).lower(*args)
+    assert [r.path for r in log] == ["ring"], log
+    hlo = scopes.hlo_ops(lowered.compile().as_text())
+    scope = f"blas.{op}.ring"
+    loops = [n for n, (opcode, name) in hlo.ops.items()
+             if opcode in ("while", "scatter") and scope in name]
+    assert not loops, (len(loops), loops[:10])
