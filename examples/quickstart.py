@@ -82,8 +82,10 @@ mesh2 = make_mesh((P2,), ("x",))
 n1q, n2q = 4 * c * c, 3 * (c + 1)
 plan = make_2d_plan(c, n1q, n2q)
 Aq = rng.standard_normal((n1q, n2q)).astype(np.float32)
-off, diag = syrk_2d(jnp.asarray(distribute_rows(Aq, plan)), plan, mesh2)
-got = assemble_sym(np.asarray(off), np.asarray(diag), plan)
+# the 2D schedules are batch-native, (P, K, ...): one matrix is K = 1
+off, diag = syrk_2d(jnp.asarray(distribute_rows(Aq, plan))[:, None], plan,
+                    mesh2)
+got = assemble_sym(np.asarray(off[:, 0]), np.asarray(diag[:, 0]), plan)
 err = np.abs(got - np.tril(Aq @ Aq.T)).max()
 print(f"  2D SYRK  (Alg 10, c={c}, P={P2}, triangle-block dist): "
       f"max|err| = {err:.2e}")
@@ -92,9 +94,9 @@ Sq = rng.standard_normal((n1q, n1q)).astype(np.float32)
 Sq = np.tril(Sq) + np.tril(Sq, -1).T
 Bq = rng.standard_normal((n1q, n2q)).astype(np.float32)
 s_off, s_diag = distribute_sym(Sq, plan)
-cd = symm_2d(jnp.asarray(s_off), jnp.asarray(s_diag),
-             jnp.asarray(distribute_rows(Bq, plan)), plan, mesh2)
-err = np.abs(collect_rows(np.asarray(cd), plan) - Sq @ Bq).max()
+cd = symm_2d(jnp.asarray(s_off)[:, None], jnp.asarray(s_diag)[:, None],
+             jnp.asarray(distribute_rows(Bq, plan))[:, None], plan, mesh2)
+err = np.abs(collect_rows(np.asarray(cd[:, 0]), plan) - Sq @ Bq).max()
 print(f"  2D SYMM  (Alg 12): max|err| = {err:.2e}")
 
 lb = memory_independent_lower_bound(n1q, n2q, P2, m=1)

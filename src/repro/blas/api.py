@@ -7,15 +7,16 @@ execution path for its (shape, dtype, mesh) by
   dense   — fused jnp (tiny shapes, CPU, GSPMD fallback);
   pallas  — triangular flat-grid TPU kernels (kernels/*.py), tiles from
             the autotuner;
-  1d/2d/3d — the paper's communication-optimal shard_map schedules when
-            a mesh is present (meshpath.py).
+  1d/ring/2d/3d/3d-limited — the mesh schedules when a mesh is
+            present, looked up in :data:`~repro.blas.meshpath.WIRES`
+            (the executors here never branch on a mesh path).
 
 Contracts shared by all paths:
   * accumulation is always f32; ``out_dtype=None`` (default) returns the
     f32 accumulation instead of silently downcasting to the input dtype;
   * leading batch dimensions are supported (vmapped over the packed-tile
-    kernels / dense path; batched mesh calls stack packed triangles on
-    the 1D wire when n2 % P == 0, else GSPMD dense);
+    kernels / dense path; on a mesh the stack rides the planned wire's
+    collective payloads, else GSPMD dense);
   * SYRK/SYR2K ``fill``: "tril" (dense lower-triangular, default),
     "full" (symmetrized dense), or "packed" (row-major packed lower
     triangle, the wire format of the 1D algorithms);
@@ -38,13 +39,13 @@ holds on the 1D/2D/3D mesh routes: 2D/3D schedules emit
 :class:`~repro.core.packing.ShardedTriTiles` extended triangle-block
 shards and only the ~n²/2 packed words are ever gathered
 (``fill="tril"/"full"`` unpacks once, at the exit); SYMM scatters a
-pre-packed operand straight into the per-device shards; batched calls
-stack packed triangles on the 1D wire instead of falling back to GSPMD
-dense.  The ring route keeps the packed triangle for ``fill="packed"``
-/ ``"sharded"`` and for packed or tiled SYMM operands; its dense fills
-are built from the gathered slot stack in whole nb×nb blocks, and a
-dense SYMM operand is cut into the slot stack the same way, with no
-element-packed round trip (:mod:`repro.core.ringpath`).
+pre-packed operand straight into the per-device shards, and a dense
+one enters through one ``pack_tril(jnp.tril(a))``.  The ring route
+keeps the packed triangle for ``fill="packed"`` / ``"sharded"`` and for
+packed or tiled SYMM operands; its dense fills are built from the
+gathered slot stack in whole nb×nb blocks, and a dense SYMM operand is
+cut into the slot stack the same way, with no element-packed round trip
+(:mod:`repro.core.ringpath`).
 """
 from __future__ import annotations
 
@@ -258,13 +259,6 @@ def _warn_densify(op: str, path: str) -> None:
 # --------------------------------------------------------------------------
 # batching helpers
 # --------------------------------------------------------------------------
-def _flatten_lead(x: jax.Array, core_rank: int):
-    """Collapse leading batch dims to one stack axis: (…, *core) ->
-    ((k, *core), lead_shape)."""
-    lead = x.shape[:x.ndim - core_rank]
-    return x.reshape((-1,) + x.shape[x.ndim - core_rank:]), lead
-
-
 def _apply_batched(fn, *arrays, trailing=None):
     """vmap ``fn`` over flattened leading batch dims (shared by all
     operands), or call directly for unbatched operands.  ``trailing``
@@ -314,8 +308,8 @@ def _execute_syrk(a32: jax.Array, c32: Optional[jax.Array], *, fill: str,
                   interpret: Optional[bool],
                   out_dtype=None) -> jax.Array:
     n1 = a32.shape[-2]
-    grid_paths = ("2d", "3d", "3d-limited")
-    if fill == "sharded" and route.path not in grid_paths:
+    wire = meshpath.wire(route)
+    if fill == "sharded" and not (wire and wire.sharded):
         # off-grid routes produce the packed triangle; one block-granular
         # scatter puts it into the mesh-resident layout
         # (already inside this route's scope)
@@ -324,51 +318,15 @@ def _execute_syrk(a32: jax.Array, c32: Optional[jax.Array], *, fill: str,
             mesh=mesh, interpret=interpret, out_dtype=out_dtype)
         return ShardedTriTiles.from_packed(packed, n1,
                                            _sharded_grid_c(route))
-    if route.path == "1d":
-        if a32.ndim > 2:
-            af, lead = _flatten_lead(a32, 2)
-            packed = meshpath.syrk_1d_packed_stacked(af, mesh, route.axis)
-            packed = packed.reshape(lead + packed.shape[-1:])
-        else:
-            packed = meshpath.syrk_1d_packed(a32, mesh, route.axis)
-        base = _packed_to_fill(packed, n1, fill)
-        return _combine_fill(base, c32, alpha, beta, fill)
-    if route.path == "ring":
-        # batch-native: leading dims ride the shifted payload; the dense
-        # fills come from the slot stack in whole blocks
-        if fill == "packed":
-            base = meshpath.syrk_ring_packed(a32, mesh, route.axis)
-        else:
-            base = meshpath.syrk_ring_dense(a32, mesh, route.axis,
-                                            symmetric=(fill == "full"))
-        return _combine_fill(base, c32, alpha, beta, fill)
-    if route.path in grid_paths:
-        if a32.ndim > 2:
-            # stacked grid wire (the planner only emits 2d/3d batched)
-            af, lead = _flatten_lead(a32, 2)
-            if route.path == "2d":
-                st = meshpath.syrk_2d_sharded_stacked(
-                    af, route.choice.c, mesh, route.axis)
-            else:
-                st = meshpath.syrk_3d_sharded_stacked(
-                    af, route.choice.c, route.choice.p2, mesh)
-            packed = st.to_packed().reshape(lead + (-1,))
-            base = _packed_to_fill(packed, n1, fill)
-            return _combine_fill(base, c32, alpha, beta, fill)
-        if route.path == "2d":
-            st = meshpath.syrk_2d_sharded(a32, route.choice.c, mesh,
-                                          route.axis)
-        elif route.path == "3d":
-            st = meshpath.syrk_3d_sharded(a32, route.choice.c,
-                                          route.choice.p2, mesh)
-        else:
-            st = meshpath.syrk_3d_limited_sharded(a32, route.choice.c,
-                                                  route.choice.p2,
-                                                  route.choice.b, mesh)
+    if wire is not None:
         if fill == "sharded":
-            return _scale_sharded(st, alpha)
-        return _combine_fill(_packed_to_fill(st.to_packed(), n1, fill),
-                             c32, alpha, beta, fill)
+            return _scale_sharded(wire.syrk(a32, mesh, route), alpha)
+        if wire.syrk_dense is not None and fill != "packed":
+            base = wire.syrk_dense(a32, mesh, route, fill == "full")
+        else:
+            packed = meshpath.as_packed(wire.syrk(a32, mesh, route))
+            base = _packed_to_fill(packed, n1, fill)
+        return _combine_fill(base, c32, alpha, beta, fill)
     if route.path == "pallas":
         fn = functools.partial(_syrk_pallas, fill=fill, tiles=route.tiles,
                                interpret=interpret, alpha=alpha, beta=beta,
@@ -391,65 +349,28 @@ def _execute_syr2k(a32: jax.Array, b32: jax.Array,
     # fallback on every other route
     post = functools.partial(grad.scale_matrix_diag, fill=fill, n1=n1,
                              scale=diag_scale)
-    grid_paths = ("2d", "3d", "3d-limited")
-    if fill == "sharded" and route.path not in grid_paths:
+    wire = meshpath.wire(route)
+    if fill == "sharded" and not (wire and wire.sharded):
         packed = _execute_syr2k.__wrapped__(
             a32, b32, None, fill="packed", alpha=alpha, beta=0.0,
             route=route, mesh=mesh, interpret=interpret,
             out_dtype=out_dtype, diag_scale=diag_scale)
         return ShardedTriTiles.from_packed(packed, n1,
                                            _sharded_grid_c(route))
-    if route.path == "1d":
-        if a32.ndim > 2:
-            af, lead = _flatten_lead(a32, 2)
-            bf, _ = _flatten_lead(b32, 2)
-            packed = meshpath.syr2k_1d_packed_stacked(af, bf, mesh,
-                                                      route.axis)
-            packed = packed.reshape(lead + packed.shape[-1:])
-        else:
-            packed = meshpath.syr2k_1d_packed(a32, b32, mesh, route.axis)
-        base = _packed_to_fill(packed, n1, fill)
-        return post(_combine_fill(base, c32, alpha, beta, fill))
-    if route.path == "ring":
-        if fill == "packed":
-            base = meshpath.syr2k_ring_packed(a32, b32, mesh, route.axis)
-        else:
-            base = meshpath.syr2k_ring_dense(a32, b32, mesh, route.axis,
-                                             symmetric=(fill == "full"))
-        return post(_combine_fill(base, c32, alpha, beta, fill))
-    if route.path in grid_paths:
-        if a32.ndim > 2:
-            af, lead = _flatten_lead(a32, 2)
-            bf, _ = _flatten_lead(b32, 2)
-            if route.path == "2d":
-                st = meshpath.syr2k_2d_sharded_stacked(
-                    af, bf, route.choice.c, mesh, route.axis)
-            else:
-                st = meshpath.syr2k_3d_sharded_stacked(
-                    af, bf, route.choice.c, route.choice.p2, mesh)
-            packed = st.to_packed().reshape(lead + (-1,))
-            base = _packed_to_fill(packed, n1, fill)
-            return post(_combine_fill(base, c32, alpha, beta, fill))
-        if route.path == "2d":
-            st = meshpath.syr2k_2d_sharded(a32, b32, route.choice.c, mesh,
-                                           route.axis)
-        elif route.path == "3d":
-            st = meshpath.syr2k_3d_sharded(a32, b32, route.choice.c,
-                                           route.choice.p2, mesh)
-        else:
-            st = meshpath.syr2k_3d_limited_sharded(a32, b32,
-                                                   route.choice.c,
-                                                   route.choice.p2,
-                                                   route.choice.b, mesh)
+    if wire is not None:
         if fill == "sharded":
+            st = wire.syr2k(a32, b32, mesh, route)
             if diag_scale != 1.0:
                 p = grad.scale_matrix_diag(st.to_packed(), "packed", n1,
                                            diag_scale)
                 st = ShardedTriTiles.from_packed(p, n1, st.c)
             return _scale_sharded(st, alpha)
-        return post(_combine_fill(_packed_to_fill(st.to_packed(), n1,
-                                                  fill), c32,
-                                  alpha, beta, fill))
+        if wire.syr2k_dense is not None and fill != "packed":
+            base = wire.syr2k_dense(a32, b32, mesh, route, fill == "full")
+        else:
+            packed = meshpath.as_packed(wire.syr2k(a32, b32, mesh, route))
+            base = _packed_to_fill(packed, n1, fill)
+        return post(_combine_fill(base, c32, alpha, beta, fill))
     if route.path == "pallas":
         fn = functools.partial(_syr2k_pallas, fill=fill, tiles=route.tiles,
                                interpret=interpret, alpha=alpha, beta=beta,
@@ -487,40 +408,11 @@ def _execute_symm(a32: Union[jax.Array, TriTiles, ShardedTriTiles],
         # one elementwise pass on an already-dense array
         a32 = grad.scale_matrix_diag(a32, "tril", a32.shape[-1],
                                      diag_scale)
-    if route.path == "1d":
-        if b32.ndim > 2:
-            af, lead = _flatten_lead(a32, 2)
-            bf, _ = _flatten_lead(b32, 2)
-            out = meshpath.symm_1d_packed_a_stacked(
-                pack_tril(jnp.tril(af)), bf, b32.shape[-2], mesh,
-                route.axis)
-            return out.reshape(lead + out.shape[-2:])
-        return meshpath.symm_1d_dense(a32, b32, mesh, route.axis)
-    if route.path == "ring":
-        return meshpath.symm_ring_dense(a32, b32, mesh, route.axis,
-                                        pin_b=pin_b)
-    if route.path in ("2d", "3d") and b32.ndim > 2:
-        af, lead = _flatten_lead(a32, 2)
-        bf, _ = _flatten_lead(b32, 2)
-        p = pack_tril(jnp.tril(af))
-        if route.path == "2d":
-            out = meshpath.symm_2d_packed_a_stacked(
-                p, bf, route.choice.c, mesh, route.axis)
-        else:
-            out = meshpath.symm_3d_packed_a_stacked(
-                p, bf, route.choice.c, route.choice.p2, mesh)
-        return out.reshape(lead + out.shape[-2:])
-    if route.path == "2d":
-        return meshpath.symm_2d_dense(a32, b32, route.choice.c, mesh,
-                                      route.axis, pin_b=pin_b)
-    if route.path == "3d":
-        return meshpath.symm_3d_dense(a32, b32, route.choice.c,
-                                      route.choice.p2, mesh, pin_b=pin_b)
-    if route.path == "3d-limited":
-        return meshpath.symm_3d_limited_dense(a32, b32, route.choice.c,
-                                              route.choice.p2,
-                                              route.choice.b, mesh,
-                                              pin_b=pin_b)
+    wire = meshpath.wire(route)
+    if wire is not None:
+        if wire.symm_dense is not None:
+            return wire.symm_dense(a32, b32, mesh, route, pin_b)
+        return wire.symm(pack_tril(jnp.tril(a32)), b32, mesh, route, pin_b)
     if route.path == "pallas":
         fn = functools.partial(_symm_pallas, tiles=route.tiles,
                                interpret=interpret,
@@ -536,11 +428,10 @@ def _execute_symm_tiles(a: TriTiles, b32: jax.Array, *, route: Route,
     """SYMM with a pre-packed symmetric operand.  The packed layout
     survives every route: straight into the kernel on the Pallas route
     (where ``diag_scale`` — the cotangent prologue — runs in VMEM),
-    the packed triangle on the 1D wire (stacked when batched), a pure
-    block-granular scatter into the extended triangle-block shards on
-    2d/3d (the diag scale stays an elementwise pass in the cotangent's
-    own dtype there).  Only the GSPMD/jnp dense fallback rebuilds a
-    dense matrix — and says so once via :func:`_warn_densify`."""
+    the packed triangle onto every mesh wire (the diag scale stays an
+    elementwise pass in the cotangent's own dtype there).  Only the
+    GSPMD/jnp dense fallback rebuilds a dense matrix — and says so once
+    via :func:`_warn_densify`."""
     n1 = a.n
     pin_b = b_layout == "sharded"
 
@@ -548,42 +439,9 @@ def _execute_symm_tiles(a: TriTiles, b32: jax.Array, *, route: Route,
         return grad.scale_matrix_diag(a.to_packed(), "packed", n1,
                                       diag_scale)
 
-    if route.path == "1d":
-        p = scaled_packed()
-        if b32.ndim > 2:
-            pf, lead = _flatten_lead(p, 1)
-            bf, _ = _flatten_lead(b32, 2)
-            out = meshpath.symm_1d_packed_a_stacked(pf, bf, n1, mesh,
-                                                    route.axis)
-            return out.reshape(lead + out.shape[-2:])
-        return meshpath.symm_1d_packed_a(p, b32, n1, mesh, route.axis)
-    if route.path == "ring":
-        return meshpath.symm_ring_packed_a(scaled_packed(), b32, n1, mesh,
-                                           route.axis, pin_b=pin_b)
-    if route.path in ("2d", "3d") and b32.ndim > 2:
-        pf, lead = _flatten_lead(scaled_packed(), 1)
-        bf, _ = _flatten_lead(b32, 2)
-        if route.path == "2d":
-            out = meshpath.symm_2d_packed_a_stacked(
-                pf, bf, route.choice.c, mesh, route.axis)
-        else:
-            out = meshpath.symm_3d_packed_a_stacked(
-                pf, bf, route.choice.c, route.choice.p2, mesh)
-        return out.reshape(lead + out.shape[-2:])
-    if route.path == "2d":
-        return meshpath.symm_2d_packed_a(scaled_packed(), b32,
-                                         route.choice.c, mesh, route.axis,
-                                         pin_b=pin_b)
-    if route.path == "3d":
-        return meshpath.symm_3d_packed_a(scaled_packed(), b32,
-                                         route.choice.c, route.choice.p2,
-                                         mesh, pin_b=pin_b)
-    if route.path == "3d-limited":
-        return meshpath.symm_3d_limited_packed_a(scaled_packed(), b32,
-                                                 route.choice.c,
-                                                 route.choice.p2,
-                                                 route.choice.b, mesh,
-                                                 pin_b=pin_b)
+    wire = meshpath.wire(route)
+    if wire is not None:
+        return wire.symm(scaled_packed(), b32, mesh, route, pin_b)
     if route.path == "pallas":
         bm = a.bm                      # the layout fixes the row tile
         bn = route.tiles[1]
@@ -602,41 +460,19 @@ def _execute_symm_sharded(st: ShardedTriTiles, b32: jax.Array, *,
                           out_dtype=None, diag_scale: float = 1.0,
                           b_layout: str = "replicated") -> jax.Array:
     """SYMM whose symmetric operand is already mesh-resident as
-    ShardedTriTiles: the grid routes consume the shards directly (no
+    ShardedTriTiles: the grid wires consume the shards directly (no
     distribute step for A), repacking only when the planned grid's c
-    differs from the layout's; everything else goes through the packed
-    triangle.  The limited route streams B/C in ``route.choice.b``-column
-    chunks against the resident shards — exactly the working set Alg 18
-    budgets."""
+    differs from the layout's; the 1d and ring wires take its packed
+    words; off the mesh it goes through the packed triangle."""
     n1 = st.n
     pin_b = b_layout == "sharded"
     if diag_scale != 1.0:
         p = grad.scale_matrix_diag(st.to_packed(), "packed", n1,
                                    diag_scale)
         st = ShardedTriTiles.from_packed(p, n1, st.c)
-    grid_paths = ("2d", "3d", "3d-limited")
-    if route.path in grid_paths and st.c != route.choice.c:
-        st = ShardedTriTiles.from_packed(st.to_packed(), n1,
-                                         route.choice.c)
-    if route.path == "1d":
-        return meshpath.symm_1d_packed_a(st.to_packed(), b32, n1, mesh,
-                                         route.axis)
-    if route.path == "ring":
-        # the mesh-resident layout regathers only its packed words, then
-        # scatters into the ring slot stacks
-        return meshpath.symm_ring_packed_a(st.to_packed(), b32, n1, mesh,
-                                           route.axis, pin_b=pin_b)
-    if route.path == "2d":
-        return meshpath.symm_2d_sharded_a(st, b32, mesh, route.axis,
-                                          pin_b=pin_b)
-    if route.path == "3d":
-        return meshpath.symm_3d_sharded_a(st, b32, route.choice.p2, mesh,
-                                          pin_b=pin_b)
-    if route.path == "3d-limited":
-        return meshpath.symm_3d_limited_sharded_a(st, b32,
-                                                  route.choice.p2,
-                                                  route.choice.b, mesh,
-                                                  pin_b=pin_b)
+    wire = meshpath.wire(route)
+    if wire is not None:
+        return wire.symm(st, b32, mesh, route, pin_b)
     if route.path == "pallas":
         bm = route.tiles[0]           # every Pallas route carries tiles
         return _execute_symm_tiles(st.to_tritiles(bm), b32, route=route,

@@ -31,12 +31,10 @@ triangle, so its cotangent L enters the SYMM as the tril-valid operand
 L with the *diagonal doubled* (sym(L + diag L) = L + Lᵀ); a "full"
 primal exposes both mirrors and contributes tril(Ḡ) + triu(Ḡ)ᵀ.
 
-Packed cotangents stay packed on every route: the 1D mesh wire feeds
-:func:`~repro.blas.meshpath.symm_1d_packed_a` (stacked when batched),
-the 2D/3D wires scatter the packed triangle straight into the
-extended triangle-block shards
-(:func:`~repro.blas.meshpath.symm_2d_packed_a` /
-:func:`~repro.blas.meshpath.symm_3d_packed_a`), and the Pallas route
+Packed cotangents stay packed on every route: every mesh wire takes
+the packed triangle as its SYMM operand (the 1D all-gather, the ring
+slot stacks, a pure scatter into the 2D/3D extended triangle-block
+shards; :data:`~repro.blas.meshpath.WIRES`), and the Pallas route
 converts to a :class:`~repro.core.packing.TriTiles` via the
 slice-granular gather converter and flows into the packed-operand
 SYMM kernel — no direction densifies an n×n intermediate and no
@@ -154,56 +152,21 @@ def _bwd_kwargs(route: routing.Route, mesh, interpret):
 def _packed_mesh_symm(g_packed: jax.Array, other: jax.Array, n1: int,
                       route: routing.Route, mesh) -> jax.Array:
     """Packed-fill cotangent × operand on a mesh route: double the
-    packed diagonal and feed the packed triangle straight onto
-    whichever packed wire the backward SYMM plans — the 1D all-gather
-    wire (stacked when batched), the ring slot stacks, or a pure
-    scatter into the 2D/3D extended triangle-block shards.  The
-    cotangent stays in a packed layout end to end (no dense
-    round-trip).  Returns None when the backward SYMM routes dense
+    packed diagonal and feed the packed triangle straight onto whichever
+    mesh wire the backward SYMM plans (:data:`~repro.blas.meshpath.
+    WIRES`).  The cotangent stays in a packed layout end to end (no
+    dense round-trip).  Returns None when the backward SYMM routes dense
     (GSPMD fallback)."""
+    from . import meshpath
     br = routing.plan_route("symm", n1, other.shape[-1],
                             dtype=jnp.float32, batch=other.ndim > 2,
                             mesh=mesh, axis=route.axis)
-    from . import meshpath
+    wire = meshpath.wire(br)
+    if wire is None:
+        return None
     lp = g_packed * jnp.asarray(
         _packed_diag_scale(n1, 2.0, g_packed.dtype))
-    if br.path == "1d":
-        if other.ndim > 2:
-            lead = other.shape[:-2]
-            pf = lp.reshape((-1, lp.shape[-1]))
-            bf = other.reshape((-1,) + other.shape[-2:])
-            out = meshpath.symm_1d_packed_a_stacked(pf, bf, n1, mesh,
-                                                    br.axis)
-            return out.reshape(lead + out.shape[-2:])
-        return meshpath.symm_1d_packed_a(lp, other, n1, mesh, br.axis)
-    if br.path == "ring":
-        # batch-native: the slot stage vmaps over leading dims
-        return meshpath.symm_ring_packed_a(lp, other, n1, mesh, br.axis)
-    if br.path == "2d":
-        if other.ndim > 2:
-            lead = other.shape[:-2]
-            pf = lp.reshape((-1, lp.shape[-1]))
-            bf = other.reshape((-1,) + other.shape[-2:])
-            out = meshpath.symm_2d_packed_a_stacked(pf, bf, br.choice.c,
-                                                    mesh, br.axis)
-            return out.reshape(lead + out.shape[-2:])
-        return meshpath.symm_2d_packed_a(lp, other, br.choice.c, mesh,
-                                         br.axis)
-    if br.path == "3d":
-        if other.ndim > 2:
-            lead = other.shape[:-2]
-            pf = lp.reshape((-1, lp.shape[-1]))
-            bf = other.reshape((-1,) + other.shape[-2:])
-            out = meshpath.symm_3d_packed_a_stacked(pf, bf, br.choice.c,
-                                                    br.choice.p2, mesh)
-            return out.reshape(lead + out.shape[-2:])
-        return meshpath.symm_3d_packed_a(lp, other, br.choice.c,
-                                         br.choice.p2, mesh)
-    if br.path == "3d-limited" and other.ndim == 2:
-        return meshpath.symm_3d_limited_packed_a(lp, other, br.choice.c,
-                                                 br.choice.p2,
-                                                 br.choice.b, mesh)
-    return None
+    return wire.symm(lp, other, mesh, br)
 
 
 def _packed_cotangent_tiles(g_packed: jax.Array, n1: int,

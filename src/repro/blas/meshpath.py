@@ -1,249 +1,211 @@
-"""shard_map execution paths for :mod:`repro.blas` (replicated in/out).
+"""shard_map execution paths for :mod:`repro.blas` (replicated in/out),
+and the one table that maps a mesh route to them.
 
-The core parallel algorithms (core/{onedim,twodim,threedim}.py) operate
-on pre-distributed device layouts — the right interface when the data
-already lives sharded.  The blas front-end instead takes ordinary
-(replicated or GSPMD-sharded) arrays, so this module adds traced jnp
-distribute / assemble shims around them:
+The core parallel algorithms (core/{twodim,threedim,ringpath}.py)
+operate on pre-distributed device layouts — the right interface when
+the data already lives sharded.  The blas front-end instead takes
+ordinary (replicated or GSPMD-sharded) arrays, so this module adds
+traced jnp distribute / assemble shims around them, one schedule per
+(route family, op):
 
-  1D — column-shard the non-symmetric operands, move only the packed
-       triangle (Algs 7–9); batched stacks ride the same wire (one
-       reduce-scatter / all-gather covers the whole stack);
-  2D — triangle-block layout on exactly P = c(c+1) devices (Algs 10–12);
-  3D — p1 × p2 grid (2D in-slice + replication axis, Algs 13–15),
-       reshaped from a single-axis mesh.
+  1d         — column-shard the non-symmetric operands, move only the
+               packed triangle (Algs 7–9's wire);
+  ring       — cyclic shifts of row blocks, each device computing only
+               the unique blocks it owns (flop-halving SYRK/SYR2K);
+  2d         — triangle-block layout on exactly P = c(c+1) devices
+               (Algs 10–12);
+  3d         — p1 × p2 grid (2D in-slice + replication axis, Algs
+               13–15), reshaped from a single-axis mesh;
+  3d-limited — the 3d grid streaming b-column chunks (Algs 16–18, §IX).
+
+Batch-native: every schedule but 3d-limited takes leading batch dims
+(…, n1, n2), and the stack rides the collectives' payloads — one
+collective (pair) covers it, since collectives don't vmap under
+shard_map.  An unbatched call is a stack of one
+(:func:`_batch_native`).  3d-limited has one unbatched form: the
+planner never routes a stack to it.
 
 Packed wire discipline: the symmetric operand/result crosses every
 boundary here in a packed layout — the element-packed triangle on the
-1D wire, :class:`~repro.core.packing.ShardedTriTiles` extended
-triangle-block shards on the 2D/3D wire.  SYRK/SYR2K return
-``ShardedTriTiles`` (2d/3d) or the packed triangle (1d) and SYMM
-consumes a pre-packed triangle via a pure scatter into the per-device
-shards; nothing on these paths builds an n₁×n₁ dense intermediate —
-that exit exists only in the explicitly-dense ``*_dense`` wrappers.
-The ring's ``*_ring_dense`` wrappers move between dense and the ring
-slot stacks in whole nb×nb blocks, never through the packed triangle.
-All functions take/return f32; :mod:`repro.blas.api` handles
-fill/dtype.
+1D and ring wires, :class:`~repro.core.packing.ShardedTriTiles`
+extended triangle-block shards on the 2D/3D wires.  SYRK/SYR2K return
+the packed triangle (1d, ring) or ``ShardedTriTiles`` (2d, 3d) and SYMM
+consumes either; nothing on these paths builds an n₁×n₁ dense
+intermediate.  The dense exits and entrance live in
+:mod:`repro.blas.api`, except the ring's: ``*_ring_dense`` move between
+dense and the ring slot stacks in whole nb×nb blocks, never through the
+packed triangle.  All functions take/return f32; :mod:`repro.blas.api`
+handles fill/dtype.
 
-The distribute/collect helpers mirror the numpy host-side versions in
-core/twodim.py but use static index tables with jnp gathers/scatters so
-they stay traceable under jit.
+:data:`WIRES` is the only place a route's path picks its schedules:
+the blas executors, the autodiff layer and the ABFT runners look a
+route up there and never branch on its path.
 """
 from __future__ import annotations
 
 import functools
+from dataclasses import dataclass
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..core import ringpath
 from ..core.dispatch import ring_nb
-from ..core.onedim import (_padded_tril_len, symm_1d_local, syr2k_1d_local,
-                           syrk_1d_local)
-from ..core.packing import (ShardedTriTiles, pack_tril, tril_size,
-                            unpack_tril)
-from ..core.twodim import (TwoDPlan, make_2d_plan, symm_2d,
-                           symm_2d_stacked, syr2k_2d, syr2k_2d_stacked,
-                           syrk_2d, syrk_2d_stacked, tb_flat_words)
-from ..core.threedim import (symm_3d, symm_3d_limited, symm_3d_stacked,
-                             syr2k_3d, syr2k_3d_limited, syr2k_3d_stacked,
-                             syrk_3d, syrk_3d_limited, syrk_3d_stacked)
+from ..core.onedim import _padded_tril_len
+from ..core.packing import ShardedTriTiles, pack_tril, tril_size, unpack_tril
+from ..core.threedim import (flat_tb_size, symm_3d, symm_3d_limited,
+                             syr2k_3d, syr2k_3d_limited, syrk_3d,
+                             syrk_3d_limited)
+from ..core.twodim import TwoDPlan, make_2d_plan, symm_2d, syr2k_2d, syrk_2d
 
 TB_AXIS, REP_AXIS = "blas_p1", "blas_p2"
+
+
+# --------------------------------------------------------------------------
+# batch dims, operand layouts
+# --------------------------------------------------------------------------
+def _batch_native(n_ops: int):
+    """Lift a stack schedule — its first ``n_ops`` arguments carry one
+    leading stack axis K — to any leading batch dims, none included:
+    they fold into K (an unbatched call is a stack of one) and unfold
+    on the output.  The last of those operands is a non-symmetric
+    (…, n1, n2) matrix, whose leading dims are the batch."""
+    def wrap(stack_schedule):
+        @functools.wraps(stack_schedule)
+        def run(*args, **kw):
+            lead = args[n_ops - 1].shape[:-2]
+            ops = jax.tree.map(
+                lambda x: x.reshape((-1,) + x.shape[len(lead):]),
+                args[:n_ops])
+            out = stack_schedule(*ops, *args[n_ops:], **kw)
+            return jax.tree.map(lambda y: y.reshape(lead + y.shape[1:]),
+                                out)
+        return run
+    return wrap
+
+
+def as_packed(a) -> jax.Array:
+    """A packed-layout symmetric matrix as the element-packed triangle
+    (a mesh-resident ShardedTriTiles regathers only its packed words)."""
+    return a.to_packed() if isinstance(a, ShardedTriTiles) else a
+
+
+def _on_grid(a, n1: int, c: int) -> ShardedTriTiles:
+    """A packed-layout symmetric operand as ShardedTriTiles of the
+    c-grid: the packed triangle scatters straight into the extended
+    triangle-block shards (a pure index-table scatter, no dense
+    staging); a mesh-resident layout is used as is, and repacked only
+    when it was built for another c."""
+    if isinstance(a, ShardedTriTiles) and a.c == c:
+        return a
+    return ShardedTriTiles.from_packed(as_packed(a), n1, c)
+
+
+def _pin_row_shards(x: jax.Array, mesh: Mesh, *axes: str) -> jax.Array:
+    """Constrain the leading device axes of a staged (P, …) — or
+    (p1, p2, …) — buffer to the mesh axes, so a ``P(axis)``-row-sharded
+    operand enters the shard_map without a replicating gather first."""
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*axes)))
 
 
 # --------------------------------------------------------------------------
 # traced distribute / collect for the non-symmetric operands
 # --------------------------------------------------------------------------
 def distribute_rows_jnp(x: jax.Array, plan: TwoDPlan) -> jax.Array:
-    """(n1, n2) -> (P, c, nb, w) per-device row-block column shares."""
+    """(K, n1, n2) -> (P, K, c, nb, w): per-device row-block column
+    shares, the stack behind the device axis so it rides one exchange
+    payload."""
     c, nb, w = plan.c, plan.nb, plan.w
-    xp = jnp.zeros((plan.n1_pad, plan.n2_pad), x.dtype)
-    xp = xp.at[:x.shape[0], :x.shape[1]].set(x)
-    blocks = xp.reshape(c * c, nb, plan.n2_pad)
-    rows = blocks[np.asarray(plan.R)]                   # (P, c, nb, n2_pad)
+    K = x.shape[0]
+    xp = jnp.zeros((K, plan.n1_pad, plan.n2_pad), x.dtype)
+    xp = xp.at[:, :x.shape[1], :x.shape[2]].set(x)
+    blocks = xp.reshape(K, c * c, nb, plan.n2_pad)
+    rows = blocks[:, np.asarray(plan.R)]               # (K, P, c, nb, n2_pad)
     base = plan.self_col[..., None] * w + np.arange(w)  # (P, c, w) static
-    idx = jnp.asarray(base)[:, :, None, :]
-    return jnp.take_along_axis(rows, idx, axis=-1)
+    idx = jnp.asarray(base)[None, :, :, None, :]
+    return jnp.moveaxis(jnp.take_along_axis(rows, idx, axis=-1), 0, 1)
 
 
 def collect_rows_jnp(dist: jax.Array, plan: TwoDPlan) -> jax.Array:
     """Inverse of :func:`distribute_rows_jnp` (unpadded)."""
     c, nb, w = plan.c, plan.nb, plan.w
-    Pn = plan.num_devices
+    Pn, K = dist.shape[:2]
     rows_idx = np.asarray(plan.R).reshape(-1)           # (P*c,)
     col_idx = (plan.self_col[..., None] * w
                + np.arange(w)).reshape(Pn * c, w)
-    data = dist.reshape(Pn * c, nb, w)
-    out = jnp.zeros((c * c, nb, plan.n2_pad), dist.dtype)
-    out = out.at[jnp.asarray(rows_idx)[:, None, None],
+    data = jnp.moveaxis(dist, 1, 0).reshape(K, Pn * c, nb, w)
+    out = jnp.zeros((K, c * c, nb, plan.n2_pad), dist.dtype)
+    out = out.at[:, jnp.asarray(rows_idx)[:, None, None],
                  jnp.arange(nb)[None, :, None],
                  jnp.asarray(col_idx)[:, None, :]].set(data)
-    return out.reshape(plan.n1_pad, plan.n2_pad)[:plan.n1, :plan.n2]
-
-
-def distribute_rows_stacked_jnp(x: jax.Array, plan: TwoDPlan) -> jax.Array:
-    """(k, n1, n2) -> (P, k, c, nb, w): the batch stacked behind the
-    device axis so the whole stack rides one exchange payload."""
-    return jnp.moveaxis(
-        jax.vmap(lambda s: distribute_rows_jnp(s, plan))(x), 1, 0)
-
-
-def collect_rows_stacked_jnp(dist: jax.Array, plan: TwoDPlan) -> jax.Array:
-    """Inverse of :func:`distribute_rows_stacked_jnp` (unpadded)."""
-    return jax.vmap(lambda d: collect_rows_jnp(d, plan))(
-        jnp.moveaxis(dist, 0, 1))
+    return out.reshape(K, plan.n1_pad, plan.n2_pad)[:, :plan.n1, :plan.n2]
 
 
 def distribute_rows_3d_jnp(x: jax.Array, plan: TwoDPlan, p2: int
                            ) -> jax.Array:
-    """(n1, n2) -> (p1, p2, c, nb, w2): column slices over the
+    """(K, n1, n2) -> (p1, p2, K, c, nb, w2): column slices over the
     replication axis, 2D layout within each (n2 % p2 == 0 required)."""
-    n1, n2 = x.shape
-    xs = x.reshape(n1, p2, n2 // p2).transpose(1, 0, 2)   # (p2, n1, n2s)
-    dist = jax.vmap(lambda s: distribute_rows_jnp(s, plan))(xs)
-    return dist.transpose(1, 0, 2, 3, 4)                  # (p1, p2, ...)
+    K, n1, n2 = x.shape
+    xs = x.reshape(K, n1, p2, n2 // p2).transpose(2, 0, 1, 3)
+    d = distribute_rows_jnp(xs.reshape(p2 * K, n1, n2 // p2), plan)
+    return d.reshape((d.shape[0], p2, K) + d.shape[2:])
 
 
 def collect_rows_3d_jnp(c_dist: jax.Array, plan: TwoDPlan, p2: int
                         ) -> jax.Array:
-    """(p1, p2, c, nb, w2) SYMM output -> dense (n1, n2)."""
-    per = jax.vmap(lambda d: collect_rows_jnp(d, plan))(
-        c_dist.transpose(1, 0, 2, 3, 4))                  # (p2, n1, n2s)
+    """(p1, p2, K, c, nb, w2) SYMM output -> dense (K, n1, n2)."""
+    p1, _, K = c_dist.shape[:3]
+    per = collect_rows_jnp(c_dist.reshape((p1, p2 * K) + c_dist.shape[3:]),
+                           plan)                          # (p2·K, n1, n2s)
     n1 = per.shape[1]
-    return per.transpose(1, 0, 2).reshape(n1, -1)
+    return per.reshape(p2, K, n1, -1).transpose(1, 2, 0, 3).reshape(K, n1, -1)
 
 
-def flat_tb_size(plan: TwoDPlan) -> int:
-    return tb_flat_words(plan.c, plan.n1)
-
-
-def _sharded_from_flat(flat_shards: jax.Array, plan: TwoDPlan, n1: int,
-                       c: int) -> ShardedTriTiles:
-    """(p1, p2, shard) reduce-scattered 3D output -> ShardedTriTiles
-    (a reshape of the ~n²/2 packed words; no dense rebuild)."""
-    p1, p2, s = flat_shards.shape
-    flat = flat_shards.reshape(p1, p2 * s)[:, :flat_tb_size(plan)]
+def _sharded_from_flat(flat: jax.Array, plan: TwoDPlan, n1: int, c: int
+                       ) -> ShardedTriTiles:
+    """(p1, p2, …, shard) reduce-scattered 3D output -> ShardedTriTiles
+    with the … stack dims leading (a reshape of the ~n²/2 packed words;
+    no dense rebuild)."""
+    p1 = flat.shape[0]
+    lead = flat.shape[2:-1]
+    flat = jnp.moveaxis(flat, (0, 1), (-3, -2)).reshape(lead + (p1, -1))
+    flat = flat[..., :flat_tb_size(plan)]
     t = plan.T * plan.nb * plan.nb
-    off = flat[:, :t].reshape(p1, plan.T, plan.nb, plan.nb)
-    diag = flat[:, t:].reshape(p1, plan.nb, plan.nb)
+    off = flat[..., :t].reshape(lead + (p1, plan.T, plan.nb, plan.nb))
+    diag = flat[..., t:].reshape(lead + (p1, plan.nb, plan.nb))
     return ShardedTriTiles(off, diag, n1, c)
 
 
 def _flat_from_sharded(st: ShardedTriTiles, p2: int) -> jax.Array:
-    """ShardedTriTiles -> (p1, p2, shard) flattened extended triangle
-    blocks, shard-split over the replication axis (3D SYMM input)."""
+    """ShardedTriTiles (stack dims … leading) -> (p1, p2, …, shard):
+    flattened extended triangle blocks, shard-split over the
+    replication axis (3D SYMM input)."""
+    lead = st.diag.shape[:-3]
     p1 = st.num_devices
-    flat = jnp.concatenate([st.off.reshape(p1, -1),
-                            st.diag.reshape(p1, -1)], 1)
-    pad = -flat.shape[1] % p2
-    flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    return flat.reshape(p1, p2, -1)
-
-
-def distribute_rows_3d_stacked_jnp(x: jax.Array, plan: TwoDPlan, p2: int
-                                   ) -> jax.Array:
-    """(k, n1, n2) -> (p1, p2, k, c, nb, w2)."""
-    d = jax.vmap(lambda s: distribute_rows_3d_jnp(s, plan, p2))(x)
-    return d.transpose(1, 2, 0, 3, 4, 5)
-
-
-def _sharded_from_flat_stacked(flat_shards: jax.Array, plan: TwoDPlan,
-                               n1: int, c: int) -> ShardedTriTiles:
-    """(p1, p2, k, shard) stacked 3D output -> batched ShardedTriTiles
-    (leading stack dim)."""
-    p1, p2, k, s = flat_shards.shape
-    flat = flat_shards.transpose(2, 0, 1, 3).reshape(k, p1, p2 * s)
-    flat = flat[:, :, :flat_tb_size(plan)]
-    t = plan.T * plan.nb * plan.nb
-    off = flat[:, :, :t].reshape(k, p1, plan.T, plan.nb, plan.nb)
-    diag = flat[:, :, t:].reshape(k, p1, plan.nb, plan.nb)
-    return ShardedTriTiles(off, diag, n1, c)
-
-
-def _flat_from_sharded_stacked(st: ShardedTriTiles, p2: int) -> jax.Array:
-    """Batched ShardedTriTiles (leading stack dim) -> (p1, p2, k, shard)."""
-    k = st.off.shape[0]
-    p1 = st.num_devices
-    flat = jnp.concatenate([st.off.reshape(k, p1, -1),
-                            st.diag.reshape(k, p1, -1)], 2)
-    flat = jnp.pad(flat, ((0, 0), (0, 0), (0, -flat.shape[2] % p2)))
-    return flat.reshape(k, p1, p2, -1).transpose(1, 2, 0, 3)
+    flat = jnp.concatenate([st.off.reshape(lead + (p1, -1)),
+                            st.diag.reshape(lead + (p1, -1))], -1)
+    flat = jnp.pad(flat, [(0, 0)] * (flat.ndim - 1)
+                   + [(0, -flat.shape[-1] % p2)])
+    return jnp.moveaxis(flat.reshape(lead + (p1, p2, -1)), (-3, -2), (0, 1))
 
 
 # --------------------------------------------------------------------------
-# 1D paths (Algs 7–9): packed triangle on the wire
+# 1D (Algs 7–9's wire): packed triangles, the stack along the payload
 # --------------------------------------------------------------------------
-def syrk_1d_packed(a: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
-    """f32 (n1, n2), n2 % P == 0 -> replicated packed tril of A·Aᵀ."""
-    n1 = a.shape[0]
-    nsh = mesh.shape[axis]
-
-    def body(a_loc):
-        shard = syrk_1d_local(a_loc, axis, nsh)
-        full = jax.lax.all_gather(shard, axis, axis=0, tiled=True)
-        return full[:tril_size(n1)]
-
-    return jax.shard_map(body, mesh=mesh, in_specs=P(None, axis),
-                         out_specs=P(), check_vma=False)(a)
-
-
-def syr2k_1d_packed(a: jax.Array, b: jax.Array, mesh: Mesh, axis: str
+# ONE reduce-scatter / all-gather of (k, tril) covers the whole stack,
+# moving k·n₁²/2 words instead of the 2·k·n₁² of a dense all-reduce +
+# broadcast.
+def _rank_update_1d(local_gram, operands, mesh: Mesh, axis: str
                     ) -> jax.Array:
-    n1 = a.shape[0]
-    nsh = mesh.shape[axis]
-
-    def body(a_loc, b_loc):
-        shard = syr2k_1d_local(a_loc, b_loc, axis, nsh)
-        full = jax.lax.all_gather(shard, axis, axis=0, tiled=True)
-        return full[:tril_size(n1)]
-
-    return jax.shard_map(body, mesh=mesh,
-                         in_specs=(P(None, axis), P(None, axis)),
-                         out_specs=P(), check_vma=False)(a, b)
-
-
-def symm_1d_packed_a(a_packed: jax.Array, b: jax.Array, n1: int, mesh: Mesh,
-                     axis: str) -> jax.Array:
-    """f32 packed tril (tril_size(n1),) × (n1, n2), n2 % P == 0 -> (n1, n2).
-
-    SYMM whose symmetric operand arrives *already packed* — the wire
-    format of the 1D algorithms, and the shape the autodiff layer hands
-    back when a packed-fill SYRK/SYR2K cotangent flows into its
-    backward SYMM (no dense round-trip before the shard_map)."""
-    nsh = mesh.shape[axis]
-    packed = jnp.pad(a_packed,
-                     (0, _padded_tril_len(n1, nsh) - a_packed.shape[0]))
-    f = functools.partial(symm_1d_local, axis=axis, n1=n1)
-    return jax.shard_map(f, mesh=mesh, in_specs=(P(axis), P(None, axis)),
-                         out_specs=P(None, axis), check_vma=False)(packed, b)
-
-
-def symm_1d_dense(a_sym: jax.Array, b: jax.Array, mesh: Mesh, axis: str
-                  ) -> jax.Array:
-    """f32 tril-valid (n1, n1) × (n1, n2), n2 % P == 0 -> (n1, n2)."""
-    n1 = a_sym.shape[0]
-    return symm_1d_packed_a(pack_tril(jnp.tril(a_sym)), b, n1, mesh, axis)
-
-
-# ---- batched stacks on the 1D wire ----------------------------------------
-# Collectives don't vmap under shard_map, so batched mesh calls used to
-# fall back to GSPMD dense.  Stacking the packed triangles along a
-# leading axis (the `_ns_iteration_1d_stacked` pattern in optim.muon)
-# keeps them on the comm-optimal wire: ONE reduce-scatter / all-gather
-# of (k, tril) covers the whole stack, moving k·n₁²/2 words instead of
-# the 2·k·n₁² of a dense all-reduce + broadcast.
-def _rank_update_1d_stacked(local_gram, operands, mesh: Mesh, axis: str
-                            ) -> jax.Array:
-    """Shared wire of the stacked 1D rank-updates: pack the local
-    (k, n1, n1) Grams (slice-granular batched :func:`pack_tril`),
-    reduce-scatter + all-gather the (k, tril) stack once, trim the
-    padding.  ``local_gram`` maps the per-device column shards to the
-    local Gram stack."""
+    """Shared wire of the 1D rank-updates: pack the local (k, n1, n1)
+    Grams (slice-granular batched :func:`pack_tril`), reduce-scatter +
+    all-gather the (k, tril) stack once, trim the padding.
+    ``local_gram`` maps the per-device column shards to the local Gram
+    stack."""
     n1 = operands[0].shape[1]
     nsh = mesh.shape[axis]
     L = tril_size(n1)
@@ -261,25 +223,28 @@ def _rank_update_1d_stacked(local_gram, operands, mesh: Mesh, axis: str
                          out_specs=P(), check_vma=False)(*operands)
 
 
-def syrk_1d_packed_stacked(a: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
-    """f32 (k, n1, n2), n2 % P == 0 -> replicated (k, tril_size(n1))."""
-    return _rank_update_1d_stacked(
+@_batch_native(1)
+def syrk_1d_packed(a: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
+    """f32 (…, n1, n2), n2 % P == 0 -> replicated (…, tril_size(n1))."""
+    return _rank_update_1d(
         lambda al: jnp.einsum("kmi,kni->kmn", al, al), (a,), mesh, axis)
 
 
-def syr2k_1d_packed_stacked(a: jax.Array, b: jax.Array, mesh: Mesh,
-                            axis: str) -> jax.Array:
-    """f32 (k, n1, n2) × 2 -> replicated (k, tril_size(n1)) of ABᵀ+BAᵀ."""
+@_batch_native(2)
+def syr2k_1d_packed(a: jax.Array, b: jax.Array, mesh: Mesh, axis: str
+                    ) -> jax.Array:
+    """f32 (…, n1, n2) × 2 -> replicated (…, tril_size(n1)) of ABᵀ+BAᵀ."""
     def local_gram(al, bl):
         g = jnp.einsum("kmi,kni->kmn", al, bl)
         return g + g.swapaxes(-1, -2)
 
-    return _rank_update_1d_stacked(local_gram, (a, b), mesh, axis)
+    return _rank_update_1d(local_gram, (a, b), mesh, axis)
 
 
-def symm_1d_packed_a_stacked(a_packed: jax.Array, b: jax.Array, n1: int,
-                             mesh: Mesh, axis: str) -> jax.Array:
-    """f32 (k, tril_size(n1)) × (k, n1, n2), n2 % P == 0 -> (k, n1, n2).
+@_batch_native(2)
+def symm_1d_packed_a(a_packed: jax.Array, b: jax.Array, n1: int,
+                     mesh: Mesh, axis: str) -> jax.Array:
+    """f32 (…, tril_size(n1)) × (…, n1, n2), n2 % P == 0 -> (…, n1, n2).
 
     The packed stack is all-gathered once (Alg 9's wire, batched along
     the payload) and unpacked to the per-device working set — the dense
@@ -302,124 +267,8 @@ def symm_1d_packed_a_stacked(a_packed: jax.Array, b: jax.Array, n1: int,
 
 
 # --------------------------------------------------------------------------
-# 2D paths (Algs 10–12): P == c(c+1) triangle-block grid, packed wire
+# ring: computation-optimal cyclic shift (flop-halving SYRK/SYR2K)
 # --------------------------------------------------------------------------
-def syrk_2d_sharded(a: jax.Array, c: int, mesh: Mesh, axis: str
-                    ) -> ShardedTriTiles:
-    """f32 (n1, n2) -> per-device extended triangle blocks of tril(A·Aᵀ)
-    — the output stays in the ~n²/(2P)-per-device wire format; callers
-    gather only the packed words (``.to_packed()``) or exit dense
-    explicitly."""
-    n1, n2 = a.shape
-    plan = make_2d_plan(c, n1, n2)
-    off, diag = syrk_2d(distribute_rows_jnp(a, plan), plan, mesh, axis)
-    return ShardedTriTiles(off, diag, n1, c)
-
-
-def syr2k_2d_sharded(a: jax.Array, b: jax.Array, c: int, mesh: Mesh,
-                     axis: str) -> ShardedTriTiles:
-    n1, n2 = a.shape
-    plan = make_2d_plan(c, n1, n2)
-    off, diag = syr2k_2d(distribute_rows_jnp(a, plan),
-                         distribute_rows_jnp(b, plan), plan, mesh, axis)
-    return ShardedTriTiles(off, diag, n1, c)
-
-
-def symm_2d_sharded_a(st: ShardedTriTiles, b: jax.Array, mesh: Mesh,
-                      axis: str, pin_b: bool = False) -> jax.Array:
-    """SYMM whose symmetric operand is already on the mesh as
-    ShardedTriTiles — no distribute step for A at all.  ``pin_b=True``
-    keeps the staged B row shares ``P(axis)``-sharded (the sharded-B
-    entry point) instead of letting GSPMD replicate them."""
-    n1, n2 = st.n, b.shape[1]
-    plan = make_2d_plan(st.c, n1, n2)
-    b_dist = distribute_rows_jnp(b, plan)
-    if pin_b:
-        b_dist = _pin_row_shards(b_dist, mesh, axis)
-    c_dist = symm_2d(st.off, st.diag, b_dist, plan, mesh, axis)
-    return collect_rows_jnp(c_dist, plan)
-
-
-def symm_2d_packed_a(a_packed: jax.Array, b: jax.Array, c: int, mesh: Mesh,
-                     axis: str, pin_b: bool = False) -> jax.Array:
-    """f32 packed tril (tril_size(n1),) × (n1, n2) -> (n1, n2).
-
-    The symmetric operand arrives element-packed and is scattered
-    straight into the extended triangle-block shards (a pure
-    index-table scatter — the distribute_sym step without the dense
-    (n1_pad, n1_pad) staging buffer)."""
-    n1 = b.shape[0]
-    st = ShardedTriTiles.from_packed(a_packed, n1, c)
-    return symm_2d_sharded_a(st, b, mesh, axis, pin_b=pin_b)
-
-
-# ---- batched stacks on the 2D wire ----------------------------------------
-def syrk_2d_sharded_stacked(a: jax.Array, c: int, mesh: Mesh, axis: str
-                            ) -> ShardedTriTiles:
-    """f32 (k, n1, n2) -> batched ShardedTriTiles (leading stack dim):
-    the whole stack rides ONE all-to-all payload."""
-    _, n1, n2 = a.shape
-    plan = make_2d_plan(c, n1, n2)
-    off, diag = syrk_2d_stacked(distribute_rows_stacked_jnp(a, plan), plan,
-                                mesh, axis)
-    return ShardedTriTiles(jnp.moveaxis(off, 0, 1),
-                           jnp.moveaxis(diag, 0, 1), n1, c)
-
-
-def syr2k_2d_sharded_stacked(a: jax.Array, b: jax.Array, c: int,
-                             mesh: Mesh, axis: str) -> ShardedTriTiles:
-    _, n1, n2 = a.shape
-    plan = make_2d_plan(c, n1, n2)
-    off, diag = syr2k_2d_stacked(distribute_rows_stacked_jnp(a, plan),
-                                 distribute_rows_stacked_jnp(b, plan),
-                                 plan, mesh, axis)
-    return ShardedTriTiles(jnp.moveaxis(off, 0, 1),
-                           jnp.moveaxis(diag, 0, 1), n1, c)
-
-
-def symm_2d_packed_a_stacked(a_packed: jax.Array, b: jax.Array, c: int,
-                             mesh: Mesh, axis: str) -> jax.Array:
-    """f32 (k, tril_size(n1)) × (k, n1, n2) -> (k, n1, n2): the packed
-    stack scatters into batched shards, B rides the stacked exchange."""
-    _, n1, n2 = b.shape
-    st = ShardedTriTiles.from_packed(a_packed, n1, c)
-    plan = make_2d_plan(c, n1, n2)
-    c_dist = symm_2d_stacked(jnp.moveaxis(st.off, 0, 1),
-                             jnp.moveaxis(st.diag, 0, 1),
-                             distribute_rows_stacked_jnp(b, plan),
-                             plan, mesh, axis)
-    return collect_rows_stacked_jnp(c_dist, plan)
-
-
-def syrk_2d_dense(a: jax.Array, c: int, mesh: Mesh, axis: str) -> jax.Array:
-    """Explicit dense exit: packed wire + one unpack of the result."""
-    return syrk_2d_sharded(a, c, mesh, axis).to_tril()
-
-
-def syr2k_2d_dense(a: jax.Array, b: jax.Array, c: int, mesh: Mesh,
-                   axis: str) -> jax.Array:
-    return syr2k_2d_sharded(a, b, c, mesh, axis).to_tril()
-
-
-def symm_2d_dense(a_sym: jax.Array, b: jax.Array, c: int, mesh: Mesh,
-                  axis: str, pin_b: bool = False) -> jax.Array:
-    """tril-valid dense A: pack the triangle (reads tril only), then the
-    packed entrance above."""
-    return symm_2d_packed_a(pack_tril(jnp.tril(a_sym)), b, c, mesh, axis,
-                            pin_b=pin_b)
-
-
-# --------------------------------------------------------------------------
-# ring path: computation-optimal cyclic shift (flop-halving SYRK/SYR2K)
-# --------------------------------------------------------------------------
-def _pin_row_shards(x: jax.Array, mesh: Mesh, *axes: str) -> jax.Array:
-    """Constrain the leading device axes of a staged (P, …) — or
-    (p1, p2, …) — buffer to the mesh axes, so a ``P(axis)``-row-sharded
-    operand enters the shard_map without a replicating gather first."""
-    from jax.sharding import NamedSharding
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*axes)))
-
-
 def _ring_stage(x: jax.Array, nsh: int) -> jax.Array:
     """(…, n1, n2) -> (nsh, …, nb, n2): zero-pad the rows to nsh·nb
     blocks and move the device-block axis to the front; leading batch
@@ -524,16 +373,72 @@ def symm_ring_dense(a_sym: jax.Array, b: jax.Array, mesh: Mesh, axis: str,
 
 
 # --------------------------------------------------------------------------
-# 3D paths (Algs 13–15): p1 × p2 grid from a single-axis mesh, packed wire
+# 2D (Algs 10–12): P == c(c+1) triangle-block grid, the stack on the
+# all-to-all payload
+# --------------------------------------------------------------------------
+def _stack_leading(off: jax.Array, diag: jax.Array, n1: int, c: int
+                   ) -> ShardedTriTiles:
+    """Device-leading (P, K, …) core output -> stack-leading
+    ShardedTriTiles (K, P, …)."""
+    return ShardedTriTiles(jnp.moveaxis(off, 0, 1), jnp.moveaxis(diag, 0, 1),
+                           n1, c)
+
+
+@_batch_native(1)
+def syrk_2d_sharded(a: jax.Array, c: int, mesh: Mesh, axis: str
+                    ) -> ShardedTriTiles:
+    """f32 (…, n1, n2) -> per-device extended triangle blocks of
+    tril(A·Aᵀ), stack dims leading — the output stays in the
+    ~n²/(2P)-per-device wire format; callers gather only the packed
+    words (``.to_packed()``)."""
+    _, n1, n2 = a.shape
+    plan = make_2d_plan(c, n1, n2)
+    off, diag = syrk_2d(distribute_rows_jnp(a, plan), plan, mesh, axis)
+    return _stack_leading(off, diag, n1, c)
+
+
+@_batch_native(2)
+def syr2k_2d_sharded(a: jax.Array, b: jax.Array, c: int, mesh: Mesh,
+                     axis: str) -> ShardedTriTiles:
+    _, n1, n2 = a.shape
+    plan = make_2d_plan(c, n1, n2)
+    off, diag = syr2k_2d(distribute_rows_jnp(a, plan),
+                         distribute_rows_jnp(b, plan), plan, mesh, axis)
+    return _stack_leading(off, diag, n1, c)
+
+
+@_batch_native(2)
+def symm_2d_packed_a(a, b: jax.Array, c: int, mesh: Mesh, axis: str,
+                     pin_b: bool = False) -> jax.Array:
+    """Packed-layout A — the packed triangle (…, tril_size(n1)) or a
+    mesh-resident ShardedTriTiles, no distribute step for A at all —
+    × (…, n1, n2) -> (…, n1, n2).  ``pin_b=True`` keeps the staged B row
+    shares ``P(axis)``-sharded (the sharded-B entry point) instead of
+    letting GSPMD replicate them."""
+    _, n1, n2 = b.shape
+    st = _on_grid(a, n1, c)
+    plan = make_2d_plan(c, n1, n2)
+    b_dist = distribute_rows_jnp(b, plan)
+    if pin_b:
+        b_dist = _pin_row_shards(b_dist, mesh, axis)
+    c_dist = symm_2d(jnp.moveaxis(st.off, 0, 1), jnp.moveaxis(st.diag, 0, 1),
+                     b_dist, plan, mesh, axis)
+    return collect_rows_jnp(c_dist, plan)
+
+
+# --------------------------------------------------------------------------
+# 3D (Algs 13–15): p1 × p2 grid from a single-axis mesh; the stack rides
+# the in-slice all-to-all and the cross-slice reduce-scatter / all-gather
 # --------------------------------------------------------------------------
 def _mesh_3d(mesh: Mesh, p1: int, p2: int) -> Mesh:
     devs = np.asarray(mesh.devices).reshape(-1)
     return Mesh(devs[:p1 * p2].reshape(p1, p2), (TB_AXIS, REP_AXIS))
 
 
+@_batch_native(1)
 def syrk_3d_sharded(a: jax.Array, c: int, p2: int, mesh: Mesh
                     ) -> ShardedTriTiles:
-    n1, n2 = a.shape
+    _, n1, n2 = a.shape
     plan = make_2d_plan(c, n1, n2 // p2)
     mesh3 = _mesh_3d(mesh, c * (c + 1), p2)
     flat = syrk_3d(distribute_rows_3d_jnp(a, plan, p2), plan, mesh3,
@@ -541,9 +446,10 @@ def syrk_3d_sharded(a: jax.Array, c: int, p2: int, mesh: Mesh
     return _sharded_from_flat(flat, plan, n1, c)
 
 
+@_batch_native(2)
 def syr2k_3d_sharded(a: jax.Array, b: jax.Array, c: int, p2: int,
                      mesh: Mesh) -> ShardedTriTiles:
-    n1, n2 = a.shape
+    _, n1, n2 = a.shape
     plan = make_2d_plan(c, n1, n2 // p2)
     mesh3 = _mesh_3d(mesh, c * (c + 1), p2)
     flat = syr2k_3d(distribute_rows_3d_jnp(a, plan, p2),
@@ -552,12 +458,14 @@ def syr2k_3d_sharded(a: jax.Array, b: jax.Array, c: int, p2: int,
     return _sharded_from_flat(flat, plan, n1, c)
 
 
-def symm_3d_sharded_a(st: ShardedTriTiles, b: jax.Array, p2: int,
-                      mesh: Mesh, pin_b: bool = False) -> jax.Array:
-    """3D SYMM with the symmetric operand already in ShardedTriTiles.
+@_batch_native(2)
+def symm_3d_packed_a(a, b: jax.Array, c: int, p2: int, mesh: Mesh,
+                     pin_b: bool = False) -> jax.Array:
+    """Packed-layout A (as :func:`symm_2d_packed_a`) × (…, n1, n2),
+    its extended triangle blocks shard-split over the replication axis.
     ``pin_b=True`` keeps the staged B shares ``P(p1, p2)``-sharded."""
-    n1, n2 = st.n, b.shape[1]
-    c = st.c
+    _, n1, n2 = b.shape
+    st = _on_grid(a, n1, c)
     plan = make_2d_plan(c, n1, n2 // p2)
     mesh3 = _mesh_3d(mesh, c * (c + 1), p2)
     b_dist = distribute_rows_3d_jnp(b, plan, p2)
@@ -568,69 +476,8 @@ def symm_3d_sharded_a(st: ShardedTriTiles, b: jax.Array, p2: int,
     return collect_rows_3d_jnp(c_dist, plan, p2)
 
 
-def symm_3d_packed_a(a_packed: jax.Array, b: jax.Array, c: int, p2: int,
-                     mesh: Mesh, pin_b: bool = False) -> jax.Array:
-    """f32 packed tril × (n1, n2) -> (n1, n2): packed scatter into the
-    extended triangle blocks, shard-split over the replication axis."""
-    st = ShardedTriTiles.from_packed(a_packed, b.shape[0], c)
-    return symm_3d_sharded_a(st, b, p2, mesh, pin_b=pin_b)
-
-
-# ---- batched stacks on the 3D wire ----------------------------------------
-def syrk_3d_sharded_stacked(a: jax.Array, c: int, p2: int, mesh: Mesh
-                            ) -> ShardedTriTiles:
-    """f32 (k, n1, n2) -> batched ShardedTriTiles: the stack rides the
-    in-slice all-to-all and the cross-slice reduce-scatter payloads."""
-    _, n1, n2 = a.shape
-    plan = make_2d_plan(c, n1, n2 // p2)
-    mesh3 = _mesh_3d(mesh, c * (c + 1), p2)
-    flat = syrk_3d_stacked(distribute_rows_3d_stacked_jnp(a, plan, p2),
-                           plan, mesh3, TB_AXIS, REP_AXIS)
-    return _sharded_from_flat_stacked(flat, plan, n1, c)
-
-
-def syr2k_3d_sharded_stacked(a: jax.Array, b: jax.Array, c: int, p2: int,
-                             mesh: Mesh) -> ShardedTriTiles:
-    _, n1, n2 = a.shape
-    plan = make_2d_plan(c, n1, n2 // p2)
-    mesh3 = _mesh_3d(mesh, c * (c + 1), p2)
-    flat = syr2k_3d_stacked(distribute_rows_3d_stacked_jnp(a, plan, p2),
-                            distribute_rows_3d_stacked_jnp(b, plan, p2),
-                            plan, mesh3, TB_AXIS, REP_AXIS)
-    return _sharded_from_flat_stacked(flat, plan, n1, c)
-
-
-def symm_3d_packed_a_stacked(a_packed: jax.Array, b: jax.Array, c: int,
-                             p2: int, mesh: Mesh) -> jax.Array:
-    """f32 (k, tril_size(n1)) × (k, n1, n2) -> (k, n1, n2)."""
-    _, n1, n2 = b.shape
-    st = ShardedTriTiles.from_packed(a_packed, n1, c)
-    plan = make_2d_plan(c, n1, n2 // p2)
-    mesh3 = _mesh_3d(mesh, c * (c + 1), p2)
-    c_dist = symm_3d_stacked(_flat_from_sharded_stacked(st, p2),
-                             distribute_rows_3d_stacked_jnp(b, plan, p2),
-                             plan, mesh3, TB_AXIS, REP_AXIS)
-    return jax.vmap(lambda d: collect_rows_3d_jnp(d, plan, p2))(
-        c_dist.transpose(2, 0, 1, 3, 4, 5))
-
-
-def syrk_3d_dense(a: jax.Array, c: int, p2: int, mesh: Mesh) -> jax.Array:
-    return syrk_3d_sharded(a, c, p2, mesh).to_tril()
-
-
-def syr2k_3d_dense(a: jax.Array, b: jax.Array, c: int, p2: int, mesh: Mesh
-                   ) -> jax.Array:
-    return syr2k_3d_sharded(a, b, c, p2, mesh).to_tril()
-
-
-def symm_3d_dense(a_sym: jax.Array, b: jax.Array, c: int, p2: int,
-                  mesh: Mesh, pin_b: bool = False) -> jax.Array:
-    return symm_3d_packed_a(pack_tril(jnp.tril(a_sym)), b, c, p2, mesh,
-                            pin_b=pin_b)
-
-
 # --------------------------------------------------------------------------
-# 3D limited-memory paths (Algs 16–18, §IX): streamed b-column chunks
+# 3D limited-memory (Algs 16–18, §IX): streamed b-column chunks, unbatched
 # --------------------------------------------------------------------------
 def _limited_steps(n2: int, p2: int, b: int):
     """Clamp the chunk to the per-slice column count and return
@@ -645,30 +492,26 @@ def _limited_steps(n2: int, p2: int, b: int):
 def _chunk_cols_3d_jnp(x: jax.Array, plan_b: TwoDPlan, p2: int,
                        nsteps: int) -> jax.Array:
     """(n1, n2) -> (p1, p2, nsteps, c, nb, bw): column slices over the
-    replication axis, b-column chunks within each, 2D row-share layout
-    per chunk (n2 % p2 == 0 required)."""
+    replication axis, each zero-padded to nsteps b-column chunks, 2D
+    row-share layout per chunk (n2 % p2 == 0 required) — the 3d layout
+    with p2·nsteps slices."""
     n1, n2 = x.shape
-    b = plan_b.n2
-    n2s = n2 // p2
-    xs = x.reshape(n1, p2, n2s).transpose(1, 0, 2)        # (p2, n1, n2s)
-    xs = jnp.pad(xs, ((0, 0), (0, 0), (0, nsteps * b - n2s)))
-    xc = xs.reshape(p2, n1, nsteps, b).transpose(0, 2, 1, 3)
-    dist = jax.vmap(jax.vmap(
-        lambda s: distribute_rows_jnp(s, plan_b)))(xc)
-    return dist.transpose(2, 0, 1, 3, 4, 5)               # (p1, p2, ...)
+    pad = nsteps * plan_b.n2 - n2 // p2
+    xs = jnp.pad(x.reshape(n1, p2, n2 // p2), ((0, 0), (0, 0), (0, pad)))
+    d = distribute_rows_3d_jnp(xs.reshape(1, n1, -1), plan_b, p2 * nsteps)
+    return d.reshape((d.shape[0], p2, nsteps) + d.shape[3:])
 
 
 def _collect_cols_3d_jnp(c_dist: jax.Array, plan_b: TwoDPlan, p2: int,
                          n2: int) -> jax.Array:
     """Inverse of :func:`_chunk_cols_3d_jnp` for the SYMM output
     (drops the zero-padded tail columns)."""
-    per = jax.vmap(jax.vmap(
-        lambda d: collect_rows_jnp(d, plan_b)))(
-        c_dist.transpose(1, 2, 0, 3, 4, 5))               # (p2, ns, n1, b)
-    n1 = per.shape[-2]
-    n2s = n2 // p2
-    per = per.transpose(0, 2, 1, 3).reshape(p2, n1, -1)[:, :, :n2s]
-    return per.transpose(1, 0, 2).reshape(n1, n2)
+    p1, _, nsteps = c_dist.shape[:3]
+    full = collect_rows_3d_jnp(
+        c_dist.reshape((p1, p2 * nsteps, 1) + c_dist.shape[3:]), plan_b,
+        p2 * nsteps)[0]                            # (n1, p2·nsteps·b)
+    n1 = full.shape[0]
+    return full.reshape(n1, p2, -1)[:, :, :n2 // p2].reshape(n1, n2)
 
 
 def syrk_3d_limited_sharded(a: jax.Array, c: int, p2: int, chunk: int,
@@ -699,12 +542,11 @@ def syr2k_3d_limited_sharded(a: jax.Array, b_mat: jax.Array, c: int,
     return _sharded_from_flat(flat, plan_b, n1, c)
 
 
-def symm_3d_limited_sharded_a(st: ShardedTriTiles, b: jax.Array, p2: int,
-                              chunk: int, mesh: Mesh, pin_b: bool = False
-                              ) -> jax.Array:
+def symm_3d_limited_packed_a(a, b: jax.Array, c: int, p2: int, chunk: int,
+                             mesh: Mesh, pin_b: bool = False) -> jax.Array:
     """Alg 18: gather A's triangle blocks once, stream B/C chunks."""
-    n1, n2 = st.n, b.shape[1]
-    c = st.c
+    n1, n2 = b.shape
+    st = _on_grid(a, n1, c)
     bw, nsteps = _limited_steps(n2, p2, chunk)
     plan_b = make_2d_plan(c, n1, bw)
     mesh3 = _mesh_3d(mesh, c * (c + 1), p2)
@@ -716,25 +558,79 @@ def symm_3d_limited_sharded_a(st: ShardedTriTiles, b: jax.Array, p2: int,
     return _collect_cols_3d_jnp(c_dist, plan_b, p2, n2)
 
 
-def symm_3d_limited_packed_a(a_packed: jax.Array, b: jax.Array, c: int,
-                             p2: int, chunk: int, mesh: Mesh,
-                             pin_b: bool = False) -> jax.Array:
-    st = ShardedTriTiles.from_packed(a_packed, b.shape[0], c)
-    return symm_3d_limited_sharded_a(st, b, p2, chunk, mesh, pin_b=pin_b)
+# --------------------------------------------------------------------------
+# the route table
+# --------------------------------------------------------------------------
+@dataclass(frozen=True)
+class Wire:
+    """The schedules of one mesh route family, on f32 operands:
+    ``syrk(a, mesh, route)``, ``syr2k(a, b, mesh, route)`` and
+    ``symm(a, b, mesh, route, pin_b)``.  The route supplies the axis and
+    the grid (``route.choice``).
+
+    ``syrk``/``syr2k`` return the replicated packed triangle
+    (…, tril_size(n1)) or, where ``sharded``, the mesh-resident
+    ShardedTriTiles; ``symm`` takes its symmetric operand as the packed
+    triangle or as ShardedTriTiles.  ``pin_b`` keeps a row-sharded B
+    sharded into the shard_map (the 1D wire column-shards B itself and
+    ignores it).  The ``*_dense`` members, where set, are the family's
+    own dense exits (``syrk_dense(a, mesh, route, symmetric)``) and
+    dense-A entrance (``symm_dense(a, b, mesh, route, pin_b)``); the
+    other families exit and enter through the packed triangle."""
+    syrk: Callable
+    syr2k: Callable
+    symm: Callable
+    sharded: bool = False
+    syrk_dense: Optional[Callable] = None
+    syr2k_dense: Optional[Callable] = None
+    symm_dense: Optional[Callable] = None
 
 
-def syrk_3d_limited_dense(a: jax.Array, c: int, p2: int, chunk: int,
-                          mesh: Mesh) -> jax.Array:
-    return syrk_3d_limited_sharded(a, c, p2, chunk, mesh).to_tril()
+WIRES = {
+    "1d": Wire(
+        syrk=lambda a, mesh, r: syrk_1d_packed(a, mesh, r.axis),
+        syr2k=lambda a, b, mesh, r: syr2k_1d_packed(a, b, mesh, r.axis),
+        symm=lambda a, b, mesh, r, pin_b=False: symm_1d_packed_a(
+            as_packed(a), b, b.shape[-2], mesh, r.axis)),
+    "ring": Wire(
+        syrk=lambda a, mesh, r: syrk_ring_packed(a, mesh, r.axis),
+        syr2k=lambda a, b, mesh, r: syr2k_ring_packed(a, b, mesh, r.axis),
+        symm=lambda a, b, mesh, r, pin_b=False: symm_ring_packed_a(
+            as_packed(a), b, b.shape[-2], mesh, r.axis, pin_b),
+        syrk_dense=lambda a, mesh, r, symmetric: syrk_ring_dense(
+            a, mesh, r.axis, symmetric),
+        syr2k_dense=lambda a, b, mesh, r, symmetric: syr2k_ring_dense(
+            a, b, mesh, r.axis, symmetric),
+        symm_dense=lambda a, b, mesh, r, pin_b=False: symm_ring_dense(
+            a, b, mesh, r.axis, pin_b)),
+    "2d": Wire(
+        syrk=lambda a, mesh, r: syrk_2d_sharded(a, r.choice.c, mesh,
+                                                r.axis),
+        syr2k=lambda a, b, mesh, r: syr2k_2d_sharded(a, b, r.choice.c, mesh,
+                                                     r.axis),
+        symm=lambda a, b, mesh, r, pin_b=False: symm_2d_packed_a(
+            a, b, r.choice.c, mesh, r.axis, pin_b),
+        sharded=True),
+    "3d": Wire(
+        syrk=lambda a, mesh, r: syrk_3d_sharded(a, r.choice.c, r.choice.p2,
+                                                mesh),
+        syr2k=lambda a, b, mesh, r: syr2k_3d_sharded(
+            a, b, r.choice.c, r.choice.p2, mesh),
+        symm=lambda a, b, mesh, r, pin_b=False: symm_3d_packed_a(
+            a, b, r.choice.c, r.choice.p2, mesh, pin_b),
+        sharded=True),
+    "3d-limited": Wire(
+        syrk=lambda a, mesh, r: syrk_3d_limited_sharded(
+            a, r.choice.c, r.choice.p2, r.choice.b, mesh),
+        syr2k=lambda a, b, mesh, r: syr2k_3d_limited_sharded(
+            a, b, r.choice.c, r.choice.p2, r.choice.b, mesh),
+        symm=lambda a, b, mesh, r, pin_b=False: symm_3d_limited_packed_a(
+            a, b, r.choice.c, r.choice.p2, r.choice.b, mesh, pin_b),
+        sharded=True),
+}
 
 
-def syr2k_3d_limited_dense(a: jax.Array, b: jax.Array, c: int, p2: int,
-                           chunk: int, mesh: Mesh) -> jax.Array:
-    return syr2k_3d_limited_sharded(a, b, c, p2, chunk, mesh).to_tril()
-
-
-def symm_3d_limited_dense(a_sym: jax.Array, b: jax.Array, c: int, p2: int,
-                          chunk: int, mesh: Mesh, pin_b: bool = False
-                          ) -> jax.Array:
-    return symm_3d_limited_packed_a(pack_tril(jnp.tril(a_sym)), b, c, p2,
-                                    chunk, mesh, pin_b=pin_b)
+def wire(route) -> Optional[Wire]:
+    """The mesh schedules of ``route``, or None off the mesh (the
+    ``pallas`` and ``dense`` routes)."""
+    return WIRES.get(route.path)

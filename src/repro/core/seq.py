@@ -8,8 +8,8 @@ matrices streamed through fast memory, padded (zero) indices neither
 computed nor communicated (§VII-C).
 
 These are the faithful-reproduction reference for the sequential lower
-bounds (Cor 3–5): ``benchmarks/bench_seq_bounds.py`` verifies
-reads / lower_bound → 1 as sizes grow.
+bounds (Cor 3–5): ``tests/test_seq.py`` checks the read counts against
+the paper's cost formula and the lower bound.
 """
 from __future__ import annotations
 

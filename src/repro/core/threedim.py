@@ -14,35 +14,43 @@ p₂ = x = 2MP/n₁² (up to the owned-data term).
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .twodim import (TwoDPlan, _exchange_rows, _syrk_blocks, make_2d_plan,
-                     symm_2d_local, symm_2d_local_stacked, syr2k_2d_local,
-                     syr2k_2d_local_stacked, syrk_2d_local,
-                     syrk_2d_local_stacked, tb_flat_words)
+from .twodim import (TwoDPlan, symm_2d_local, syr2k_2d_local, syrk_2d_local,
+                     tb_flat_words)
 
 
 # --------------------------------------------------------------------------
 # local bodies (inside shard_map over axes (tb, rep))
 # --------------------------------------------------------------------------
+# The stack of K matrices rides the in-slice all-to-all and the
+# cross-slice reduce-scatter / all-gather as extra payload dims, as on
+# the 2D wire (core/twodim.py); an unbatched call is K = 1.
 def _flatten_tb(off: jax.Array, diag: jax.Array) -> jax.Array:
-    return jnp.concatenate([off.reshape(-1), diag.reshape(-1)])
+    """(…, T, nb, nb) + (…, nb, nb) -> (…, (T+1)·nb²)."""
+    lead = diag.shape[:-2]
+    return jnp.concatenate([off.reshape(lead + (-1,)),
+                            diag.reshape(lead + (-1,))], -1)
 
 
-def _unflatten_tb(flat: jax.Array, plan: TwoDPlan) -> Tuple[jax.Array, jax.Array]:
+def _unflatten_tb(flat: jax.Array, plan: TwoDPlan
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """Inverse of :func:`_flatten_tb` (drops the shard padding)."""
+    lead = flat.shape[:-1]
     t = plan.T * plan.nb * plan.nb
-    off = flat[:t].reshape(plan.T, plan.nb, plan.nb)
-    diag = flat[t:t + plan.nb * plan.nb].reshape(plan.nb, plan.nb)
+    off = flat[..., :t].reshape(lead + (plan.T, plan.nb, plan.nb))
+    diag = flat[..., t:t + plan.nb * plan.nb].reshape(
+        lead + (plan.nb, plan.nb))
     return off, diag
 
 
 def _pad_to(x: jax.Array, mult: int) -> jax.Array:
-    pad = -x.shape[0] % mult
-    return jnp.pad(x, (0, pad))
+    """Zero-pad the last axis to a multiple of ``mult``."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, -x.shape[-1] % mult)])
 
 
 def _varying(x: jax.Array, axes: Tuple[str, ...]) -> jax.Array:
@@ -53,68 +61,35 @@ def _varying(x: jax.Array, axes: Tuple[str, ...]) -> jax.Array:
 def syrk_3d_local(a_own: jax.Array, plan: TwoDPlan, tb_axis: str,
                   rep_axis: str, p2: int) -> jax.Array:
     """Alg 13: 2D SYRK in-slice + reduce-scatter of the extended triangle
-    block over the replication axis.  a_own: (c, nb, w₂) with
-    w₂ = n₂/(p₂(c+1)).  Returns this device's flat shard of C_Tk."""
+    block over the replication axis.  a_own: (K, c, nb, w₂) with
+    w₂ = n₂/(p₂(c+1)).  Returns this device's flat shards of C_Tk
+    (K, shard)."""
     off, diag = syrk_2d_local(a_own, plan, tb_axis)
-    flat = _pad_to(_flatten_tb(off, diag), p2)
-    return jax.lax.psum_scatter(flat, rep_axis, scatter_dimension=0,
-                                tiled=True)
+    return jax.lax.psum_scatter(_pad_to(_flatten_tb(off, diag), p2),
+                                rep_axis, scatter_dimension=1, tiled=True)
 
 
 def syr2k_3d_local(a_own: jax.Array, b_own: jax.Array, plan: TwoDPlan,
                    tb_axis: str, rep_axis: str, p2: int) -> jax.Array:
+    """Alg 14, stacked as :func:`syrk_3d_local`."""
     off, diag = syr2k_2d_local(a_own, b_own, plan, tb_axis)
-    flat = _pad_to(_flatten_tb(off, diag), p2)
-    return jax.lax.psum_scatter(flat, rep_axis, scatter_dimension=0,
-                                tiled=True)
+    return jax.lax.psum_scatter(_pad_to(_flatten_tb(off, diag), p2),
+                                rep_axis, scatter_dimension=1, tiled=True)
 
 
 def symm_3d_local(a_flat_shard: jax.Array, b_own: jax.Array, plan: TwoDPlan,
                   tb_axis: str, rep_axis: str) -> jax.Array:
     """Alg 15: all-gather A_Tk over the replication axis, then 2D SYMM
-    in-slice.  a_flat_shard: this device's 1/p₂ of the flattened extended
-    triangle block of A; b_own: (c, nb, w₂).  Returns C shares (c, nb, w₂)."""
-    flat = jax.lax.all_gather(a_flat_shard, rep_axis, axis=0, tiled=True)
+    in-slice.  a_flat_shard (K, shard): this device's 1/p₂ of the
+    flattened extended triangle blocks of A; b_own (K, c, nb, w₂).
+    Returns C shares (K, c, nb, w₂)."""
+    flat = jax.lax.all_gather(a_flat_shard, rep_axis, axis=1, tiled=True)
     a_off, a_diag = _unflatten_tb(flat, plan)
     return symm_2d_local(a_off, a_diag, b_own, plan, tb_axis)
 
 
-# ---- batched stacks on the 3D wire ----------------------------------------
-# Same payload-stacking as the 2D wire: the K-stack rides the in-slice
-# all-to-all and the cross-slice reduce-scatter / all-gather as extra
-# payload dims (scatter/gather dimension shifts from 0 to 1).
-def syrk_3d_local_stacked(a_own: jax.Array, plan: TwoDPlan, tb_axis: str,
-                          rep_axis: str, p2: int) -> jax.Array:
-    """a_own (K, c, nb, w₂) -> (K, shard) flat C_Tk shards."""
-    off, diag = syrk_2d_local_stacked(a_own, plan, tb_axis)
-    K = off.shape[0]
-    flat = jnp.concatenate([off.reshape(K, -1), diag.reshape(K, -1)], 1)
-    flat = jnp.pad(flat, ((0, 0), (0, -flat.shape[1] % p2)))
-    return jax.lax.psum_scatter(flat, rep_axis, scatter_dimension=1,
-                                tiled=True)
-
-
-def syr2k_3d_local_stacked(a_own: jax.Array, b_own: jax.Array,
-                           plan: TwoDPlan, tb_axis: str, rep_axis: str,
-                           p2: int) -> jax.Array:
-    off, diag = syr2k_2d_local_stacked(a_own, b_own, plan, tb_axis)
-    K = off.shape[0]
-    flat = jnp.concatenate([off.reshape(K, -1), diag.reshape(K, -1)], 1)
-    flat = jnp.pad(flat, ((0, 0), (0, -flat.shape[1] % p2)))
-    return jax.lax.psum_scatter(flat, rep_axis, scatter_dimension=1,
-                                tiled=True)
-
-
-def symm_3d_local_stacked(a_flat_shard: jax.Array, b_own: jax.Array,
-                          plan: TwoDPlan, tb_axis: str, rep_axis: str
-                          ) -> jax.Array:
-    """a_flat_shard (K, shard), b_own (K, c, nb, w₂) -> (K, c, nb, w₂)."""
-    flat = jax.lax.all_gather(a_flat_shard, rep_axis, axis=1, tiled=True)
-    a_off, a_diag = jax.vmap(lambda f: _unflatten_tb(f, plan))(flat)
-    return symm_2d_local_stacked(a_off, a_diag, b_own, plan, tb_axis)
-
-
-# ---- limited-memory variants (Algs 16–18) ---------------------------------
+# ---- limited-memory variants (Algs 16–18), unbatched ----------------------
+# Each streamed chunk runs the in-slice 2D schedule as a stack of one.
 def _zero_tb(plan: TwoDPlan, dtype, axes: Tuple[str, ...]
              ) -> Tuple[jax.Array, jax.Array]:
     """The owned extended triangle block (off, diag), zeroed — the scan
@@ -130,8 +105,8 @@ def syrk_3d_limited_local(a_own_chunks: jax.Array, plan: TwoDPlan,
     through a lax.scan, each step's 2D rank update accumulated into the
     owned extended triangle block; one reduce-scatter at the end."""
     def step(acc, chunk):
-        off, diag = syrk_2d_local(chunk, plan, tb_axis)
-        return (acc[0] + off, acc[1] + diag), None
+        off, diag = syrk_2d_local(chunk[None], plan, tb_axis)
+        return (acc[0] + off[0], acc[1] + diag[0]), None
 
     acc0 = _zero_tb(plan, a_own_chunks.dtype, (tb_axis, rep_axis))
     (off, diag), _ = jax.lax.scan(step, acc0, a_own_chunks)
@@ -144,8 +119,8 @@ def syr2k_3d_limited_local(a_own_chunks: jax.Array, b_own_chunks: jax.Array,
                            p2: int) -> jax.Array:
     """Alg 17: like Alg 16 with the symmetrized two-sided update."""
     def step(acc, ab):
-        off, diag = syr2k_2d_local(ab[0], ab[1], plan, tb_axis)
-        return (acc[0] + off, acc[1] + diag), None
+        off, diag = syr2k_2d_local(ab[0][None], ab[1][None], plan, tb_axis)
+        return (acc[0] + off[0], acc[1] + diag[0]), None
 
     acc0 = _zero_tb(plan, a_own_chunks.dtype, (tb_axis, rep_axis))
     (off, diag), _ = jax.lax.scan(step, acc0,
@@ -162,7 +137,8 @@ def symm_3d_limited_local(a_flat_shard: jax.Array, b_own_chunks: jax.Array,
     a_off, a_diag = _unflatten_tb(flat, plan)
 
     def step(_, chunk):
-        return None, symm_2d_local(a_off, a_diag, chunk, plan, tb_axis)
+        return None, symm_2d_local(a_off[None], a_diag[None], chunk[None],
+                                   plan, tb_axis)[0]
 
     _, c_chunks = jax.lax.scan(step, None, b_own_chunks)
     return c_chunks  # (nsteps, c, nb, bw)
@@ -171,124 +147,56 @@ def symm_3d_limited_local(a_flat_shard: jax.Array, b_own_chunks: jax.Array,
 # --------------------------------------------------------------------------
 # full-array wrappers over a 2-axis mesh
 # --------------------------------------------------------------------------
+def _grid_map(local, n_in: int, mesh, tb_axis: str, rep_axis: str):
+    """shard_map a per-device body over the (tb, rep) grid: every input
+    and the output carry the two device axes first."""
+    def body(*xs):                     # xs: (1, 1, …) per device
+        return local(*(x[0, 0] for x in xs))[None, None]
+
+    spec = P(tb_axis, rep_axis)
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,) * n_in,
+                                 out_specs=spec))
+
+
 def syrk_3d(a_dist: jax.Array, plan: TwoDPlan, mesh, tb_axis: str = "tb",
             rep_axis: str = "rep") -> jax.Array:
-    """a_dist global (p1, p2, c, nb, w2) sharded P(tb, rep)."""
-    p2 = mesh.shape[rep_axis]
+    """a_dist global (p1, p2, K, c, nb, w2) sharded P(tb, rep) ->
+    (p1, p2, K, shard)."""
     f = functools.partial(syrk_3d_local, plan=plan, tb_axis=tb_axis,
-                          rep_axis=rep_axis, p2=p2)
-
-    def body(a):                       # a: (1, 1, c, nb, w2) per device
-        return f(a[0, 0])[None, None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=P(tb_axis, rep_axis),
-        out_specs=P(tb_axis, rep_axis)))(a_dist)
+                          rep_axis=rep_axis, p2=mesh.shape[rep_axis])
+    return _grid_map(f, 1, mesh, tb_axis, rep_axis)(a_dist)
 
 
 def syr2k_3d(a_dist, b_dist, plan: TwoDPlan, mesh, tb_axis="tb",
              rep_axis="rep"):
-    p2 = mesh.shape[rep_axis]
     f = functools.partial(syr2k_3d_local, plan=plan, tb_axis=tb_axis,
-                          rep_axis=rep_axis, p2=p2)
-
-    def body(a, b):
-        return f(a[0, 0], b[0, 0])[None, None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
-        out_specs=P(tb_axis, rep_axis)))(a_dist, b_dist)
+                          rep_axis=rep_axis, p2=mesh.shape[rep_axis])
+    return _grid_map(f, 2, mesh, tb_axis, rep_axis)(a_dist, b_dist)
 
 
 def symm_3d(a_flat, b_dist, plan: TwoDPlan, mesh, tb_axis="tb",
             rep_axis="rep"):
-    """a_flat global (p1, p2, shard) sharded P(tb, rep);
-    b_dist global (p1, p2, c, nb, w2)."""
-    f = functools.partial(symm_3d_local, plan=plan, tb_axis=tb_axis,
-                          rep_axis=rep_axis)
-
-    def body(a, b):
-        return f(a[0, 0], b[0, 0])[None, None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
-        out_specs=P(tb_axis, rep_axis)))(a_flat, b_dist)
-
-
-def syrk_3d_stacked(a_dist: jax.Array, plan: TwoDPlan, mesh,
-                    tb_axis: str = "tb", rep_axis: str = "rep"
-                    ) -> jax.Array:
-    """a_dist global (p1, p2, K, c, nb, w2) sharded P(tb, rep) ->
-    (p1, p2, K, shard)."""
-    p2 = mesh.shape[rep_axis]
-    f = functools.partial(syrk_3d_local_stacked, plan=plan,
-                          tb_axis=tb_axis, rep_axis=rep_axis, p2=p2)
-
-    def body(a):
-        return f(a[0, 0])[None, None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=P(tb_axis, rep_axis),
-        out_specs=P(tb_axis, rep_axis)))(a_dist)
-
-
-def syr2k_3d_stacked(a_dist, b_dist, plan: TwoDPlan, mesh, tb_axis="tb",
-                     rep_axis="rep"):
-    p2 = mesh.shape[rep_axis]
-    f = functools.partial(syr2k_3d_local_stacked, plan=plan,
-                          tb_axis=tb_axis, rep_axis=rep_axis, p2=p2)
-
-    def body(a, b):
-        return f(a[0, 0], b[0, 0])[None, None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
-        out_specs=P(tb_axis, rep_axis)))(a_dist, b_dist)
-
-
-def symm_3d_stacked(a_flat, b_dist, plan: TwoDPlan, mesh, tb_axis="tb",
-                    rep_axis="rep"):
     """a_flat global (p1, p2, K, shard) sharded P(tb, rep);
     b_dist global (p1, p2, K, c, nb, w2)."""
-    f = functools.partial(symm_3d_local_stacked, plan=plan,
-                          tb_axis=tb_axis, rep_axis=rep_axis)
-
-    def body(a, b):
-        return f(a[0, 0], b[0, 0])[None, None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
-        out_specs=P(tb_axis, rep_axis)))(a_flat, b_dist)
+    f = functools.partial(symm_3d_local, plan=plan, tb_axis=tb_axis,
+                          rep_axis=rep_axis)
+    return _grid_map(f, 2, mesh, tb_axis, rep_axis)(a_flat, b_dist)
 
 
 def syrk_3d_limited(a_chunks: jax.Array, plan: TwoDPlan, mesh,
                     tb_axis: str = "tb", rep_axis: str = "rep") -> jax.Array:
     """a_chunks global (p1, p2, nsteps, c, nb, bw) sharded P(tb, rep);
     plan is the per-chunk 2D plan (n₂ = b).  Returns (p1, p2, shard)."""
-    p2 = mesh.shape[rep_axis]
     f = functools.partial(syrk_3d_limited_local, plan=plan, tb_axis=tb_axis,
-                          rep_axis=rep_axis, p2=p2)
-
-    def body(a):                   # a: (1, 1, nsteps, c, nb, bw) per device
-        return f(a[0, 0])[None, None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=P(tb_axis, rep_axis),
-        out_specs=P(tb_axis, rep_axis)))(a_chunks)
+                          rep_axis=rep_axis, p2=mesh.shape[rep_axis])
+    return _grid_map(f, 1, mesh, tb_axis, rep_axis)(a_chunks)
 
 
 def syr2k_3d_limited(a_chunks, b_chunks, plan: TwoDPlan, mesh,
                      tb_axis="tb", rep_axis="rep"):
-    p2 = mesh.shape[rep_axis]
     f = functools.partial(syr2k_3d_limited_local, plan=plan, tb_axis=tb_axis,
-                          rep_axis=rep_axis, p2=p2)
-
-    def body(a, b):
-        return f(a[0, 0], b[0, 0])[None, None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
-        out_specs=P(tb_axis, rep_axis)))(a_chunks, b_chunks)
+                          rep_axis=rep_axis, p2=mesh.shape[rep_axis])
+    return _grid_map(f, 2, mesh, tb_axis, rep_axis)(a_chunks, b_chunks)
 
 
 def symm_3d_limited(a_flat, b_chunks, plan: TwoDPlan, mesh,
@@ -298,13 +206,7 @@ def symm_3d_limited(a_flat, b_chunks, plan: TwoDPlan, mesh,
     in the same (p1, p2, nsteps, c, nb, bw) layout."""
     f = functools.partial(symm_3d_limited_local, plan=plan, tb_axis=tb_axis,
                           rep_axis=rep_axis)
-
-    def body(a, b):
-        return f(a[0, 0], b[0, 0])[None, None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P(tb_axis, rep_axis),) * 2,
-        out_specs=P(tb_axis, rep_axis)))(a_flat, b_chunks)
+    return _grid_map(f, 2, mesh, tb_axis, rep_axis)(a_flat, b_chunks)
 
 
 def flat_tb_size(plan: TwoDPlan) -> int:

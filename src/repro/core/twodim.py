@@ -20,6 +20,10 @@ Data layout per device k (leading axis = mesh axis of size P):
   * symmetric extended triangle block: off-diag ``(T, nb, nb)`` for the
     T = c(c−1)/2 pairs (i>j ∈ R_k, lexicographic) plus diag ``(nb, nb)``
     for the assigned diagonal block D_k (zeros when |D_k| = 0).
+
+The schedules are batch-native: each per-device array carries a stack
+axis K right behind the device axis (``(P, K, c, nb, w)`` globally), and
+the stack rides the exchange payloads.  An unbatched call is K = 1.
 """
 from __future__ import annotations
 
@@ -254,67 +258,27 @@ def tb_device_row_starts(c: int, n1: int, k: int
 
 
 # --------------------------------------------------------------------------
-# the all-to-all row exchange (Alg 10 lines 3–14)
+# the all-to-all row exchange (Alg 10 lines 3–14), batch-native
 # --------------------------------------------------------------------------
-def _exchange_rows(a_own: jax.Array, plan: TwoDPlan, axis: str) -> jax.Array:
-    """(c, nb, w) own shares -> (c, nb, n2_pad) fully assembled rows."""
-    c, nb, w = plan.c, plan.nb, plan.w
-    k = jax.lax.axis_index(axis)
-    # build send buffer: row p = our share of the row block shared with p
-    own_pad = jnp.concatenate([a_own, jnp.zeros((1, nb, w), a_own.dtype)], 0)
-    send = own_pad[jnp.asarray(plan.send_slot)[k]]            # (P, nb, w)
-    recv = jax.lax.all_to_all(send, axis, 0, 0, tiled=True)    # (P, nb, w)
-    # assemble: rows[s] = concat over j of share from Q_i[j]
-    gsrc = jnp.asarray(plan.gather_src)[k]                     # (c, c+1)
-    is_self = gsrc == k                                        # (c, c+1)
-    shares = recv[gsrc]                                        # (c, c+1, nb, w)
-    shares = jnp.where(is_self[:, :, None, None], a_own[:, None], shares)
-    rows = shares.transpose(0, 2, 1, 3).reshape(c, nb, (c + 1) * w)
-    return rows
-
-
-def _reverse_exchange(c_partial: jax.Array, plan: TwoDPlan, axis: str
-                      ) -> jax.Array:
-    """SYMM output reduction (Alg 12 lines 21–33): partial full rows
-    (c, nb, n2_pad) -> summed own column shares (c, nb, w)."""
-    c, nb, w = plan.c, plan.nb, plan.w
-    k = jax.lax.axis_index(axis)
-    parts = c_partial.reshape(c, nb, c + 1, w)                # col shares
-    # send: to peer p, our partial of the shared row, p's column share
-    slot = jnp.asarray(plan.send_slot)[k]                      # (P,)
-    pcol = jnp.asarray(plan.peer_col)[k]                       # (P,)
-    valid = jnp.asarray(plan.send_valid)[k]                    # (P,)
-    parts_pad = jnp.concatenate(
-        [parts, jnp.zeros((1, nb, c + 1, w), parts.dtype)], 0)
-    send = parts_pad[slot, :, pcol] * valid[:, None, None]     # (P, nb, w)
-    recv = jax.lax.all_to_all(send, axis, 0, 0, tiled=True)    # (P, nb, w)
-    # sum received pieces into their slots (+ our own column share)
-    seg = jnp.where(valid, slot, c)                            # (P,)
-    summed = jax.ops.segment_sum(recv, seg, num_segments=c + 1)[:c]
-    own = jnp.take_along_axis(
-        parts, jnp.asarray(plan.self_col)[k][:, None, None, None], axis=2
-    )[:, :, 0, :]                                              # (c, nb, w)
-    return own + summed
-
-
-# ---- batched stacks on the 2D wire ----------------------------------------
-# Collectives don't vmap under shard_map; instead the batch rides the
-# all-to-all payload (the `syrk_1d_packed_stacked` pattern): the K-stack
-# moves as extra leading payload dims of the SAME exchange, so one
-# collective (pair) covers the whole stack.  The collective-free local
-# compute then vmaps over K.
-def _exchange_rows_stacked(a_own: jax.Array, plan: TwoDPlan, axis: str
-                           ) -> jax.Array:
-    """Stacked :func:`_exchange_rows`: (K, c, nb, w) own shares ->
-    (K, c, nb, n2_pad) assembled rows, one all-to-all for the stack."""
+# Collectives don't vmap under shard_map; instead a stack of K matrices
+# rides the all-to-all payload as extra leading payload dims of the SAME
+# exchange, so one collective (pair) covers the whole stack.  The
+# collective-free local compute then vmaps over K.  An unbatched call
+# is a stack of one.
+def _exchange_rows(a_own: jax.Array, plan: TwoDPlan, axis: str
+                   ) -> jax.Array:
+    """(K, c, nb, w) own shares -> (K, c, nb, n2_pad) fully assembled
+    rows, one all-to-all for the stack."""
     c, nb, w = plan.c, plan.nb, plan.w
     k = jax.lax.axis_index(axis)
     own = jnp.moveaxis(a_own, 0, 1)                           # (c, K, nb, w)
     K = own.shape[1]
+    # send buffer: row p = our share of the row block shared with p
     own_pad = jnp.concatenate(
         [own, jnp.zeros((1, K, nb, w), own.dtype)], 0)
     send = own_pad[jnp.asarray(plan.send_slot)[k]]            # (P, K, nb, w)
     recv = jax.lax.all_to_all(send, axis, 0, 0, tiled=True)
+    # assemble: rows[s] = concat over j of the share from Q_i[j]
     gsrc = jnp.asarray(plan.gather_src)[k]                    # (c, c+1)
     is_self = gsrc == k
     shares = recv[gsrc]                                   # (c, c+1, K, nb, w)
@@ -323,14 +287,15 @@ def _exchange_rows_stacked(a_own: jax.Array, plan: TwoDPlan, axis: str
     return shares.transpose(2, 0, 3, 1, 4).reshape(K, c, nb, (c + 1) * w)
 
 
-def _reverse_exchange_stacked(c_partial: jax.Array, plan: TwoDPlan,
-                              axis: str) -> jax.Array:
-    """Stacked :func:`_reverse_exchange`: (K, c, nb, n2_pad) partial
-    rows -> summed own column shares (K, c, nb, w)."""
+def _reverse_exchange(c_partial: jax.Array, plan: TwoDPlan, axis: str
+                      ) -> jax.Array:
+    """SYMM output reduction (Alg 12 lines 21–33): partial full rows
+    (K, c, nb, n2_pad) -> summed own column shares (K, c, nb, w)."""
     c, nb, w = plan.c, plan.nb, plan.w
     k = jax.lax.axis_index(axis)
     K = c_partial.shape[0]
-    parts = c_partial.reshape(K, c, nb, c + 1, w)
+    parts = c_partial.reshape(K, c, nb, c + 1, w)             # col shares
+    # send: to peer p, our partial of the shared row, p's column share
     slot = jnp.asarray(plan.send_slot)[k]                      # (P,)
     pcol = jnp.asarray(plan.peer_col)[k]                       # (P,)
     valid = jnp.asarray(plan.send_valid)[k]                    # (P,)
@@ -339,6 +304,7 @@ def _reverse_exchange_stacked(c_partial: jax.Array, plan: TwoDPlan,
     send = parts_pad[:, slot, :, pcol]                         # (P, K, nb, w)
     send = send * valid[:, None, None, None]
     recv = jax.lax.all_to_all(send, axis, 0, 0, tiled=True)    # (P, K, nb, w)
+    # sum received pieces into their slots (+ our own column share)
     seg = jnp.where(valid, slot, c)
     summed = jax.ops.segment_sum(recv, seg, num_segments=c + 1)[:c]
     own = jnp.take_along_axis(
@@ -353,7 +319,7 @@ def _reverse_exchange_stacked(c_partial: jax.Array, plan: TwoDPlan,
 def _syrk_blocks(rows_a: jax.Array, rows_b: Optional[jax.Array],
                  plan: TwoDPlan, axis: str) -> Tuple[jax.Array, jax.Array]:
     """Off-diagonal GEMMs + diagonal SYRK for the triangle block (Alg 10
-    lines 15–17 / Alg 11 lines 18–20)."""
+    lines 15–17 / Alg 11 lines 18–20) of one matrix of the stack."""
     k = jax.lax.axis_index(axis)
     pa, pb = plan.pairs[:, 0], plan.pairs[:, 1]
     if rows_b is None:  # SYRK
@@ -371,22 +337,11 @@ def _syrk_blocks(rows_a: jax.Array, rows_b: Optional[jax.Array],
     return off, diag
 
 
-def syrk_2d_local(a_own: jax.Array, plan: TwoDPlan, axis: str):
-    rows = _exchange_rows(a_own, plan, axis)
-    return _syrk_blocks(rows, None, plan, axis)
-
-
-def syr2k_2d_local(a_own: jax.Array, b_own: jax.Array, plan: TwoDPlan,
-                   axis: str):
-    rows_a = _exchange_rows(a_own, plan, axis)
-    rows_b = _exchange_rows(b_own, plan, axis)
-    return _syrk_blocks(rows_a, rows_b, plan, axis)
-
-
 def _symm_partial(a_off: jax.Array, a_diag: jax.Array, rows_b: jax.Array,
                   plan: TwoDPlan, axis: str) -> jax.Array:
-    """Collective-free core of Alg 12: extended triangle block ×
-    assembled B rows (c, nb, n2p) -> partial C rows (c, nb, n2p)."""
+    """Collective-free core of Alg 12 for one matrix of the stack:
+    extended triangle block × assembled B rows (c, nb, n2p) -> partial
+    C rows (c, nb, n2p)."""
     c = plan.c
     k = jax.lax.axis_index(axis)
     pa, pb = plan.pairs[:, 0], plan.pairs[:, 1]
@@ -403,114 +358,69 @@ def _symm_partial(a_off: jax.Array, a_diag: jax.Array, rows_b: jax.Array,
         jnp.where(ds >= 0, dcontrib, jnp.zeros_like(dcontrib)))
 
 
-def symm_2d_local(a_off: jax.Array, a_diag: jax.Array, b_own: jax.Array,
-                  plan: TwoDPlan, axis: str) -> jax.Array:
-    """Alg 12.  a_off: (T, nb, nb) off-diag blocks A_{ij}, i>j ∈ R_k;
-    a_diag: (nb, nb) lower-tri diagonal block (zeros if none);
-    b_own: (c, nb, w) B row shares.  Returns C row shares (c, nb, w)."""
-    rows_b = _exchange_rows(b_own, plan, axis)                # (c, nb, n2p)
-    c_partial = _symm_partial(a_off, a_diag, rows_b, plan, axis)
-    return _reverse_exchange(c_partial, plan, axis)
-
-
-def syrk_2d_local_stacked(a_own: jax.Array, plan: TwoDPlan, axis: str):
-    """(K, c, nb, w) -> (off (K, T, nb, nb), diag (K, nb, nb)): stacked
-    exchange + vmapped (collective-free) block compute."""
-    rows = _exchange_rows_stacked(a_own, plan, axis)
+def syrk_2d_local(a_own: jax.Array, plan: TwoDPlan, axis: str):
+    """Alg 10.  (K, c, nb, w) -> (off (K, T, nb, nb), diag (K, nb, nb)):
+    one exchange for the stack + vmapped (collective-free) block
+    compute."""
+    rows = _exchange_rows(a_own, plan, axis)
     return jax.vmap(lambda r: _syrk_blocks(r, None, plan, axis))(rows)
 
 
-def syr2k_2d_local_stacked(a_own: jax.Array, b_own: jax.Array,
-                           plan: TwoDPlan, axis: str):
-    rows_a = _exchange_rows_stacked(a_own, plan, axis)
-    rows_b = _exchange_rows_stacked(b_own, plan, axis)
+def syr2k_2d_local(a_own: jax.Array, b_own: jax.Array, plan: TwoDPlan,
+                   axis: str):
+    """Alg 11, stacked as :func:`syrk_2d_local`."""
+    rows_a = _exchange_rows(a_own, plan, axis)
+    rows_b = _exchange_rows(b_own, plan, axis)
     return jax.vmap(
         lambda ra, rb: _syrk_blocks(ra, rb, plan, axis))(rows_a, rows_b)
 
 
-def symm_2d_local_stacked(a_off: jax.Array, a_diag: jax.Array,
-                          b_own: jax.Array, plan: TwoDPlan, axis: str
-                          ) -> jax.Array:
-    """Stacked Alg 12: (K, T, nb, nb) + (K, nb, nb) + (K, c, nb, w) ->
-    C row shares (K, c, nb, w); both exchanges cover the whole stack."""
-    rows_b = _exchange_rows_stacked(b_own, plan, axis)
+def symm_2d_local(a_off: jax.Array, a_diag: jax.Array, b_own: jax.Array,
+                  plan: TwoDPlan, axis: str) -> jax.Array:
+    """Alg 12.  a_off (K, T, nb, nb): off-diag blocks A_{ij}, i>j ∈ R_k;
+    a_diag (K, nb, nb): lower-tri diagonal block (zeros if none);
+    b_own (K, c, nb, w): B row shares.  Returns C row shares
+    (K, c, nb, w); both exchanges cover the whole stack."""
+    rows_b = _exchange_rows(b_own, plan, axis)            # (K, c, nb, n2p)
     c_partial = jax.vmap(
         lambda o, d, r: _symm_partial(o, d, r, plan, axis))(
         a_off, a_diag, rows_b)
-    return _reverse_exchange_stacked(c_partial, plan, axis)
+    return _reverse_exchange(c_partial, plan, axis)
 
 
 # --------------------------------------------------------------------------
 # full-array wrappers (mesh axis of size P = c(c+1))
 # --------------------------------------------------------------------------
-def syrk_2d(a_dist: jax.Array, plan: TwoDPlan, mesh, axis: str = "x"):
-    """a_dist: (P, c, nb, w) globally, sharded P(axis).  Returns
-    (off (P,T,nb,nb), diag (P,nb,nb)) sharded over axis."""
-    def body(a):  # per-device (1, c, nb, w)
-        off, diag = syrk_2d_local(a[0], plan, axis)
-        return off[None], diag[None]
+def _device_map(local, n_in: int, mesh, axis: str):
+    """shard_map a per-device body over the mesh axis: every input and
+    output carries the device axis first."""
+    def body(*xs):                     # xs: (1, K, …) per device
+        out = local(*(x[0] for x in xs))
+        return jax.tree.map(lambda y: y[None], out)
 
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=P(axis),
-        out_specs=(P(axis), P(axis))))(a_dist)
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(axis),) * n_in,
+                                 out_specs=P(axis)))
+
+
+def syrk_2d(a_dist: jax.Array, plan: TwoDPlan, mesh, axis: str = "x"):
+    """a_dist: (P, K, c, nb, w) globally, sharded P(axis).  Returns
+    (off (P, K, T, nb, nb), diag (P, K, nb, nb)) sharded over axis."""
+    local = functools.partial(syrk_2d_local, plan=plan, axis=axis)
+    return _device_map(local, 1, mesh, axis)(a_dist)
 
 
 def syr2k_2d(a_dist: jax.Array, b_dist: jax.Array, plan: TwoDPlan, mesh,
              axis: str = "x"):
-    def body(a, b):
-        off, diag = syr2k_2d_local(a[0], b[0], plan, axis)
-        return off[None], diag[None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P(axis), P(axis)),
-        out_specs=(P(axis), P(axis))))(a_dist, b_dist)
+    local = functools.partial(syr2k_2d_local, plan=plan, axis=axis)
+    return _device_map(local, 2, mesh, axis)(a_dist, b_dist)
 
 
 def symm_2d(a_off: jax.Array, a_diag: jax.Array, b_dist: jax.Array,
             plan: TwoDPlan, mesh, axis: str = "x"):
-    def body(ao, ad, b):
-        return symm_2d_local(ao[0], ad[0], b[0], plan, axis)[None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
-        out_specs=P(axis)))(a_off, a_diag, b_dist)
-
-
-def syrk_2d_stacked(a_dist: jax.Array, plan: TwoDPlan, mesh,
-                    axis: str = "x"):
-    """a_dist: (P, K, c, nb, w) sharded P(axis).  Returns
-    (off (P, K, T, nb, nb), diag (P, K, nb, nb)) sharded over axis."""
-    def body(a):
-        off, diag = syrk_2d_local_stacked(a[0], plan, axis)
-        return off[None], diag[None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=P(axis),
-        out_specs=(P(axis), P(axis))))(a_dist)
-
-
-def syr2k_2d_stacked(a_dist: jax.Array, b_dist: jax.Array, plan: TwoDPlan,
-                     mesh, axis: str = "x"):
-    def body(a, b):
-        off, diag = syr2k_2d_local_stacked(a[0], b[0], plan, axis)
-        return off[None], diag[None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P(axis), P(axis)),
-        out_specs=(P(axis), P(axis))))(a_dist, b_dist)
-
-
-def symm_2d_stacked(a_off: jax.Array, a_diag: jax.Array,
-                    b_dist: jax.Array, plan: TwoDPlan, mesh,
-                    axis: str = "x"):
     """a_off (P, K, T, nb, nb), a_diag (P, K, nb, nb),
     b_dist (P, K, c, nb, w) -> C shares (P, K, c, nb, w)."""
-    def body(ao, ad, b):
-        return symm_2d_local_stacked(ao[0], ad[0], b[0], plan, axis)[None]
-
-    return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
-        out_specs=P(axis)))(a_off, a_diag, b_dist)
+    local = functools.partial(symm_2d_local, plan=plan, axis=axis)
+    return _device_map(local, 3, mesh, axis)(a_off, a_diag, b_dist)
 
 
 # --------------------------------------------------------------------------

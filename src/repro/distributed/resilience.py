@@ -315,72 +315,53 @@ def owner_of_rows(rows: np.ndarray, n: int, world: int) -> List[int]:
 _ROUTE_JIT: dict = {}
 
 
-def _route_world(route: str, mesh, axis: str, c, p2) -> int:
-    if route in ("1d", "ring"):
-        return int(mesh.shape[axis])
-    if route in ("2d", "3d", "3d-limited"):
+def _route_world(route: str, mesh, axis: str, c) -> int:
+    """Devices whose row bands make up a route's payload: the c(c+1)
+    triangle-block grid on the grid wires, the mesh axis on the others."""
+    if route == "local":
+        return 1
+    from ..blas import meshpath
+    if meshpath.WIRES[route].sharded:
         return c * (c + 1)
-    return 1                                        # local
+    return int(mesh.shape[axis])
 
 
 def route_runner(op: str, route: str, mesh=None, axis: str = "x",
                  c: Optional[int] = None, p2: Optional[int] = None,
                  chunk: Optional[int] = None) -> Callable:
-    """Jitted packed-output runner for (op, route) — the same meshpath
-    entry points the blas router dispatches to, with ShardedTriTiles
-    exits lowered to the element-packed triangle in-jit.  Cached so
-    repeated checked calls reuse the compiled executable."""
+    """Jitted packed-output runner for (op, route) — the same
+    :data:`~repro.blas.meshpath.WIRES` schedules the blas router
+    dispatches to, on the caller's grid (``c``, ``p2``, ``chunk``), with
+    ShardedTriTiles exits lowered to the element-packed triangle
+    in-jit.  Cached so repeated checked calls reuse the compiled
+    executable."""
     key = (op, route, mesh, axis, c, p2, chunk)
     fn = _ROUTE_JIT.get(key)
     if fn is not None:
         return fn
-    from ..blas import meshpath
     from ..core.packing import pack_tril, unpack_tril
-    if op in ("syrk", "syr2k"):
+    if route == "local":
         mk = {
-            "local": {
-                "syrk": lambda a: pack_tril(a @ a.T),
-                "syr2k": lambda a, b: pack_tril(a @ b.T + b @ a.T)},
-            "1d": {
-                "syrk": lambda a: meshpath.syrk_1d_packed(a, mesh, axis),
-                "syr2k": lambda a, b: meshpath.syr2k_1d_packed(
-                    a, b, mesh, axis)},
-            "ring": {
-                "syrk": lambda a: meshpath.syrk_ring_packed(a, mesh,
-                                                            axis),
-                "syr2k": lambda a, b: meshpath.syr2k_ring_packed(
-                    a, b, mesh, axis)},
-            "2d": {
-                "syrk": lambda a: meshpath.syrk_2d_sharded(
-                    a, c, mesh, axis).to_packed(),
-                "syr2k": lambda a, b: meshpath.syr2k_2d_sharded(
-                    a, b, c, mesh, axis).to_packed()},
-            "3d": {
-                "syrk": lambda a: meshpath.syrk_3d_sharded(
-                    a, c, p2, mesh).to_packed(),
-                "syr2k": lambda a, b: meshpath.syr2k_3d_sharded(
-                    a, b, c, p2, mesh).to_packed()},
-            "3d-limited": {
-                "syrk": lambda a: meshpath.syrk_3d_limited_sharded(
-                    a, c, p2, chunk, mesh).to_packed(),
-                "syr2k": lambda a, b: meshpath.syr2k_3d_limited_sharded(
-                    a, b, c, p2, chunk, mesh).to_packed()},
-        }[route][op]
-    else:                                           # symm
-        mk = {
-            "local": lambda p, b: unpack_tril(
+            "syrk": lambda a: pack_tril(a @ a.T),
+            "syr2k": lambda a, b: pack_tril(a @ b.T + b @ a.T),
+            "symm": lambda p, b: unpack_tril(
                 p.astype(jnp.float32), b.shape[0], symmetric=True) @ b,
-            "1d": lambda p, b: meshpath.symm_1d_packed_a(
-                p, b, b.shape[0], mesh, axis),
-            "ring": lambda p, b: meshpath.symm_ring_packed_a(
-                p, b, b.shape[0], mesh, axis),
-            "2d": lambda p, b: meshpath.symm_2d_packed_a(
-                p, b, c, mesh, axis),
-            "3d": lambda p, b: meshpath.symm_3d_packed_a(
-                p, b, c, p2, mesh),
-            "3d-limited": lambda p, b: meshpath.symm_3d_limited_packed_a(
-                p, b, c, p2, chunk, mesh),
-        }[route]
+        }[op]
+    else:
+        from ..blas import meshpath
+        from ..blas.routing import M_OF, Route
+        from ..core.dispatch import AlgoChoice
+        schedule = getattr(meshpath.WIRES[route], op)
+        P = int(mesh.shape[axis])
+        choice = AlgoChoice(route, 0, P, c=c or 0,
+                            p1=(c or 0) * ((c or 0) + 1), p2=p2 or 1,
+                            b=chunk or 0)
+
+        def mk(*ops):
+            n1, n2 = ops[-1].shape
+            r = Route(op, route, "ABFT-checked call", n1, n2, M_OF[op],
+                      P=P, axis=axis, choice=choice)
+            return meshpath.as_packed(schedule(*ops, mesh, r))
     fn = jax.jit(mk)
     _ROUTE_JIT[key] = fn
     return fn
@@ -502,7 +483,7 @@ def checked_syrk(a: jax.Array, *, route: str = "local", mesh=None,
     run = route_runner("syrk", route, mesh, axis, c, p2, chunk)
     chk = _check_syrk(n1, rtol if rtol is not None
                       else _default_rtol(n1, n2, a.dtype), atol)
-    world = _route_world(route, mesh, axis, c, p2)
+    world = _route_world(route, mesh, axis, c)
     return _checked(
         "syrk", n1, world, lambda: run(a),
         lambda o, s: _corrupt_packed(o, n1, world, "syrk", s),
@@ -523,7 +504,7 @@ def checked_syr2k(a: jax.Array, b: jax.Array, *, route: str = "local",
     run = route_runner("syr2k", route, mesh, axis, c, p2, chunk)
     chk = _check_syr2k(n1, rtol if rtol is not None
                        else _default_rtol(n1, n2, a.dtype), atol)
-    world = _route_world(route, mesh, axis, c, p2)
+    world = _route_world(route, mesh, axis, c)
     return _checked(
         "syr2k", n1, world, lambda: run(a, b),
         lambda o, s: _corrupt_packed(o, n1, world, "syr2k", s),
@@ -545,7 +526,7 @@ def checked_symm(a_packed: jax.Array, b: jax.Array, *,
     run = route_runner("symm", route, mesh, axis, c, p2, chunk)
     chk = _check_symm(n1, rtol if rtol is not None
                       else _default_rtol(n1, n2, b.dtype), atol)
-    world = _route_world(route, mesh, axis, c, p2)
+    world = _route_world(route, mesh, axis, c)
     return _checked(
         "symm", n1, world, lambda: run(a_packed, b),
         lambda o, s: _corrupt_dense_rows(o, world, "symm", s),

@@ -2,12 +2,13 @@
 tests/test_kernels.py and tests/test_slstm_kernel.py):
 
 * syrk / syr2k / symm — the paper's three computations with triangular
-  flat-grid scheduling and packed-triangle tile storage (ops.py
-  wrappers, ref.py jnp oracles);
+  flat-grid scheduling and packed-triangle tile storage (``*_tiles``
+  kernels, reached through :mod:`repro.blas`'s Pallas route; ref.py
+  jnp oracles);
 * slstm — fused recurrence scan (§Perf cell-1 TPU endgame: state in
   registers, one HBM pass over the gates).
 """
-from . import ops, ref
+from . import ref
 from .slstm import slstm_scan
 
-__all__ = ["ops", "ref", "slstm_scan"]
+__all__ = ["ref", "slstm_scan"]

@@ -62,24 +62,26 @@ def check_2d(c: int) -> None:
     B = rng.standard_normal((n1, n2)).astype(np.float32)
     mesh = _mesh((P,), ("x",))
 
-    a_dist = jnp.asarray(distribute_rows(A, plan))
-    assert np.allclose(collect_rows(np.asarray(a_dist), plan), A)
+    # the schedules are batch-native: (P, K, ...) with a stack of K = 1
+    dist = distribute_rows(A, plan)
+    assert np.allclose(collect_rows(dist, plan), A)
+    a_dist = jnp.asarray(dist)[:, None]
     off, diag = syrk_2d(a_dist, plan, mesh)
-    got = assemble_sym(np.asarray(off), np.asarray(diag), plan)
+    got = assemble_sym(np.asarray(off[:, 0]), np.asarray(diag[:, 0]), plan)
     np.testing.assert_allclose(got, np.tril(A @ A.T), rtol=2e-4, atol=2e-4)
 
-    b_dist = jnp.asarray(distribute_rows(B, plan))
+    b_dist = jnp.asarray(distribute_rows(B, plan))[:, None]
     off, diag = syr2k_2d(a_dist, b_dist, plan, mesh)
-    got = assemble_sym(np.asarray(off), np.asarray(diag), plan)
+    got = assemble_sym(np.asarray(off[:, 0]), np.asarray(diag[:, 0]), plan)
     np.testing.assert_allclose(got, np.tril(A @ B.T + B @ A.T), rtol=2e-4,
                                atol=2e-4)
 
     S = rng.standard_normal((n1, n1)).astype(np.float32)
     S = np.tril(S) + np.tril(S, -1).T
     s_off, s_diag = distribute_sym(S, plan)
-    c_dist = symm_2d(jnp.asarray(s_off), jnp.asarray(s_diag), b_dist, plan,
-                     mesh)
-    got = collect_rows(np.asarray(c_dist), plan)
+    c_dist = symm_2d(jnp.asarray(s_off)[:, None], jnp.asarray(s_diag)[:, None],
+                     b_dist, plan, mesh)
+    got = collect_rows(np.asarray(c_dist[:, 0]), plan)
     np.testing.assert_allclose(got, S @ B, rtol=2e-4, atol=2e-4)
     print(f"OK 2d c={c} P={P}")
 
@@ -109,21 +111,22 @@ def check_3d(c: int, p2: int, nsteps: int) -> None:
     mesh = _mesh((p1, p2), ("tb", "rep"))
 
     if nsteps == 1:
+        # the schedules are batch-native: a stack of K = 1
         plan = make_2d_plan(c, n1, n2s)
-        a_dist = distribute_rows_3d_jnp(jnp.asarray(A), plan, p2)
+        a_dist = distribute_rows_3d_jnp(jnp.asarray(A)[None], plan, p2)
         out = syrk_3d(a_dist, plan, mesh)
-        got = np.asarray(_sharded_from_flat(out, plan, n1, c).to_tril())
+        got = np.asarray(_sharded_from_flat(out, plan, n1, c).to_tril()[0])
         np.testing.assert_allclose(got, np.tril(A @ A.T), rtol=2e-4,
                                    atol=2e-4)
-        b_dist = distribute_rows_3d_jnp(jnp.asarray(B), plan, p2)
+        b_dist = distribute_rows_3d_jnp(jnp.asarray(B)[None], plan, p2)
         out = syr2k_3d(a_dist, b_dist, plan, mesh)
-        got = np.asarray(_sharded_from_flat(out, plan, n1, c).to_tril())
+        got = np.asarray(_sharded_from_flat(out, plan, n1, c).to_tril()[0])
         np.testing.assert_allclose(got, np.tril(A @ B.T + B @ A.T),
                                    rtol=2e-4, atol=2e-4)
         # SYMM 3D: triangle blocks in, column slices out
-        st = ShardedTriTiles.from_tril(jnp.tril(jnp.asarray(S)), c)
+        st = ShardedTriTiles.from_tril(jnp.tril(jnp.asarray(S))[None], c)
         c_dist = symm_3d(_flat_from_sharded(st, p2), b_dist, plan, mesh)
-        got = np.asarray(collect_rows_3d_jnp(c_dist, plan, p2))
+        got = np.asarray(collect_rows_3d_jnp(c_dist, plan, p2)[0])
         np.testing.assert_allclose(got, S @ B, rtol=2e-4, atol=2e-4)
         print(f"OK 3d c={c} p2={p2}")
     else:
@@ -589,8 +592,68 @@ def check_mesh_packed() -> None:
                                atol=2e-4)
     print("  3d packed parity + dense-free wire + grads")
 
-    # ---- ShardedTriTiles round-trips against the mesh outputs ------------
+    # ---- each op unbatched and batched through its route's one schedule --
     from repro.blas import meshpath
+    from repro.core.packing import pack_tril
+
+    def packed_of(x):
+        return np.asarray(x.to_packed() if isinstance(x, ShardedTriTiles)
+                          else x)
+
+    for path, mesh_, n1, n2 in (("1d", mesh4, 16, 64), ("2d", mesh6, 34, 6),
+                                ("3d", mesh12, 24, 8)):
+        wire = meshpath.WIRES[path]
+        Ab = jnp.asarray(rng.standard_normal((3, n1, n2)), jnp.float32)
+        Bb = jnp.asarray(rng.standard_normal((3, n1, n2)), jnp.float32)
+        Sb = rng.standard_normal((3, n1, n1)).astype(np.float32)
+        routes = {}
+        for op in ("syrk", "syr2k", "symm"):
+            r = blas.plan_route(op, n1, n2, batch=True, mesh=mesh_)
+            assert r.path == path and r.reason.startswith("batched:"), r
+            routes[op] = r
+        # the stack equals its slices run one at a time (stacks of one)
+        whole = packed_of(wire.syrk(Ab, mesh_, routes["syrk"]))
+        whole2 = packed_of(wire.syr2k(Ab, Bb, mesh_, routes["syr2k"]))
+        Sp = pack_tril(jnp.tril(jnp.asarray(Sb)))
+        whole_s = np.asarray(wire.symm(Sp, Bb, mesh_, routes["symm"]))
+        for i in range(3):
+            a, b = np.asarray(Ab[i]), np.asarray(Bb[i])
+            one = packed_of(wire.syrk(Ab[i], mesh_, routes["syrk"]))
+            np.testing.assert_allclose(whole[i], one, **TOL)
+            np.testing.assert_allclose(one, packed_np(a @ a.T), **TOL)
+            one = packed_of(wire.syr2k(Ab[i], Bb[i], mesh_, routes["syr2k"]))
+            np.testing.assert_allclose(whole2[i], one, **TOL)
+            np.testing.assert_allclose(one, packed_np(a @ b.T + b @ a.T),
+                                       **TOL)
+            one = np.asarray(wire.symm(Sp[i], Bb[i], mesh_, routes["symm"]))
+            np.testing.assert_allclose(whole_s[i], one, **TOL)
+            np.testing.assert_allclose(one, sym_np(Sb[i]) @ b, **TOL)
+        # the blas surface on the stack: parity, dense-free wire, grads
+        got = np.asarray(blas.syrk(Ab, fill="packed", mesh=mesh_))
+        np.testing.assert_allclose(got, whole, **TOL)
+        ttb = TriTiles.from_tril(jnp.tril(jnp.asarray(Sb)), 8)
+        np.testing.assert_allclose(np.asarray(blas.symm(ttb, Bb, mesh=mesh_)),
+                                   whole_s, **TOL)
+        jx = jax.make_jaxpr(lambda x: blas.syrk(x, fill="packed",
+                                                mesh=mesh_))(Ab)
+        assert not _square_vars_on_wire(jx, n1), \
+            f"batched {path} syrk wire densified"
+        jx = jax.make_jaxpr(
+            lambda t, y: blas.symm(TriTiles(t, n1, 8), y, mesh=mesh_))(
+                ttb.tiles, Bb)
+        assert not _square_vars_on_wire(jx, n1), \
+            f"batched {path} symm wire densified"
+        with blas.capture_routes() as log:
+            gm = jax.grad(lambda x: jnp.sum(
+                blas.syrk(x, fill="packed", mesh=mesh_) ** 2))(Ab)
+        assert ("symm", path) in [(r.op, r.path) for r in log], log
+        gd = jax.grad(lambda x: jnp.sum(blas.syrk(x, fill="packed") ** 2))(Ab)
+        np.testing.assert_allclose(np.asarray(gm), np.asarray(gd),
+                                   rtol=2e-3, atol=2e-4)
+    print("  1d/2d/3d: each op unbatched == batched through one schedule; "
+          "batched wire dense-free + grads")
+
+    # ---- ShardedTriTiles round-trips against the mesh outputs ------------
     st = meshpath.syrk_2d_sharded(A2, 2, mesh6, "x")
     assert isinstance(st, ShardedTriTiles) and (st.n, st.c) == (36, 2)
     np.testing.assert_allclose(

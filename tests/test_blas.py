@@ -113,12 +113,13 @@ def test_out_dtype_cast():
 
 
 def test_old_ops_wrappers_preserve_f32():
-    from repro.kernels import ops
+    """The Pallas route keeps the f32 accumulation of bf16 inputs unless
+    asked to cast (the contract the old kernel wrappers held)."""
     a = _rand((32, 16), 9, jnp.bfloat16)
-    out = ops.syrk(a, bm=16, bk=16)
+    out = blas.syrk(a, tile=(16, 16), interpret=True)
     assert out.dtype == jnp.float32
-    assert ops.syrk(a, bm=16, bk=16,
-                    out_dtype=jnp.bfloat16).dtype == jnp.bfloat16
+    assert blas.syrk(a, tile=(16, 16), interpret=True,
+                     out_dtype=jnp.bfloat16).dtype == jnp.bfloat16
 
 
 # ---------------------------------------------------------------------------

@@ -11,14 +11,15 @@ pytest.importorskip("hypothesis",
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.kernels import ops
+from repro import blas
 from repro.kernels.ref import symm_ref, syr2k_ref, syrk_ref
 
 jax.config.update("jax_enable_x64", False)
 
 SHAPES = [(16, 16), (32, 16), (16, 48), (64, 32), (48, 80)]
 DTYPES = [jnp.float32, jnp.bfloat16]
-BLK = dict(bm=16, bk=16)
+#: the Pallas route of repro.blas at 16x16 tiles, kernel bodies interpreted
+BLK = dict(tile=(16, 16), interpret=True)
 
 
 def _rand(shape, seed, dtype):
@@ -35,7 +36,7 @@ def _tol(dtype):
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_syrk_kernel(shape, dtype):
     a = _rand(shape, 0, dtype)
-    got = ops.syrk(a, **BLK)
+    got = blas.syrk(a, **BLK)
     want = syrk_ref(a)
     np.testing.assert_allclose(np.asarray(got, np.float32), want, **_tol(dtype))
     # strict upper triangle zero (packed-output contract)
@@ -46,7 +47,7 @@ def test_syrk_kernel(shape, dtype):
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_syr2k_kernel(shape, dtype):
     a, b = _rand(shape, 1, dtype), _rand(shape, 2, dtype)
-    got = ops.syr2k(a, b, **BLK)
+    got = blas.syr2k(a, b, **BLK)
     want = syr2k_ref(a, b)
     np.testing.assert_allclose(np.asarray(got, np.float32), want, **_tol(dtype))
 
@@ -56,20 +57,20 @@ def test_syr2k_kernel(shape, dtype):
 def test_symm_kernel(n1, n2, dtype):
     a = _rand((n1, n1), 3, dtype)
     b = _rand((n1, n2), 4, dtype)
-    got = ops.symm(a, b, bm=16, bn=16)
+    got = blas.symm(a, b, **BLK)
     want = symm_ref(a, b)
     np.testing.assert_allclose(np.asarray(got, np.float32), want, **_tol(dtype))
 
 
 def test_unaligned_shapes_padded():
-    # wrapper pads to tile multiples and slices back
+    # the route pads to tile multiples and slices back
     a = _rand((20, 24), 5, jnp.float32)
-    got = ops.syrk(a, **BLK)
+    got = blas.syrk(a, **BLK)
     np.testing.assert_allclose(np.asarray(got), syrk_ref(a), rtol=2e-5,
                                atol=2e-5)
     s = _rand((20, 20), 6, jnp.float32)
     b = _rand((20, 8), 7, jnp.float32)
-    np.testing.assert_allclose(np.asarray(ops.symm(s, b, bm=16, bn=16)),
+    np.testing.assert_allclose(np.asarray(blas.symm(s, b, **BLK)),
                                symm_ref(s, b), rtol=2e-5, atol=2e-5)
 
 
@@ -77,7 +78,7 @@ def test_unaligned_shapes_padded():
 @given(nt=st.integers(1, 4), nk=st.integers(1, 4), seed=st.integers(0, 99))
 def test_syrk_property(nt, nk, seed):
     a = _rand((nt * 16, nk * 16), seed, jnp.float32)
-    got = ops.syrk(a, **BLK)
+    got = blas.syrk(a, **BLK)
     np.testing.assert_allclose(np.asarray(got), syrk_ref(a), rtol=3e-5,
                                atol=3e-5)
 
@@ -86,7 +87,7 @@ def test_block_size_sweep():
     a = _rand((64, 64), 8, jnp.float32)
     want = syrk_ref(a)
     for bm, bk in [(8, 8), (16, 32), (32, 16), (64, 64)]:
-        got = ops.syrk(a, bm=bm, bk=bk)
+        got = blas.syrk(a, tile=(bm, bk), interpret=True)
         np.testing.assert_allclose(np.asarray(got), want, rtol=3e-5,
                                    atol=3e-5)
 
@@ -97,6 +98,6 @@ def test_symm_reads_only_tril():
     a = np.asarray(_rand((n1, n1), 9, jnp.float32)).copy()
     b = _rand((n1, 16), 10, jnp.float32)
     a_poison = a + np.triu(np.full((n1, n1), 1e6, np.float32), 1)
-    got = ops.symm(jnp.asarray(a_poison), b, bm=16, bn=16)
+    got = blas.symm(jnp.asarray(a_poison), b, **BLK)
     np.testing.assert_allclose(np.asarray(got), symm_ref(jnp.asarray(a), b),
                                rtol=2e-5, atol=2e-5)

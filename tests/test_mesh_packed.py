@@ -143,6 +143,38 @@ def test_tritiles_densify_warns_once_naming_route():
 
 
 # ---------------------------------------------------------------------------
+# the route table: one entry per mesh path the planner can emit
+# ---------------------------------------------------------------------------
+class _AxisMesh:
+    """Stands in for a one-axis Mesh in routing decisions (plan_route
+    reads only ``.shape``)."""
+
+    def __init__(self, P):
+        self.shape = {"x": P}
+
+
+def test_route_table_has_exactly_the_planned_mesh_paths():
+    from repro.blas import meshpath
+    emitted = set()
+    # 1d, ring, 2d, 3d, and (a small budget M) 3d-limited, batched or not
+    for op in ("syrk", "syr2k", "symm"):
+        for P, n1, n2, M in ((4, 16, 64, None), (8, 256, 256, None),
+                             (6, 36, 6, None), (12, 24, 8, None),
+                             (12, 24, 32, 60), (3, 96, 96, None),
+                             (8, 64, 4096, None), (5, 24, 7, None)):
+            for batch in (False, True):
+                r = blas.plan_route(op, n1, n2, batch=batch,
+                                    mesh=_AxisMesh(P), M=M)
+                emitted.add(r.path)
+    mesh_paths = emitted - {"dense", "pallas"}
+    assert mesh_paths == {"1d", "ring", "2d", "3d", "3d-limited"}, emitted
+    assert set(meshpath.WIRES) == mesh_paths
+    # the grid families, and only they, emit the mesh-resident layout
+    assert {p for p, w in meshpath.WIRES.items() if w.sharded} \
+        == {"2d", "3d", "3d-limited"}
+
+
+# ---------------------------------------------------------------------------
 # bf16 packed Gram state (single-device side of the satellite)
 # ---------------------------------------------------------------------------
 def test_packed_gram_out_dtype_bf16():
