@@ -119,9 +119,11 @@ def _packed_to_fill(packed: jax.Array, n1: int, fill: str) -> jax.Array:
 def _tiles_to_fill(tiles: jax.Array, n1: int, bm: int, fill: str
                    ) -> jax.Array:
     """Kernel-emitted packed tiles (T, bm, bm), diagonal already masked
-    in-epilogue, to the requested fill.  "packed" is a cached-index
-    gather — no n×n dense intermediate; "tril"/"full" scatter straight
-    into the output buffer (no re-tril / re-pack fixups)."""
+    in-epilogue, to the requested fill.  "packed" is a slice-granular
+    scatter-add — no n×n dense intermediate; "tril"/"full" assemble the
+    output from whole tiles (:func:`unpack_tril_tiles`: static slices
+    of the tile axis on the grids the kernels' tiles give, no re-tril /
+    re-pack fixups)."""
     if fill == "packed":
         return tiles_to_packed(tiles, n1)
     npad = -(-n1 // bm) * bm

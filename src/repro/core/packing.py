@@ -10,11 +10,17 @@ Converter discipline (the PR-5 rewrite): no converter performs an
 element-granular gather or scatter.  Row-major packed offsets are
 quadratic in the row index, so no pure reshape exists between the packed
 vector and any 2-D layout — but every matrix row (and every intra-tile
-row of every tile) *is* one contiguous slice of the packed vector.  All
-converters therefore move data as a single `lax.gather`/`lax.scatter_add`
-whose index count is the number of rows touched — O(n) for dense↔packed,
-O(T·bm) = O(n²/bm) for tiles↔packed, T for tile↔dense takes — with the
-per-element work reduced to one vectorized intra-tile mask.  Ballard et
+row of every tile) *is* one contiguous slice of the packed vector.  The
+element-packed converters therefore move data as a single
+`lax.gather`/`lax.scatter_add` whose index count is the number of rows
+touched — O(n) for dense↔packed, O(T·bm) = O(n²/bm) for tiles↔packed —
+with the per-element work reduced to one vectorized intra-tile mask.
+The tile↔dense converters move whole tiles by static slices: the TPU
+compiler lowers a gather or scatter of whole 1024×1024 tiles to a
+serial loop per op, and a static slice to a plain fusion.  Only a grid
+of small tiles too large to unroll (:func:`_unrolls`) keeps one gather
+/ scatter over the tile axis, which stays one op (one short loop over
+a stack) there.  Ballard et
 al.'s point that layout conversion must not re-move the data is what
 this buys: the old per-element tables made the packed *backward* path
 ~30× slower than tril at n=1024 (XLA serializes element-row scatters);
@@ -183,13 +189,40 @@ def tile_flat_index(i: int, j: int) -> int:
     return i * (i + 1) // 2 + j
 
 
+#: the largest tile count T = nt(nt+1)/2 (nt ≤ 16) that the tile↔dense
+#: converters always unroll into static whole-tile slices
+STATIC_TILE_MAX = 136
+
+#: the largest tile side the converters may move by one gather / scatter
+#: over the tile axis, on a grid past :data:`STATIC_TILE_MAX`.  Compiled
+#: for a TPU v5e, a gather or scatter of whole 1024-row tiles lowers to a
+#: serial loop per op (10 ``while`` around one 24-stacked SYRK), while
+#: at 128-row tiles it stays one op, or one loop over a stack.  Unrolling
+#: T = 2016 such tiles (n = 8064) made one SYRK compile in 93 s against
+#: 0.6 s gathered.
+GATHER_TILE_MAX = 128
+
+
+def _unrolls(nt: int, tile: int) -> bool:
+    """Whether the tile↔dense converters move an nt×nt grid of
+    (tile, tile) tiles by static slices rather than one gather."""
+    return tile > GATHER_TILE_MAX or tile_tril_count(nt) <= STATIC_TILE_MAX
+
+
 def pack_tril_tiles(x, tile: int):
     """(…, n, n) -> (…, T, tile, tile): dense tiles of the lower triangle of
     the tile grid (diagonal tiles kept dense — the intra-tile upper halves of
-    diagonal tiles are the only redundancy, a 1/nt fraction)."""
+    diagonal tiles are the only redundancy, a 1/nt fraction).
+
+    The stack is built from static whole-tile slices, except on a large
+    grid of small tiles (:func:`_unrolls`): one gather over the grid."""
     n = x.shape[-1]
     assert n % tile == 0
     nt = n // tile
+    if _unrolls(nt, tile):
+        return jnp.stack([x[..., i * tile:(i + 1) * tile,
+                            j * tile:(j + 1) * tile]
+                          for i, j in tile_tril_coords(nt)], axis=-3)
     coords = tile_tril_coords(nt)
     xt = x.reshape(x.shape[:-2] + (nt, tile, nt, tile))
     xt = jnp.moveaxis(xt, -2, -3)  # (…, nt, nt, tile, tile)
@@ -304,9 +337,35 @@ def tiles_to_packed(tiles, n: int):
     return _over_batch(one, tiles, 3)
 
 
+def _sym_diag_tile(d):
+    """A diagonal tile's lower half mirrored into its upper half."""
+    return jnp.tril(d) + jnp.swapaxes(jnp.tril(d, -1), -1, -2)
+
+
 def unpack_tril_tiles(p, n: int, tile: int, symmetric: bool = True):
-    """(…, T, tile, tile) -> full (…, n, n) symmetric matrix."""
+    """(…, T, tile, tile) -> dense (…, n, n): the stored tiles in the
+    lower triangle, and above it the mirror (``symmetric``: diagonal
+    tiles symmetrized from their lower halves) or zeros (diagonal tiles
+    as stored).
+
+    Each tile row is a concatenation of static slices of the tile axis
+    (an upper tile the transpose of its mirror), except on a large grid
+    of small tiles (:func:`_unrolls`): one scatter into a zero grid."""
     nt = n // tile
+    if _unrolls(nt, tile):
+        def at(i, j):
+            return p[..., tile_flat_index(i, j), :, :]
+
+        if not symmetric:
+            zero = jnp.zeros(p.shape[:-3] + (tile, tile), p.dtype)
+        rows = []
+        for i in range(nt):
+            diag = _sym_diag_tile(at(i, i)) if symmetric else at(i, i)
+            upper = [jnp.swapaxes(at(j, i), -1, -2) if symmetric else zero
+                     for j in range(i + 1, nt)]
+            rows.append(jnp.concatenate(
+                [at(i, j) for j in range(i)] + [diag] + upper, axis=-1))
+        return jnp.concatenate(rows, axis=-2)
     coords = tile_tril_coords(nt)
     full = jnp.zeros(p.shape[:-3] + (nt, nt, tile, tile), dtype=p.dtype)
     full = full.at[..., coords[:, 0], coords[:, 1], :, :].set(p)
@@ -316,11 +375,8 @@ def unpack_tril_tiles(p, n: int, tile: int, symmetric: bool = True):
         ii = jnp.arange(nt)
         lower_mask = (ii[:, None] >= ii[None, :])[..., None, None]
         full = jnp.where(lower_mask, full, mirrored)
-        # diagonal tiles: symmetrize within the tile
-        diag_tiles = full[..., ii, ii, :, :]
-        tl = jnp.tril(diag_tiles)
-        sym_diag = tl + jnp.swapaxes(jnp.tril(diag_tiles, -1), -1, -2)
-        full = full.at[..., ii, ii, :, :].set(sym_diag)
+        full = full.at[..., ii, ii, :, :].set(
+            _sym_diag_tile(full[..., ii, ii, :, :]))
     out = jnp.moveaxis(full, -3, -2)
     return out.reshape(p.shape[:-3] + (n, n))
 
@@ -454,16 +510,13 @@ class TriTiles:
         if pad:
             cfg = [(0, 0)] * len(lead) + [(0, pad), (0, pad)]
             xp = jnp.pad(x, cfg)
-        tiles = pack_tril_tiles(xp, bm)
-        ii = jnp.arange(-(-n // bm))
-        rows = jnp.arange(bm)
-        tril_mask = rows[:, None] >= rows[None, :]
-        diag_slots = ii * (ii + 3) // 2
+        coords = tile_tril_coords(-(-n // bm))
+        is_diag = coords[:, 0] == coords[:, 1]
         # where, not multiply: the unread upper halves may hold NaN/inf
         # garbage ("tril-valid" contract) and 0·NaN would propagate it
-        diag = tiles[..., diag_slots, :, :]
-        tiles = tiles.at[..., diag_slots, :, :].set(
-            jnp.where(tril_mask, diag, jnp.zeros_like(diag)))
+        tiles = pack_tril_tiles(xp, bm)
+        keep = _tile_keep_mask(tiles.shape[-3], bm, is_diag)
+        tiles = jnp.where(keep, tiles, jnp.zeros((), tiles.dtype))
         return cls(tiles, n, bm)
 
     @classmethod

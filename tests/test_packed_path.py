@@ -416,20 +416,71 @@ def test_pack_unpack_match_element_reference(n):
         full + full.T - np.diag(np.diag(full)))
 
 
-@pytest.mark.parametrize("n", [33, 40, 48])
-def test_tile_converters_match_element_reference(n):
-    """packed<->tiles must agree bit-for-bit with the (kept, reference)
-    per-element tables on ragged n, including zeroed padding slots."""
-    bm = 16
-    p = np.asarray(_rand((tril_size(n),), 31))
-    tidx, ridx, cidx = packed_tile_indices(n, bm)
+def _tile_converter_cases():
+    """(n, bm, batch, fill): bm = 16 unrolls into static slices, bm = 2
+    (T > STATIC_TILE_MAX) takes the gather; ragged n against both."""
+    seed = [(n, 16, (), "full") for n in (33, 40, 48)]
+    for n in (33, 40, 48):
+        yield pytest.param(n, 16, (), "full", id=str(n))
+    grids = [(33, 16, "static-ragged33"), (40, 16, "static-ragged40"),
+             (48, 16, "static"), (41, 16, "static-ragged41"),
+             (48, 2, "gather"), (41, 2, "gather-ragged")]
+    batches = [((), "unbatched"), ((3,), "batch1"), ((2, 3), "batch2")]
+    for n, bm, grid in grids:
+        for fill in ("tril", "full"):
+            for batch, bid in batches:
+                if (n, bm, batch, fill) not in seed:
+                    yield pytest.param(n, bm, batch, fill,
+                                       id=f"{grid}-{fill}-{bid}")
+
+
+@pytest.mark.parametrize("n,bm,batch,fill", list(_tile_converter_cases()))
+def test_tile_converters_match_element_reference(n, bm, batch, fill):
+    """packed<->tiles and tiles<->dense (the Pallas route's fill glue and
+    the TriTiles dense entry and exits) must agree bit-for-bit with the
+    (kept, reference) per-element tables on ragged n, including zeroed
+    padding slots, on both sides of the static-slice / gather choice and
+    under leading batch dims; ``from_tril`` drops a NaN upper half."""
+    from repro.blas.api import _fill_to_tiles, _tiles_to_fill
+    from repro.core.packing import _unrolls, pack_tril_tiles, pad2d
     nt = -(-n // bm)
-    ref = np.zeros((nt * (nt + 1) // 2, bm, bm), np.float32)
-    ref[tidx, ridx, cidx] = p
+    assert _unrolls(nt, bm) == (bm == 16), (nt, bm)
+    p = np.asarray(_rand(batch + (tril_size(n),), 31))
+    tidx, ridx, cidx = packed_tile_indices(n, bm)
+    ref = np.zeros(batch + (nt * (nt + 1) // 2, bm, bm), np.float32)
+    ref[..., tidx, ridx, cidx] = p
     got = np.asarray(packed_to_tiles(jnp.asarray(p), n, bm))
     np.testing.assert_array_equal(got, ref)
     np.testing.assert_array_equal(
         np.asarray(tiles_to_packed(jnp.asarray(ref), n)), p)
+
+    i, j = np.tril_indices(n)
+    tril = np.zeros(batch + (n, n), np.float32)
+    tril[..., i, j] = p
+    full = tril.copy()
+    full[..., j, i] = p
+    want = full if fill == "full" else tril
+    tiles = jnp.asarray(ref)
+    np.testing.assert_array_equal(
+        np.asarray(_tiles_to_fill(tiles, n, bm, fill)), want)
+    tt = TriTiles(tiles, n, bm)
+    np.testing.assert_array_equal(
+        np.asarray(tt.to_full() if fill == "full" else tt.to_tril()), want)
+    # the route applies the fill glue per matrix, vmapped over the stack
+    flat = jnp.asarray(tril).reshape((-1, n, n))
+    np.testing.assert_array_equal(np.asarray(jax.vmap(
+        lambda x: _fill_to_tiles(x, n, bm, "tril"))(flat)).reshape(ref.shape),
+        ref)
+    padded = jax.vmap(lambda x: pad2d(x, bm, bm))(flat)
+    np.testing.assert_array_equal(
+        np.asarray(pack_tril_tiles(padded, bm)).reshape(ref.shape), ref)
+    poisoned = tril + np.triu(np.full((n, n), np.nan, np.float32), 1) \
+        if fill == "tril" else full
+    back = TriTiles.from_tril(jnp.asarray(poisoned), bm)
+    np.testing.assert_array_equal(np.asarray(back.tiles), ref)
+    np.testing.assert_array_equal(
+        np.asarray(back.to_full() if fill == "full" else back.to_tril()),
+        want)
 
 
 def test_converters_batched_match_element_reference():

@@ -208,3 +208,64 @@ def test_ring_dense_converters_have_no_loops_on_v5e_2x2(topo, one_chip, op):
     loops = [n for n, (opcode, name) in hlo.ops.items()
              if opcode in ("while", "scatter") and scope in name]
     assert not loops, (len(loops), loops[:10])
+
+
+#: ``stablelm-1.6b.muon``'s Newton–Schulz operands (the 24 stacked
+#: 2048x5632 MLP weights and the 2048x100352 embedding), then one grid
+#: on each side of the converters' static-slice / gather choice: 17
+#: 1024-row tiles a side (T = 153, past ``STATIC_TILE_MAX``) still
+#: unroll, 63 128-row tiles a side (T = 2016) take one gather
+PALLAS_NS_CASES = [
+    pytest.param((24,), 2048, 5632, id="mlp-24x2048x5632"),
+    pytest.param((), 2048, 100352, id="embed-2048x100352"),
+    pytest.param((), 17408, 1024, id="static-17408x1024"),
+    pytest.param((), 8064, 1024, id="gather-8064x1024"),
+]
+
+
+@pytest.mark.parametrize("lead,n1,n2", PALLAS_NS_CASES)
+@pytest.mark.parametrize("op", ["syrk", "symm"])
+def test_pallas_dense_converters_have_no_loops_on_v5e(one_chip, op, lead,
+                                                      n1, n2):
+    """The NS chain's dense Pallas calls — ``syrk(fill="full")`` for the
+    Gram and dense-A ``symm`` for S·S — compiled for one described v5e
+    chip at the heuristic tiles move between the kernels' packed tiles
+    and dense S in static whole-tile slices: no ``while``, ``scatter``
+    or ``gather`` op lies under their scope.  (Whole-tile gathers and
+    scatters over the tile grid lower to serial loops at 1024-row
+    tiles: 10 ``while`` under ``blas.syrk.pallas`` and 8 under
+    ``blas.symm.pallas`` on the MLP stack; 4 gathers and 2 scatters,
+    and 4 gathers, on the embedding.)  Only a grid of 128-row tiles
+    too large to unroll keeps its gather, which stays loop-free."""
+    import pathlib
+    import sys
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    if str(repo) not in sys.path:
+        sys.path.insert(0, str(repo))
+    from bench import scopes
+    from repro.core.packing import _unrolls
+    tile = blas.heuristic_tiles(op, n1, n2)
+    unrolled = _unrolls(-(-n1 // tile[0]), tile[0])
+    assert unrolled == (n1 != 8064), tile
+    kernel = dict(tile=tile, interpret=False)
+    if op == "syrk":
+        fn = lambda a: blas.syrk(a, fill="full", **kernel)   # noqa: E731
+        shapes = [lead + (n1, n2)]
+    else:
+        fn = lambda s, b: blas.symm(s, b, **kernel)           # noqa: E731
+        shapes = [lead + (n1, n1), lead + (n1, n2)]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    with blas.capture_routes() as log:
+        lowered = jax.jit(fn).lower(*args)
+    assert [(r.path, r.tiles) for r in log] == [("pallas", tile)], log
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    hlo = scopes.hlo_ops(text)
+    scope = f"blas.{op}.pallas"
+    moves = [opcode for opcode, name in hlo.ops.values()
+             if opcode in ("while", "scatter", "gather") and scope in name]
+    if unrolled:
+        assert not moves, moves
+    else:
+        assert "gather" in moves and "while" not in moves, moves
