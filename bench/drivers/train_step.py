@@ -50,10 +50,33 @@ class CompileCounter:
 
 
 def _program_config(cell):
+    """The program's ``ArchConfig``: the configuration's ``program.arch``
+    with its ``program.overrides`` applied.  A dict overrides fields of
+    the dataclass that a field holds (``{"moe": {"n_experts": 8}}``); a
+    dict for a field that holds none, or a field the config does not
+    have, is an error that names it."""
     from repro.configs import get_config
     prog = cell.config["program"]
-    return dataclasses.replace(get_config(prog["arch"]),
-                               **prog.get("overrides", {}))
+    return _override(get_config(prog["arch"]), prog.get("overrides", {}))
+
+
+def _override(obj, overrides: Dict[str, Any], where: str = ""):
+    fields = {f.name for f in dataclasses.fields(obj)}
+    new = {}
+    for key, value in overrides.items():
+        name = where + key
+        if key not in fields:
+            raise ValueError(f"program override {name!r}: "
+                             f"{type(obj).__name__} has no such field")
+        if isinstance(value, dict):
+            held = getattr(obj, key)
+            if not dataclasses.is_dataclass(held):
+                raise ValueError(f"program override {name!r}: a dict for "
+                                 f"a field that holds {held!r}, not a "
+                                 "dataclass")
+            value = _override(held, value, name + ".")
+        new[key] = value
+    return dataclasses.replace(obj, **new)
 
 
 def _check_optimizer(opt, job) -> None:
@@ -229,6 +252,17 @@ def ns_calls(cell, layout) -> List[work.Call]:
                          job["ns_min_side"])
 
 
+def step_flop(cell, layout) -> Dict[str, float]:
+    """The flop one step requires: the model's, as the configuration's
+    reference counts it from the published shapes, and the NS chains
+    of ``layout``'s leaves (``bench/work.py``)."""
+    job = cell.traffic
+    model = cell.reference().model_flop(cell.config, job["global_batch"],
+                                        job["seq_len"])
+    return work.step_flop(model, [s for _, s, _, _ in layout],
+                          job["ns_steps"], job["ns_min_side"])
+
+
 def run(cell, args, t0: float, profile_dir: pathlib.Path) -> Dict[str, Any]:
     """One run of the cell; ``t0`` is the process start on the host
     clock.  Returns what ``bench/run.py`` prints."""
@@ -287,9 +321,7 @@ def run(cell, args, t0: float, profile_dir: pathlib.Path) -> Dict[str, Any]:
     calls = ns_calls(cell, layout)
     pallas = {(op, n1, n2) for op, n1, n2, path, _ in routes
               if path == "pallas"}
-    flop = work.step_flop(cell.config, [s for _, s, _, _ in layout],
-                          job["global_batch"], job["seq_len"],
-                          job["ns_steps"], job["ns_min_side"])
+    flop = step_flop(cell, layout)
     return {
         "attempted": done, "failed": failed,
         "correct": failed == 0 and all(v <= lim for _, v, lim in checks),

@@ -36,6 +36,12 @@ Variants, each the reference put in the program's place:
   ``no_exchange``  each matrix's NS chain on column shards of
                    ``exchange_shards`` with no sum between them, as a
                    mesh step that left out its collectives would.
+
+Required work: ``model_flop(cfg, batch, seq)`` is the flop one step of
+this model needs, from the published shapes (``bench/work.py``'s dense
+count: every matmul parameter active for every token, attention at
+``head_dim``); the harness's ``step_mfu`` divides it, with the NS
+chains, by the chip's peak.
 """
 from __future__ import annotations
 
@@ -50,6 +56,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from bench import weights
+from bench.work import model_flop  # noqa: F401  (the required work)
 
 HIGHEST = jax.lax.Precision.HIGHEST
 VARIANTS = ("reference", "control", "ns_control", "half_batch",

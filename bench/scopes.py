@@ -16,6 +16,10 @@ puts its self time in exactly one bucket:
   other       under none of these;
   unattributed  no metadata, or a name the HLO does not have.
 
+Each op's self time is also kept under its :func:`label`, the short
+scope path of its ``op_name``, so every attributed op falls in exactly
+one label and the labels add up to the attributed time; a per-layer
+metric reads the labels that match a pattern with :func:`scope_s`.
 Under ``blas.<op>.pallas`` the ``tpu_custom_call`` time of each op is
 kept apart from the glue around it (pad, tile <-> fill).  An
 instruction XLA made without metadata takes the scope of its fused
@@ -23,6 +27,24 @@ computation, its operands, its users or its caller (:func:`hlo_ops`).
 When an op's opcode differs from the HLO's, more than
 ``MAX_UNATTRIBUTED`` of the time is unattributed, or no op carries a
 scope (a program without them), the join gives nothing, and says why.
+
+The scope names a program may use (``jax.named_scope``), each one
+name in the ``op_name`` path.  Where an inner name extends an outer one
+by ``.<name>`` (``model.moe.route`` inside ``model.moe``), the label
+keeps the inner one alone:
+
+  train.loss, train.clip          the step's loss and gradient, its clip;
+  model.<name>[.<name>...]        the model's layers, nested as deep as
+                                  the program likes;
+  optim.<name>                    an optimizer (``optim.muon``,
+                                  ``optim.adamw``) or its wire
+                                  (``optim.muon_1d``);
+  optim.muon.ns.<leaf>            one matrix leaf's NS chain;
+  ns.<name>                       an NS product;
+  blas.<op>.<path>                a symmetric-BLAS route.
+
+Any other name is not a scope here: its time falls under the scopes
+around it.
 
 Host spans (``bench.*`` and the program's ``repro.*``) are on the host's
 clock.  ``clock_offset`` bounds the offset from each step's device
@@ -61,7 +83,7 @@ MODULES_LINE = "XLA Modules"
 DONE_EVENT = "CompleteCallbacks"
 LAUNCH_EVENT = "PJRT_LoadedExecutable_Execute"
 
-_SCOPE = re.compile(r"(?<![\w.])(train\.loss|train\.clip|model\.\w+|"
+_SCOPE = re.compile(r"(?<![\w.])(train\.loss|train\.clip|model(?:\.\w+)+|"
                     r"optim\.muon\.ns\.[^/()]+|optim\.\w+|ns\.\w+|"
                     r"blas\.\w+\.[\w-]+)(?=[/)]|$)")
 _LEAF = re.compile(r"optim\.muon\.ns\.([^/()]+)")
@@ -308,6 +330,7 @@ class Join:
     products: Dict[str, float]
     kernel: Dict[str, float]      # blas op -> tpu_custom_call time
     glue: Dict[str, float]        # blas op -> other time under .pallas
+    labels: Dict[str, float]      # scope label -> self time
     ops: Dict[str, str]           # short op name -> scope label
     mismatches: List[Tuple[str, str, str]]
     busy_s: float
@@ -340,7 +363,7 @@ def attribute(tr: xplane.Trace, hlo: Dict[str, Tuple[str, str]],
     ``op_name``."""
     lo, hi = window
     acc = {k: {} for k in ("buckets", "leaves", "products", "kernel",
-                           "glue")}
+                           "glue", "labels")}
     acc["buckets"] = dict.fromkeys(BUCKETS, 0.0)
     names: Dict[str, str] = {}
     mismatches = []
@@ -370,6 +393,7 @@ def attribute(tr: xplane.Trace, hlo: Dict[str, Tuple[str, str]],
             b = bucket(op_name)
             add("buckets", b, t)
             names[o.name] = label(op_name)
+            add("labels", names[o.name], t)
             if b == "optimizer":
                 leaf = _LEAF.search(op_name)
                 add("leaves", leaf.group(1) if leaf else "rest", t)
@@ -453,6 +477,8 @@ def _compute(ctx) -> Optional[Dict]:
                                          for k, v in join.kernel.items()},
             "pallas_glue_per_step_s": {k: v / n
                                        for k, v in join.glue.items()},
+            "labels_per_step_s": {k: v / n for k, v in sorted(
+                join.labels.items(), key=lambda kv: -kv[1])},
             "host_spans_s": _span_totals(tl, windows[0])}
     if why:
         line = {"none": why, **line}
@@ -489,6 +515,18 @@ def read(ctx) -> Optional[Dict]:
                   flush=True)
             ctx["scopes"] = None
     return ctx["scopes"]
+
+
+def scope_s(ctx, pattern: str) -> Optional[float]:
+    """Device self time per traced step, mean over chips, of the ops
+    whose :func:`label` matches the regex ``pattern`` (``re.search``);
+    None when the join refuses or no label matches."""
+    got = read(ctx)
+    if not got:
+        return None
+    rx = re.compile(pattern)
+    hit = [t for name, t in got["join"].labels.items() if rx.search(name)]
+    return sum(hit) / got["steps"] if hit else None
 
 
 def pallas_roofline(ctx, op: str) -> Optional[float]:

@@ -3,12 +3,21 @@
 Everything that belongs to one configuration, traffic mix or metric
 lives in a file of its own, found here by name:
 
-  configs/<config>.json        sizes as run, source, plain reference
+  configs/<config>.json        sizes as run, source, plain reference,
+                               the program's ``ArchConfig`` overrides
+                               (``program``; nested for a field that
+                               holds a dataclass) and the tiny copy the
+                               CPU tests run (``tiny``: ``config`` and
+                               ``overrides`` at test sizes)
   traffic/<traffic>.json       the job; its ``kind`` picks drivers/<kind>.py
   limits/<workload>.json       the limit of each number that decides
                                ``correct``
-  metrics/<metric>.py          one reader per per-layer metric
-  references/<reference>.py    the plain reference a configuration names
+  metrics/<metric>.py          one reader per per-layer metric; a time
+                               under the program's scopes is a few lines
+                               on ``scopes.scope_s``
+  references/<reference>.py    the plain reference a configuration names:
+                               ``layout``, ``follow`` and ``model_flop``,
+                               the model's required work
 
 So a later cell, traffic mix or metric is added as files and entries,
 without editing any file that is already here.

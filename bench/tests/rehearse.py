@@ -1,7 +1,8 @@
 """Helpers that drive ``bench/run.py`` on the CPU at a tiny size.
 
 ``make_root`` copies the benchmark into a temporary checkout (the
-program's ``src`` linked in) and adds tiny cells beside the real ones;
+program's ``src`` linked in) and adds tiny cells beside the real ones,
+each at the size its configuration file's ``tiny`` object gives;
 ``run_cell`` runs one cell there in a child process with the harness's
 look for a chip replaced, optionally with a fault planted in the
 program.  Tests only: the benchmark itself never runs without a TPU.
@@ -16,77 +17,70 @@ import subprocess
 import sys
 from typing import Dict, Optional
 
+from bench import spec
+
 REPO = pathlib.Path(__file__).resolve().parents[2]
 
-TINY = {
-    "stablelm-1.6b": {"hidden_size": 64, "num_hidden_layers": 2,
-                      "num_attention_heads": 4, "num_key_value_heads": 4,
-                      "head_dim": 16, "intermediate_size": 160,
-                      "vocab_size": 512},
-    "pixtral-12b": {"hidden_size": 64, "num_hidden_layers": 2,
-                    "num_attention_heads": 4, "num_key_value_heads": 2,
-                    "head_dim": 16, "intermediate_size": 128,
-                    "vocab_size": 512},
-}
-PROGRAM_KEYS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
-                "num_attention_heads": "n_heads",
-                "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-                "intermediate_size": "d_ff", "vocab_size": "vocab"}
 
-
-def tiny_config(name: str) -> Dict:
-    cfg = json.loads((REPO / "bench" / "configs" / f"{name}.json")
-                     .read_text())
-    cfg.update(TINY[name])
+def tiny_config(name: str, root: pathlib.Path = REPO) -> Dict:
+    """Configuration ``name`` of the checkout ``root`` at the size its
+    file's ``tiny`` object gives: ``tiny.config`` over the source keys,
+    ``tiny.overrides`` over the program's overrides (a nested override
+    merged into the one it extends)."""
+    path = root / "bench" / "configs" / f"{name}.json"
+    cfg = spec.load_json(path)
+    tiny = cfg.pop("tiny", None)
+    if not isinstance(tiny, dict) or not {"config", "overrides"} <= set(tiny):
+        raise spec.SpecError(f"{path}: no 'tiny' object with 'config' and "
+                             "'overrides', the sizes its CPU tests run")
+    cfg.update(tiny["config"])
     cfg["name"] = f"{name}-tiny"
-    cfg["program"]["overrides"] = dict(
-        cfg["program"].get("overrides", {}),
-        **{PROGRAM_KEYS[k]: v for k, v in TINY[name].items()})
+    over = dict(cfg["program"].get("overrides", {}))
+    for k, v in tiny["overrides"].items():
+        nested = isinstance(v, dict) and isinstance(over.get(k), dict)
+        over[k] = {**over[k], **v} if nested else v
+    cfg["program"]["overrides"] = over
     return cfg
 
 
-#: a cell whose files are here but which is not yet in BENCHMARK.json
-#: (not measured on the chip); its tiny copy takes the limits of
-#: ``LIMITS_OF``
-PREPARED = [{"name": "pixtral-12b.muon-tp4", "config": "pixtral-12b",
-             "traffic": "muon-tp4", "chips": 4, "why": "prepared"}]
-LIMITS_OF = "stablelm-1.6b.muon"
+def add_tiny_cell(root: pathlib.Path, bench: Dict, w: Dict,
+                  seq_len: int = 32) -> str:
+    """Add to the checkout ``root`` and to ``bench`` (its BENCHMARK.json,
+    as a dict) the cell ``<config>-tiny.<traffic>``: cell ``w`` at its
+    configuration's tiny size and batch 8 x ``seq_len``, under its
+    limits and per-layer metrics.  Returns the new cell's name."""
+    b = root / "bench"
+    c, traffic = w["config"], w["traffic"]
+    (b / "configs" / f"{c}-tiny.json").write_text(
+        json.dumps(tiny_config(c, root)))
+    if f"{c}-tiny" not in {x["name"] for x in bench["configs"]}:
+        bench["configs"].append({"name": f"{c}-tiny", "source": "test",
+                                 "file": f"bench/configs/{c}-tiny.json",
+                                 "reduced": [], "why": "test"})
+    tr = spec.load_json(b / "traffic" / f"{traffic}.json")
+    tr["seq_len"] = seq_len
+    (b / "traffic" / f"{traffic}-tiny.json").write_text(json.dumps(tr))
+    name = f"{c}-tiny.{traffic}"
+    shutil.copy(b / "limits" / f"{w['name']}.json",
+                b / "limits" / f"{name}.json")
+    bench["workloads"].append(dict(w, name=name, config=f"{c}-tiny",
+                                   traffic=f"{traffic}-tiny"))
+    for m in bench["per_layer"]:
+        if w["name"] in m.get("workloads", []):
+            m["workloads"].append(name)
+    return name
 
 
 def make_root(tmp: pathlib.Path, seq_len: int = 32) -> pathlib.Path:
-    """A checkout with a ``<config>-tiny.<traffic>`` cell for each cell
-    of BENCHMARK.json and each prepared cell, at a tiny size and batch
-    8 x ``seq_len``."""
+    """A checkout with a ``<config>-tiny.<traffic>`` cell
+    (:func:`add_tiny_cell`) beside each cell of BENCHMARK.json."""
     root = tmp / "checkout"
     shutil.copytree(REPO / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "data"))
     os.symlink(REPO / "src", root / "src")
-    bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    names = {w["name"] for w in bench["workloads"]}
-    for w in list(bench["workloads"]) + [p for p in PREPARED
-                                         if p["name"] not in names]:
-        c = w["config"]
-        cfg = tiny_config(c)
-        (root / "bench" / "configs" / f"{c}-tiny.json").write_text(
-            json.dumps(cfg))
-        bench["configs"].append({"name": f"{c}-tiny", "source": "test",
-                                 "file": f"bench/configs/{c}-tiny.json",
-                                 "reduced": [], "why": "test"})
-        tr = json.loads((root / "bench" / "traffic" /
-                         f"{w['traffic']}.json").read_text())
-        tr["seq_len"] = seq_len
-        (root / "bench" / "traffic" / f"{w['traffic']}-tiny.json") \
-            .write_text(json.dumps(tr))
-        name = f"{c}-tiny.{w['traffic']}"
-        limits = root / "bench" / "limits" / f"{w['name']}.json"
-        if not limits.is_file():
-            limits = root / "bench" / "limits" / f"{LIMITS_OF}.json"
-        shutil.copy(limits, root / "bench" / "limits" / f"{name}.json")
-        bench["workloads"].append(dict(w, name=name, config=f"{c}-tiny",
-                                       traffic=f"{w['traffic']}-tiny"))
-        for m in bench["per_layer"]:
-            if w["name"] in m.get("workloads", []):
-                m["workloads"].append(name)
+    bench = spec.load_json(REPO / "BENCHMARK.json")
+    for w in list(bench["workloads"]):
+        add_tiny_cell(root, bench, w, seq_len)
     (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
     return root
 

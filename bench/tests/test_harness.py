@@ -31,7 +31,7 @@ def tiny(cell) -> str:
 # every fault a training cell can have: a step that returns its state
 # unchanged, half of the batch left out, and on a mesh the exchange
 # between chips left out (no token or answer is produced to alter)
-FAULTS = [(tiny(c), c["chips"], f) for c in CELLS + rehearse.PREPARED
+FAULTS = [(tiny(c), c["chips"], f) for c in CELLS
           for f in ("frozen", "half_batch")
           + (("no_exchange",) if c["chips"] > 1 else ())]
 
@@ -152,8 +152,10 @@ def test_control_is_not_correct(cell, variant):
     (tiny widths, on the CPU)."""
     import numpy as np
     from bench.drivers import train_step
-    from bench.references import dense_decoder as ref
     cfg = rehearse.tiny_config(cell["config"])
+    ref = spec.load_module(
+        REPO / "bench" / "references" / f"{cfg['reference']}.py",
+        cfg["reference"])
     job = json.loads((REPO / "bench" / "traffic" /
                       f"{cell['traffic']}.json").read_text())
     limits = json.loads((REPO / "bench" / "limits" /

@@ -11,6 +11,10 @@ import pytest
 from bench import scopes, spec, work
 from bench.xplane import Op, nest
 
+#: instructions that move or name data and do no work of their own
+NO_WORK = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+           "copy")
+
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 DEV = "/device:TPU:0"
 NS = "jit(train_step)/optim.muon/optim.muon.ns.periods.b0.mlp.wi/while/body"
@@ -79,6 +83,13 @@ def test_hlo_ops_names_every_instruction():
     (f"{NS}/closed_call/ns.apply/blas.symm.pallas/pallas_call",
      "optimizer", "optim.muon.ns.periods.b0.mlp.wi/ns.apply/"
      "blas.symm.pallas"),
+    # nested model scopes: the inner one that extends an outer one
+    # stands for both
+    (f"{LOSS}/jvp(model.blocks)/while/body/model.moe/model.moe.route/"
+     "dot_general", "forward", "train.loss[forward]/model.blocks/"
+     "model.moe.route"),
+    (f"{LOSS}/transpose(jvp(model.blocks))/model.moe/mul", "backward",
+     "train.loss[backward]/model.blocks/model.moe"),
     ("jit(train_step)/sin", "other", ""),
     ("", "unattributed", ""),
 ])
@@ -139,6 +150,21 @@ def test_attribute_self_time_into_buckets():
     assert j.ops["k.2 custom-call tpu_custom_call"] == \
         "optim.muon.ns.periods.b0.mlp.wi/ns.apply/blas.symm.pallas"
     assert j.refusal() is None
+
+
+def test_labels_keep_each_scope_path_s_self_time():
+    j = scopes.attribute(_timeline(), _hlo(), STEPS, (0, 1300))
+    ns = 1e-9
+    leaf = "optim.muon.ns.periods.b0.mlp.wi"
+    assert j.labels == {
+        leaf: pytest.approx(0),           # the loop's own
+        f"{leaf}/ns.gram/blas.syrk.pallas": pytest.approx(150 * ns),
+        f"{leaf}/ns.apply/blas.symm.pallas": pytest.approx(250 * ns),
+        "train.loss[forward]/model.blocks": pytest.approx(200 * ns),
+        "train.loss[backward]/model.blocks": pytest.approx(300 * ns),
+        "train.clip": pytest.approx(100 * ns)}
+    assert sum(j.labels.values()) == pytest.approx(
+        j.busy_s - j.buckets["unattributed"])
 
 
 def test_attribute_clips_to_the_window():
@@ -250,3 +276,107 @@ def test_recorded_v5e_trace_has_offset_bounds():
     assert gaps[0][0] == "bench.loss_read"
     assert all(g[0].startswith(("bench.", "repro.")) or g[0] == "unresolved"
                for g in gaps)
+
+
+# ------------------------------------------ a join on a compiled step
+@pytest.fixture(scope="module")
+def smoke_join():
+    """The compiled smoke-size stablelm-1.6b Muon step, joined to a
+    trace that runs each of its working instructions once, one after
+    another, each for its own length."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_smoke_config
+    from repro.launch.steps import make_optimizer, make_train_step
+    from repro.models.model import init_params
+    cfg = get_smoke_config("stablelm-1.6b")
+    opt = make_optimizer(cfg, "muon")
+    step = make_train_step(cfg, opt, loss_chunk=16)
+    shape = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    state = jax.eval_shape(opt.init, shape)
+    batch = {k: jax.ShapeDtypeStruct((2, 32), jnp.int32)
+             for k in ("tokens", "labels")}
+    hlo = scopes.hlo_ops(jax.jit(step).lower(shape, state, batch)
+                         .compile().as_text())
+    ops, t = [], 0
+    for i, name in enumerate(hlo.top):
+        opcode = hlo.ops[name][0]
+        if opcode in NO_WORK:
+            continue
+        ops.append(Op(f"{name} {opcode}", t, t + 10 + i % 7, "compute"))
+        t = ops[-1].end
+    tl = scopes.Timeline(devices={DEV: nest(ops)},
+                         modules={DEV: [(hlo.module, 0, t, "1")]})
+    return scopes.attribute(tl, hlo.ops, {DEV: [(0, t)]}, (0, t))
+
+
+def test_labels_add_up_to_the_buckets_on_a_compiled_step(smoke_join):
+    j = smoke_join
+    assert j.refusal() is None
+    assert sum(j.labels.values()) == pytest.approx(
+        j.busy_s - j.buckets["unattributed"])
+
+    def under(prefix):
+        return sum(v for k, v in j.labels.items()
+                   if k.split("/")[0].startswith(prefix))
+    assert under("train.loss[") == pytest.approx(
+        sum(j.buckets[b] for b in scopes.MODEL))
+    assert under("train.loss[") > 0
+    assert under("optim.") == pytest.approx(j.buckets["optimizer"])
+    assert under("optim.") > 0
+    assert under("train.clip") == pytest.approx(j.buckets["clip"])
+
+
+# ------------------------------------------------- wire_device_s
+WIRE_OPS = [  # (name, opcode, op_name, ns)
+    ("dot.1", "dot", f"{NS}/ns.gram/blas.syrk.ring/dot_general", 100),
+    ("cp.2", "collective-permute", f"{NS}/ns.gram/blas.syrk.ring/ppermute",
+     60),
+    ("ar.3", "all-reduce", f"{NS}/ns.apply/blas.symm.1d/psum", 40),
+    ("rs.4", "reduce-scatter", "jit(train_step)/optim.muon/optim.muon.ns."
+     "embed/optim.muon_1d/shard_map/ns.gram/reduce_scatter", 30),
+    ("k.5", "custom-call", f"{NS}/ns.square/blas.symm.pallas/pallas_call",
+     70),
+    ("add.6", "add", "jit(train_step)/optim.muon/add", 20),
+    ("ag.7", "all-gather", f"{LOSS}/jvp(model.blocks)/all_gather", 50),
+]
+
+
+def _wire_ctx():
+    ops, hlo, t = [], {}, 0
+    for name, opcode, op_name, ns in WIRE_OPS:
+        ops.append(Op(f"{name} {opcode}", t, t + ns, "compute"))
+        hlo[name] = (opcode, op_name)
+        t += ns
+    tl = scopes.Timeline(devices={DEV: nest(ops)})
+    j = scopes.attribute(tl, hlo, {DEV: [(0, t)]}, (0, t))
+    return {"scopes": {"join": j, "steps": 2}}
+
+
+def _reader(name):
+    return spec.load_module(spec.BENCH / "metrics" / f"{name}.py", name)
+
+
+def test_wire_device_s_reads_both_wires():
+    ctx = _wire_ctx()
+    wire = _reader("wire_device_s").read(ctx)
+    # the ring and 1d routes and Muon's 1d wire; not the Pallas route,
+    # the optimizer's own add or the model's all-gather
+    assert wire == pytest.approx((100 + 60 + 40 + 30) * 1e-9 / 2)
+    assert wire < _reader("optimizer_device_s").read(ctx)
+
+
+def test_wire_device_s_is_none_without_a_wire():
+    j = scopes.attribute(_timeline(), _hlo(), STEPS, (0, 1000))
+    reader = _reader("wire_device_s")
+    assert reader.read({"scopes": {"join": j, "steps": 2}}) is None
+    assert reader.read({"scopes": None}) is None
+
+
+def test_scope_s_matches_labels_by_pattern():
+    ctx = _wire_ctx()
+    assert scopes.scope_s(ctx, r"^train\.loss\[forward\]") == \
+        pytest.approx(50e-9 / 2)
+    assert scopes.scope_s(ctx, r"ns\.square") == pytest.approx(70e-9 / 2)
+    assert scopes.scope_s(ctx, r"model\.moe") is None

@@ -1,4 +1,5 @@
-"""Required-work counts against hand counts for stablelm-1.6b's shapes.
+"""Required-work counts against hand counts for stablelm-1.6b's shapes,
+and each cell's step count pinned to the value its ``step_mfu`` divides.
 
     python -m pytest -q bench/tests/test_work.py
 """
@@ -7,7 +8,8 @@ import pathlib
 
 import pytest
 
-from bench import work
+from bench import spec, work
+from bench.drivers import train_step
 from bench.references import dense_decoder
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
@@ -40,7 +42,7 @@ def test_model_flop_matches_hand_count(cfg):
     per_layer = 4 * 2048 * 2048 + 3 * 2048 * 5632
     n = 24 * per_layer + 2048 * 100352       # the embedding is a gather
     attn = 6 * 8 * 512 * 512 * 32 * 64 * 24
-    got = work.model_flop(cfg, batch=8, seq=512)
+    got = dense_decoder.model_flop(cfg, batch=8, seq=512)
     assert got == 6 * n * 8 * 512 + attn
     assert got == pytest.approx(3.6e13, rel=0.01)
 
@@ -72,6 +74,20 @@ def test_roofline_takes_the_larger_bound():
 
 
 def test_step_flop_totals(cfg, shapes):
-    f = work.step_flop(cfg, shapes, 8, 512, 5)
+    model = dense_decoder.model_flop(cfg, 8, 512)
+    f = work.step_flop(model, shapes, 5)
+    assert f["model"] == model
     assert f["total"] == f["model"] + f["ns"]
     assert f["total"] == pytest.approx(1.01e14, rel=0.01)
+
+
+# each cell's required work, to the last bit: a change to where the
+# count lives must not move step_mfu
+@pytest.mark.parametrize("workload,model,ns", [
+    ("stablelm-1.6b.muon", 35974646071296.0, 65111775539200.0),
+    ("pixtral-12b.muon-tp4", 84009560309760.0, 356482300948000.0),
+])
+def test_cell_step_flop_is_pinned(workload, model, ns):
+    cell = spec.load_cell(BENCH.parent, workload)
+    f = train_step.step_flop(cell, cell.reference().layout(cell.config))
+    assert f == {"model": model, "ns": ns, "total": model + ns}

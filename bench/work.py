@@ -3,11 +3,19 @@
 These counts do not change when the implementation changes: they are
 what the step has to do, not what one implementation compiled to.
 
-* Model: 6 flop per matmul parameter per token (forward and backward,
-  recomputation not counted) plus causal attention.
+* Model: each configuration's plain reference (``references/<name>.py``,
+  named by the configuration's ``reference`` key) gives the model's
+  term as ``model_flop(cfg, batch, seq)``, forward and backward of one
+  step from the published shapes: 6 flop per parameter that a token
+  multiplies by (the active ones: a mixture of experts counts the
+  experts a token is routed to and the shared ones, never all of them),
+  plus causal attention at its own head dimensions; recomputation not
+  counted.  :func:`model_flop` here is the dense decoder's count, which
+  ``references/dense_decoder.py`` gives as its own.
 * Newton–Schulz (NS): each iteration on X (m x n, m <= n) is one SYRK
   S = X Xᵀ, one SYMM S·S and one SYMM Y·X, counted with the paper's
-  counts: SYRK m²n, SYR2K 2m²n, SYMM 2m²n.
+  counts: SYRK m²n, SYR2K 2m²n, SYMM 2m²n.  It is counted from the
+  leaves' shapes, so it holds for any model.
 * Bytes of a symmetric call: every operand read once (a symmetric
   operand as its triangle), the output written once at its fill.
 """
@@ -62,8 +70,9 @@ def roofline_s(flop: float, nbytes: float, peak: Dict[str, float]) -> float:
 
 # ---------------------------------------------------------------- model
 def matmul_params(cfg: Dict) -> int:
-    """Parameters that take part in a matmul per token (the embedding
-    lookup is a gather; the head is a matmul, tied or not)."""
+    """A dense decoder's parameters that take part in a matmul per token
+    (the embedding lookup is a gather; the head is a matmul, tied or
+    not)."""
     d, ff, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
     h, kv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
                  cfg["head_dim"])
@@ -72,8 +81,8 @@ def matmul_params(cfg: Dict) -> int:
 
 
 def model_flop(cfg: Dict, batch: int, seq: int) -> float:
-    """Forward and backward of one step: 6·N·tokens plus causal
-    attention (QKᵀ and PV, half the square, three passes)."""
+    """A dense decoder's forward and backward of one step: 6·N·tokens
+    plus causal attention (QKᵀ and PV, half the square, three passes)."""
     attn = 6.0 * batch * seq * seq * cfg["num_attention_heads"] \
         * cfg["head_dim"] * cfg["num_hidden_layers"]
     return 6.0 * matmul_params(cfg) * batch * seq + attn
@@ -117,8 +126,9 @@ def calls_roofline_s(calls: Iterable[Call], peak: Dict[str, float],
                * c.batch * c.per_step for c in calls)
 
 
-def step_flop(cfg: Dict, shapes: Iterable[Sequence[int]], batch: int,
-              seq: int, ns_steps: int, min_side: int = 8) -> Dict[str, float]:
+def step_flop(model: float, shapes: Iterable[Sequence[int]], ns_steps: int,
+              min_side: int = 8) -> Dict[str, float]:
+    """The step's flop: ``model``, the reference's ``model_flop``, plus
+    the NS chains of the leaves of ``shapes``."""
     ns = calls_flop(ns_calls(list(shapes), ns_steps, min_side))
-    model = model_flop(cfg, batch, seq)
     return {"model": model, "ns": ns, "total": model + ns}
